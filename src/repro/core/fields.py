@@ -29,6 +29,45 @@ class FieldFns(NamedTuple):
     # of chunked density/color calls.  None everywhere else — analytic
     # and pure-jnp fields keep the reference chunked march.
     fused: object = None
+    # Optional ``Bound``: the arrays the closures above capture, and how
+    # to rebuild the closures around any value of them.  Jitted callers
+    # use it (``unbind``/``rebind``) to pass the params as ARGUMENTS; a
+    # closure over concrete arrays would bake them into the executable
+    # as constants (64 MB of hash tables at the paper config).
+    bound: object = None
+
+
+class Bound:
+    """``build(params, cfg)`` rebuilds the FieldFns that holds this.
+
+    A plain class (identity hash) so a FieldFns carrying one stays
+    hashable; ``unbind`` keys jitted code on ``(build, cfg)`` only, so
+    fields that differ only in their params share one executable.
+    """
+
+    def __init__(self, build: Callable, cfg, params):
+        self.build = build
+        self.cfg = cfg
+        self.params = params
+
+
+def unbind(fns: FieldFns):
+    """Split ``fns`` into (static key, params): the key is hashable and
+    holds no arrays, the params are what a jitted caller passes as
+    arguments; ``rebind(key, params)`` inside the trace gives back the
+    field.  Fields without ``bound`` (analytic ones hold no arrays) are
+    their own key."""
+    if fns.bound is None:
+        return fns, None
+    return (fns.bound.build, fns.bound.cfg), fns.bound.params
+
+
+def rebind(key, params) -> FieldFns:
+    """Inverse of ``unbind``."""
+    if isinstance(key, FieldFns):
+        return key
+    build, cfg = key
+    return build(params, cfg)
 
 
 def analytic_field_fns(field) -> FieldFns:

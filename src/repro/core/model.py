@@ -99,6 +99,7 @@ def field_fns(params: Dict, cfg: NGPConfig):
     return fields.FieldFns(
         density=lambda pts: query_density(params, cfg, pts),
         color=lambda geo, dirs: query_color(params, cfg, geo, dirs),
+        bound=fields.Bound(field_fns, cfg, params),
     )
 
 

@@ -24,7 +24,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from . import adaptive, decouple, rendering, scene
+from . import adaptive, decouple, fields, rendering, scene
 from .fields import FieldFns
 
 LOG_EPS_T = jnp.log(rendering.EARLY_TERM_TRANSMITTANCE)
@@ -75,10 +75,17 @@ def render_fixed_fns(
 
     Deliberately NOT jitted here: fns closures may capture model params,
     and a static-fns jit would bake those arrays into the executable and
-    recompile per FieldFns construction.  Callers with stable fns (the
-    serving engine, launch cells) jit at their own boundary.
+    recompile per FieldFns construction.  Callers jit at their own
+    boundary with the params as arguments (``fields.unbind``), as
+    ``probe_phase`` does.
     """
-    pts, deltas, ts = scene.sample_points(origins, dirs, n_samples, key)
+    ts = scene.sample_ts(origins.shape[0], n_samples, key)
+    return render_at(fns, origins, dirs, ts, white_background)
+
+
+def render_at(fns: FieldFns, origins, dirs, ts, white_background=True):
+    """``render_fixed_fns`` at given sample distances ts (R, S)."""
+    pts, deltas = scene.points_at(origins, dirs, ts)
     R, S = pts.shape[:2]
     flat = pts.reshape(-1, 3)
     dflat = jnp.repeat(dirs, S, axis=0)
@@ -282,6 +289,66 @@ def render_adaptive(fns: FieldFns, acfg: ASDRConfig, origins, dirs, counts,
     return rgb, acc, stats
 
 
+def probe_rays(fns: FieldFns, acfg: ASDRConfig, origins, dirs, ts):
+    """Phase-I march of the probe rays -> per-ray (counts (R,) int32,
+    acc (R,), expected termination depth (R,)).
+
+    Marches fixed blocks under ``lax.map``, each holding as many samples
+    as one Phase-II chunk step (``block_size * chunk``), so the probe's
+    per-sample temporaries are bounded like the march's instead of
+    scaling with the frame (one 800x800 probe at 192 samples is 4.9M
+    samples, ~16 GB of temporaries on a TPU).  Every output is per ray,
+    so blocking changes no value; pad rays are cropped.
+    """
+    R, S = ts.shape
+    B = max(1, acfg.block_size * acfg.chunk // S)
+    pad = (-R) % B
+    if pad:
+        origins = jnp.concatenate([origins, jnp.zeros((pad, 3))], axis=0)
+        dirs = jnp.concatenate(
+            [dirs, jnp.tile(jnp.asarray([[0.0, 0.0, 1.0]]), (pad, 1))],
+            axis=0)
+        ts = jnp.concatenate([ts, jnp.broadcast_to(ts[:1], (pad, S))],
+                             axis=0)
+
+    def block(args):
+        rgb, aux = render_at(fns, *args,
+                             white_background=acfg.white_background)
+        counts = adaptive.probe_counts(
+            aux["sigmas"], aux["colors"], rgb, acfg.ns_full,
+            acfg.candidates, acfg.delta)
+        t_exp = rendering.expected_termination_depth(
+            aux["weights"], aux["ts"], aux["acc"], scene.FAR)
+        return counts, aux["acc"], t_exp
+
+    out = jax.lax.map(block, (origins.reshape(-1, B, 3),
+                              dirs.reshape(-1, B, 3),
+                              ts.reshape(-1, B, S)))
+    return tuple(x.reshape(-1)[:R] for x in out)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _probe_maps(fkey, acfg: ASDRConfig, geom, params, rot, origin,
+                probe_key):
+    """The whole probe, jitted with the field params as arguments:
+    (counts, opacity, depth), each (H*W,)."""
+    H, W, focal = geom
+    o, d = scene.camera_rays(scene.Camera(H, W, focal, rot, origin))
+    jj, ii = jnp.meshgrid(jnp.arange(0, H, acfg.probe_stride),
+                          jnp.arange(0, W, acfg.probe_stride),
+                          indexing="ij")
+    probe_idx = (jj * W + ii).reshape(-1)
+    probe_hw = (jj.shape[0], jj.shape[1])
+    ts = scene.sample_ts(probe_idx.shape[0], acfg.ns_full, probe_key)
+    pcounts, acc, t_exp = probe_rays(fields.rebind(fkey, params), acfg,
+                                     o[probe_idx], d[probe_idx], ts)
+    counts = adaptive.interpolate_counts(
+        pcounts, probe_hw, (H, W), acfg.candidates, acfg.ns_full)
+    opacity = adaptive.interpolate_map(acc, probe_hw, (H, W))
+    depth = adaptive.interpolate_map(t_exp, probe_hw, (H, W))
+    return counts, opacity, depth
+
+
 def probe_phase(fns: FieldFns, acfg: ASDRConfig, cam, probe_key=None,
                 return_opacity: bool = False, return_depth: bool = False):
     """Phase I: strided probe -> per-pixel sample-count map (H*W,).
@@ -290,36 +357,21 @@ def probe_phase(fns: FieldFns, acfg: ASDRConfig, cam, probe_key=None,
     accumulated opacity over the image (secondary block-sort key).  With
     return_depth, additionally interpolates each probe ray's expected
     termination distance (background pinned to FAR) — the proxy depth the
-    framecache warp primitive reprojects per-pixel maps with."""
-    H, W = cam.height, cam.width
-    o, d = scene.camera_rays(cam)
-    d_stride = acfg.probe_stride
-    jj, ii = jnp.meshgrid(
-        jnp.arange(0, H, d_stride), jnp.arange(0, W, d_stride), indexing="ij"
-    )
-    probe_idx = (jj * W + ii).reshape(-1)
-    rgb_full, aux = render_fixed_fns(
-        fns, o[probe_idx], d[probe_idx], acfg.ns_full, probe_key,
-        white_background=acfg.white_background,
-    )
-    pcounts = adaptive.probe_counts(
-        aux["sigmas"], aux["colors"], rgb_full, acfg.ns_full,
-        acfg.candidates, acfg.delta,
-    )
-    counts = adaptive.interpolate_counts(
-        pcounts, (jj.shape[0], jj.shape[1]), (H, W),
-        acfg.candidates, acfg.ns_full,
-    )
-    probe_cost = int(probe_idx.shape[0]) * acfg.ns_full
+    framecache warp primitive reprojects per-pixel maps with.
+
+    One jitted call (``_probe_maps``) with the field params passed as
+    arguments; the probe rays march in blocks (``probe_rays``)."""
+    fkey, params = fields.unbind(fns)
+    counts, opacity, depth = _probe_maps(
+        fkey, acfg, (cam.height, cam.width, cam.focal), params,
+        jnp.asarray(cam.c2w_rot), jnp.asarray(cam.origin), probe_key)
+    stride = acfg.probe_stride
+    n_probe = (-(-cam.height // stride)) * (-(-cam.width // stride))
+    probe_cost = n_probe * acfg.ns_full
     if not (return_opacity or return_depth):
         return counts, probe_cost
-    probe_hw = (jj.shape[0], jj.shape[1])
-    opacity = adaptive.interpolate_map(aux["acc"], probe_hw, (H, W))
     if not return_depth:
         return counts, probe_cost, opacity
-    t_exp = rendering.expected_termination_depth(
-        aux["weights"], aux["ts"], aux["acc"], scene.FAR)
-    depth = adaptive.interpolate_map(t_exp, probe_hw, (H, W))
     return counts, probe_cost, opacity, depth
 
 

@@ -128,7 +128,9 @@ def camera_rays(cam: Camera):
     y = -(j - cam.height * 0.5 + 0.5) / cam.focal
     d_cam = jnp.stack([x, y, jnp.ones_like(x)], axis=-1)  # (H, W, 3)
     rot = jnp.asarray(cam.c2w_rot)
-    d = d_cam @ rot.T
+    # full float32: a TPU's default matmul precision rounds the operands
+    # to bfloat16, which bends every ray by ~2^-9 rad
+    d = jnp.matmul(d_cam, rot.T, precision=jax.lax.Precision.HIGHEST)
     d = d / jnp.linalg.norm(d, axis=-1, keepdims=True)
     o = jnp.broadcast_to(jnp.asarray(cam.origin), d.shape)
     return o.reshape(-1, 3), d.reshape(-1, 3)
@@ -138,23 +140,34 @@ def camera_rays(cam: Camera):
 NEAR, FAR = 0.2, 2.2
 
 
+def sample_ts(n_rays: int, n_samples: int, key=None):
+    """(n_rays, n_samples) sample distances: stratified (if key) or
+    midpoint sampling of n_samples along each ray."""
+    edges = jnp.linspace(NEAR, FAR, n_samples + 1)
+    mids = 0.5 * (edges[:-1] + edges[1:])
+    if key is not None:
+        jitter = (jax.random.uniform(key, (n_rays, n_samples)) - 0.5) * (
+            (FAR - NEAR) / n_samples
+        )
+        return mids[None, :] + jitter
+    return jnp.broadcast_to(mids[None, :], (n_rays, n_samples))
+
+
+def points_at(origins, dirs, ts):
+    """Sample points (R, S, 3) and deltas (R, S) at distances ts (R, S)."""
+    n_samples = ts.shape[-1]
+    deltas = jnp.full(ts.shape, (FAR - NEAR) / n_samples)
+    pts = origins[:, None, :] + ts[..., None] * dirs[:, None, :]
+    return pts, deltas
+
+
 def sample_points(origins, dirs, n_samples: int, key=None):
     """Stratified (if key) or midpoint sampling of n_samples along each ray.
 
     Returns points (R, S, 3), deltas (R, S), ts (R, S).
     """
-    R = origins.shape[0]
-    edges = jnp.linspace(NEAR, FAR, n_samples + 1)
-    mids = 0.5 * (edges[:-1] + edges[1:])
-    if key is not None:
-        jitter = (jax.random.uniform(key, (R, n_samples)) - 0.5) * (
-            (FAR - NEAR) / n_samples
-        )
-        ts = mids[None, :] + jitter
-    else:
-        ts = jnp.broadcast_to(mids[None, :], (R, n_samples))
-    deltas = jnp.full((R, n_samples), (FAR - NEAR) / n_samples)
-    pts = origins[:, None, :] + ts[..., None] * dirs[:, None, :]
+    ts = sample_ts(origins.shape[0], n_samples, key)
+    pts, deltas = points_at(origins, dirs, ts)
     return pts, deltas, ts
 
 

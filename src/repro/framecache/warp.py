@@ -29,6 +29,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..core import scene
@@ -46,7 +47,11 @@ def project_to_camera(points: jnp.ndarray, cam) -> Tuple[jnp.ndarray,
     (the depth a ray from ``cam`` through that pixel would record).
     """
     H, W = cam.height, cam.width
-    rel = (points - jnp.asarray(cam.origin)) @ jnp.asarray(cam.c2w_rot)
+    # full float32, as in scene.camera_rays: at bfloat16 operands (a TPU's
+    # default) an 800-pixel frame's projection lands pixels off by ~2
+    rel = jnp.matmul(points - jnp.asarray(cam.origin),
+                     jnp.asarray(cam.c2w_rot),
+                     precision=jax.lax.Precision.HIGHEST)
     z = rel[:, 2]
     in_front = z > 1e-6
     zs = jnp.where(in_front, z, 1.0)

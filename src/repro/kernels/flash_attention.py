@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 NEG_INF = -1e30
 QB = 128      # q rows per block program
 KB = 128      # kv rows per inner step
@@ -64,8 +66,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, out_ref, *, kv_len, window,
         out_ref.dtype)
 
 
-def flash_attention(q, k, v, window: int = 0, softcap: float = 0.0,
-                    interpret: bool = True):
+def flash_attention(q, k, v, window: int = 0, softcap: float = 0.0):
     """q (B, S, H, Dh); k/v (B, S, KV, Dh) with H % KV == 0 (GQA).
     Causal (+ optional sliding-window) attention. Returns (B, S, H, Dh)."""
     B, S, H, Dh = q.shape
@@ -93,6 +94,6 @@ def flash_attention(q, k, v, window: int = 0, softcap: float = 0.0,
         ],
         out_specs=pl.BlockSpec((None, QB, Dh), lambda bh, qb: (bh, qb, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, Dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(qf, kf, vf)
     return out.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)

@@ -40,8 +40,9 @@ Data layout (prepared by ops.fused_march_blocks):
   o / d    (N*B, PPAD) f32  — rays padded to 8 lanes, one block per
                               grid step
   sh       (N*B, P)    f32  — SH(dir) pre-placed at cols [G, G+S)
-  budgets  (N, 8)      i32  — col 0 = per-block sample budget
-  meta     (L, 8)      i32  — hash_encode.grid_meta rows
+  budgets  (N,)        i32  — per-block sample budget (SMEM, read by
+                              program id)
+  meta     (L, 8)      i32  — hash_encode.grid_meta rows (SMEM)
   tables   (L, T, F)   f32  — resident: VMEM for all grid steps;
                               streamed: stays in HBM (ANY memory
                               space), DMA'd per level into a
@@ -56,17 +57,23 @@ Data layout (prepared by ops.fused_march_blocks):
 entirely and rgb reads 0 — acc/depth/chunks keep full parity with the
 reference density-only march.
 
-VMEM accounting (ops.fused_march_vmem_bytes is the ledger): the full
-config's table stack (16 levels x 2^19 x 2 x 4 B = 64 MB) exceeds a
-16 MB VMEM, so residency cannot ship at production scale — the
-STREAMED lowering above runs it with a 2 x T x F = 8 MB working pair.
-ops.fused_march_blocks auto-selects per config: resident whenever the
-stack fits (bit-identical to streamed — same gather math against the
-same bytes, gated by tests), streamed otherwise.  The small test
-config (8 x 2^14 x 2 = 128 KB) stays resident.  Weights: (nd+nc) x
-64 KB as in fused_mlp.py.  This container validates both paths in
-interpret mode (the DMA ping/pong included); on hardware the same
-kernel lowers with real async copies.
+VMEM accounting (ops.fused_march_vmem_bytes is the ledger) counts the
+tables at 4 B per element: 64 MB for the full config's resident stack
+(16 levels x 2^19 x 2), 8 MB for the streamed 2 x T x F pair.  That is
+an undercount on a TPU, where the minor dimension F=2 is laid out in
+128-lane tiles: each (T, 2) block occupies 64x its bytes, so even one
+streamed level (T x 128 x 4 B = 256 MB) cannot sit in VMEM.
+ops.fused_march_blocks auto-selects resident whenever the stack fits
+the ledger (bit-identical to streamed — same gather math against the
+same bytes, gated by tests), streamed otherwise.
+
+TPU status: neither supply compiles (tests/test_tpu_compile.py pins
+it).  Mosaic refuses (1) the VMEM vector gather ``table[idx]`` of the
+shared ``HE.encode_level`` ("Shape mismatch in input, indices and
+output") and (2) the streamed per-level DMA ``tables_ref.at[level]``
+("Slice shape along dimension 2 must be aligned to tiling (128), but is
+2").  Both paths run in interpret mode on the CPU only, and
+``ops.fused_march_blocks`` raises on a TPU instead of interpreting.
 """
 from __future__ import annotations
 
@@ -78,6 +85,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import hash_encode as HE
+from . import resolve_interpret
 
 P = 128      # padded feature width (MXU lane width) — matches fused_mlp
 PPAD = 8     # padded ray row [x, y, z, 0...]    — matches hash_encode
@@ -160,7 +168,7 @@ def _march_impl(o_ref, d_ref, sh_ref, bud_ref, meta_ref, wd_ref, wc_ref,
     o = o_ref[...][:, :3]
     d = d_ref[...][:, :3]
     sh = sh_ref[...]
-    budget = bud_ref[0]
+    budget = bud_ref[pl.program_id(0)]
     wd = wd_ref[...]
     wc = wc_ref[...]
 
@@ -252,11 +260,10 @@ def _march_impl(o_ref, d_ref, sh_ref, bud_ref, meta_ref, wd_ref, wc_ref,
 def _march_kernel_resident(o_ref, d_ref, sh_ref, bud_ref, meta_ref,
                            tables_ref, wd_ref, wc_ref, out_ref, **kw):
     """All L levels VMEM-resident for the whole march (small configs)."""
-    meta = meta_ref[...]
     tables = tables_ref[...]
     _march_impl(o_ref, d_ref, sh_ref, bud_ref, meta_ref, wd_ref, wc_ref,
                 out_ref,
-                lambda flat: _encode_points(flat, meta,
+                lambda flat: _encode_points(flat, meta_ref,
                                             lambda l: tables[l],
                                             kw["n_levels"]), **kw)
 
@@ -275,8 +282,6 @@ def _march_kernel_streamed(o_ref, d_ref, sh_ref, bud_ref, meta_ref,
     always two levels apart, and level l-1's slot was fully consumed
     before level l+1's copy into it starts.
     """
-    meta = meta_ref[...]
-
     def copy(level, slot):
         return pltpu.make_async_copy(
             tables_ref.at[level], tbuf.at[slot], sem.at[slot])
@@ -289,7 +294,7 @@ def _march_kernel_streamed(o_ref, d_ref, sh_ref, bud_ref, meta_ref,
 
     def encode(flat):
         copy(0, 0).start()                    # warm-up: first level
-        return _encode_points(flat, meta, read_level, kw["n_levels"])
+        return _encode_points(flat, meta_ref, read_level, kw["n_levels"])
 
     _march_impl(o_ref, d_ref, sh_ref, bud_ref, meta_ref, wd_ref, wc_ref,
                 out_ref, encode, **kw)
@@ -298,9 +303,8 @@ def _march_kernel_streamed(o_ref, d_ref, sh_ref, bud_ref, meta_ref,
 def fused_march_call(o, d, sh, budgets, meta, tables, wd, wc, *,
                      block_size, geo_dim, group, chunk, near, far,
                      log_eps_t, early_term, white_background,
-                     with_color, stream_tables=False, per_ray_exit=False,
-                     interpret=True):
-    """o/d (N*B, PPAD), sh (N*B, P), budgets (N, 8) i32, meta (L, 8) i32,
+                     with_color, stream_tables=False, per_ray_exit=False):
+    """o/d (N*B, PPAD), sh (N*B, P), budgets (N,) i32, meta (L, 8) i32,
     tables (L, T, F), wd (nd,P,P), wc (nc,P,P) -> packed (N*B, OUT_W).
 
     ``stream_tables`` selects the table supply: False keeps the stack
@@ -319,7 +323,7 @@ def fused_march_call(o, d, sh, budgets, meta, tables, wd, wc, *,
               white_background=white_background, with_color=with_color)
     if stream_tables:
         kern = functools.partial(_march_kernel_streamed, **kw)
-        tables_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        tables_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [pltpu.VMEM((2, T, F), jnp.float32),
                    pltpu.SemaphoreType.DMA((2,))]
     else:
@@ -333,8 +337,8 @@ def fused_march_call(o, d, sh, budgets, meta, tables, wd, wc, *,
             pl.BlockSpec((B, PPAD), lambda i: (i, 0)),
             pl.BlockSpec((B, PPAD), lambda i: (i, 0)),
             pl.BlockSpec((B, P), lambda i: (i, 0)),
-            pl.BlockSpec((None, 8), lambda i: (i, 0)),
-            pl.BlockSpec((L, 8), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             tables_spec,
             pl.BlockSpec((wd.shape[0], P, P), lambda i: (0, 0, 0)),
             pl.BlockSpec((wc.shape[0], P, P), lambda i: (0, 0, 0)),
@@ -342,5 +346,5 @@ def fused_march_call(o, d, sh, budgets, meta, tables, wd, wc, *,
         out_specs=pl.BlockSpec((B, OUT_W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks * B, OUT_W), jnp.float32),
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(o, d, sh, budgets, meta, tables, wd, wc)

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 P = 128          # padded feature width (MXU lane width)
 TILE = 256       # points per block program
 
@@ -106,7 +108,7 @@ def _tile_spec():
     return pl.BlockSpec((TILE, P), lambda i: (i, 0))
 
 
-def fused_field_call(enc, sh, wd, wc, geo_dim: int, interpret: bool = True):
+def fused_field_call(enc, sh, wd, wc, geo_dim: int):
     """enc/sh (N, P) padded; wd (nd,P,P); wc (nc,P,P) -> packed (N, P)."""
     n = enc.shape[0]
     assert n % TILE == 0, "ops.py pads N to a TILE multiple"
@@ -120,11 +122,11 @@ def fused_field_call(enc, sh, wd, wc, geo_dim: int, interpret: bool = True):
                   _weights_spec(wd.shape[0]), _weights_spec(wc.shape[0])],
         out_specs=_tile_spec(),
         out_shape=jax.ShapeDtypeStruct((n, P), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(enc, sh, wd, wc)
 
 
-def density_call(enc, wd, geo_dim: int, interpret: bool = True):
+def density_call(enc, wd, geo_dim: int):
     n = enc.shape[0]
     assert n % TILE == 0
     kern = functools.partial(_density_kernel, nd=wd.shape[0], geo_dim=geo_dim)
@@ -134,11 +136,11 @@ def density_call(enc, wd, geo_dim: int, interpret: bool = True):
         in_specs=[_tile_spec(), _weights_spec(wd.shape[0])],
         out_specs=_tile_spec(),
         out_shape=jax.ShapeDtypeStruct((n, P), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(enc, wd)
 
 
-def color_call(cin, wc, interpret: bool = True):
+def color_call(cin, wc):
     n = cin.shape[0]
     assert n % TILE == 0
     kern = functools.partial(_color_kernel, nc=wc.shape[0])
@@ -148,5 +150,5 @@ def color_call(cin, wc, interpret: bool = True):
         in_specs=[_tile_spec(), _weights_spec(wc.shape[0])],
         out_specs=_tile_spec(),
         out_shape=jax.ShapeDtypeStruct((n, P), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(cin, wc)

@@ -21,20 +21,26 @@ Grid = (n_levels, n_point_tiles); each step re-binds the level's table
 while point tiles iterate — table traffic is L*T*F bytes total regardless
 of N (vs N*8*L*F naive).
 
-Layout notes: table minor dim F=2 and the (TILE, 8) gather are interpret-
-mode-validated; a production TPU lowering packs F into 128-lane rows and
-uses a one-hot-matmul gather for the dense levels (see EXPERIMENTS.md
-§Perf for the measured trade-off).
+The per-level metadata table sits in SMEM and each grid step reads its
+level's scalars there.
+
+TPU status: this kernel runs in interpret mode only.  Mosaic refuses the
+VMEM vector gather ``table[idx]`` in ``encode_level`` ("Shape mismatch in
+input, indices and output"), and the (T, F=2) table block is laid out in
+128-lane tiles, so a level's VMEM footprint is 64x its bytes.  The serving
+path therefore encodes with the XLA gather (``core.hashgrid.encode``);
+``tests/test_tpu_compile.py`` pins the refusal.
 """
 from __future__ import annotations
-
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.hashgrid import PRIMES
+from . import resolve_interpret
 
 TILE = 256   # points per block program
 PPAD = 8     # padded point row: [x, y, z, 0...]
@@ -79,15 +85,15 @@ def encode_level(pts, res, is_dense, rows, table):
 
 
 def _encode_kernel(pts_ref, meta_ref, table_ref, out_ref):
-    meta = meta_ref[...]
+    level = pl.program_id(0)
     pts = pts_ref[...][:, :3]                            # (TILE, 3)
-    out_ref[...] = encode_level(pts, meta[0], meta[1], meta[2],
-                                table_ref[...])
+    out_ref[...] = encode_level(pts, meta_ref[level, 0], meta_ref[level, 1],
+                                meta_ref[level, 2], table_ref[...])
 
 
-def hash_encode_call(points_padded, meta, tables, interpret: bool = True):
-    """points_padded (N, PPAD); meta (L, 8) int32 [res, is_dense, rows, ...];
-    tables (L, T, F) -> features (L, N, F) f32."""
+def hash_encode_call(points_padded, meta, tables):
+    """points_padded (N, PPAD); meta (L, 8) int32 [res, is_dense, rows, ...]
+    (whole table in SMEM); tables (L, T, F) -> features (L, N, F) f32."""
     n = points_padded.shape[0]
     L, T, F = tables.shape
     assert n % TILE == 0, "ops.py pads N to a TILE multiple"
@@ -96,10 +102,10 @@ def hash_encode_call(points_padded, meta, tables, interpret: bool = True):
         grid=(L, n // TILE),
         in_specs=[
             pl.BlockSpec((TILE, PPAD), lambda l, i: (i, 0)),
-            pl.BlockSpec((None, 8), lambda l, i: (l, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((None, T, F), lambda l, i: (l, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, TILE, F), lambda l, i: (l, i, 0)),
         out_shape=jax.ShapeDtypeStruct((L, n, F), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(points_padded, meta, tables)

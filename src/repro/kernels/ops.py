@@ -12,28 +12,40 @@ modules and exposes the *logical* shapes used by core/:
 ``field_fns(params, cfg)`` returns a kernels-backed FieldFns so the whole
 ASDR pipeline (core/pipeline.py) can run on the kernel path; tests assert
 it matches the pure-jnp model path.
+
+The kernels interpret exactly on the CPU and run compiled everywhere else
+(``repro.kernels.resolve_interpret``); no wrapper overrides that.
 """
 from __future__ import annotations
 
 import math
 import threading
 from collections import OrderedDict
-from functools import partial
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
+from ..core import hashgrid
 from ..core import mlp as mlp_lib
 from ..core import rendering, scene
-from ..core.fields import FieldFns
+from ..core.fields import Bound, FieldFns
 from . import fused_march as FMA
 from . import fused_mlp as FM
 from . import hash_encode as HE
+from . import resolve_interpret
 from . import volume_render as VR
 
-# interpret=True everywhere in this container (CPU validation); flip on TPU.
-INTERPRET = True
+# Why the fused march cannot run compiled on a TPU (tests/test_tpu_compile.py
+# pins each refusal); ``fused_march_blocks`` raises this instead of
+# interpreting.
+FUSED_MARCH_TPU_REFUSAL = (
+    "march_backend='fused' does not compile for TPU: Mosaic refuses the "
+    "in-kernel VMEM gather table[idx] of hash_encode.encode_level ('Shape "
+    "mismatch in input, indices and output'), and the streamed supply's "
+    "per-level DMA tables_ref.at[level] ('Slice shape along dimension 2 "
+    "must be aligned to tiling (128), but is 2': the (T, F=2) tables pad "
+    "to 128 lanes). Use march_backend='reference'.")
 
 
 def _pad_rows(x, mult):
@@ -65,18 +77,15 @@ def grid_meta(cfg) -> jnp.ndarray:
     return jnp.asarray(rows, jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _hash_encode_padded(points_padded, meta, tables, interpret=INTERPRET):
-    return HE.hash_encode_call(points_padded, meta, tables, interpret)
+_hash_encode_padded = jax.jit(HE.hash_encode_call)
 
 
-def hash_encode(points, tables, cfg, interpret: bool = INTERPRET):
+def hash_encode(points, tables, cfg):
     """points (N,3) in [0,1] -> encoding (N, L*F), matching hashgrid.encode."""
     n = points.shape[0]
     pts = _pad_cols(points.astype(jnp.float32), HE.PPAD)
     pts, _ = _pad_rows(pts, HE.TILE)
-    feats = _hash_encode_padded(pts, grid_meta(cfg), tables,
-                                interpret=interpret)     # (L, Np, F)
+    feats = _hash_encode_padded(pts, grid_meta(cfg), tables)
     feats = feats[:, :n]                                  # strip row pad
     L, _, F = feats.shape
     return jnp.transpose(feats, (1, 0, 2)).reshape(n, L * F)
@@ -124,7 +133,13 @@ _PACK_STATS = {"hits": 0, "misses": 0}
 
 
 def packed_weights(params: Dict, cfg: mlp_lib.MLPConfig):
-    """Memoized ``(wd, wc)`` padded weight stacks for an mlps param dict."""
+    """Memoized ``(wd, wc)`` padded weight stacks for an mlps param dict.
+
+    Traced weights (params passed into a jit as arguments) are packed
+    inside the trace and never cached: a tracer's id names nothing after
+    its trace ends."""
+    if isinstance(params["density"][0], jax.core.Tracer):
+        return pack_density_weights(params, cfg), pack_color_weights(params)
     key = (tuple(id(w) for w in params["density"]),
            tuple(id(w) for w in params["color"]), cfg.geo_feature_dim)
     with _PACK_LOCK:
@@ -134,8 +149,11 @@ def packed_weights(params: Dict, cfg: mlp_lib.MLPConfig):
             _PACK_STATS["hits"] += 1
             return hit[0], hit[1]
         _PACK_STATS["misses"] += 1
-    wd = pack_density_weights(params, cfg)
-    wc = pack_color_weights(params)
+    # concrete weights pack eagerly even when the first call comes from
+    # inside a trace: a staged pack would cache that trace's tracers
+    with jax.ensure_compile_time_eval():
+        wd = pack_density_weights(params, cfg)
+        wc = pack_color_weights(params)
     with _PACK_LOCK:
         _PACK_CACHE[key] = (wd, wc,
                             list(params["density"]), list(params["color"]))
@@ -158,13 +176,11 @@ def _sh_padded(dirs, cfg: mlp_lib.MLPConfig):
     return buf.at[:, cfg.geo_feature_dim : cfg.geo_feature_dim + sh.shape[1]].set(sh)
 
 
-@partial(jax.jit, static_argnames=("geo_dim", "interpret"))
-def _fused_field_padded(enc, sh, wd, wc, geo_dim, interpret=INTERPRET):
-    return FM.fused_field_call(enc, sh, wd, wc, geo_dim, interpret)
+_fused_field_padded = jax.jit(FM.fused_field_call,
+                              static_argnames=("geo_dim",))
 
 
-def fused_field(enc, dirs, params: Dict, cfg: mlp_lib.MLPConfig,
-                interpret: bool = INTERPRET):
+def fused_field(enc, dirs, params: Dict, cfg: mlp_lib.MLPConfig):
     """(enc (N,D), dirs (N,3)) -> (sigma (N,), rgb (N,3), geo (N,G))."""
     n = enc.shape[0]
     G = cfg.geo_feature_dim
@@ -172,48 +188,41 @@ def fused_field(enc, dirs, params: Dict, cfg: mlp_lib.MLPConfig,
     encp, _ = _pad_rows(encp, FM.TILE)
     shp, _ = _pad_rows(_sh_padded(dirs, cfg), FM.TILE)
     wd, wc = packed_weights(params, cfg)
-    out = _fused_field_padded(encp, shp, wd, wc, G, interpret=interpret)[:n]
+    out = _fused_field_padded(encp, shp, wd, wc, geo_dim=G)[:n]
     return out[:, 0], out[:, 1:4], out[:, 4 : 4 + G]
 
 
-@partial(jax.jit, static_argnames=("geo_dim", "interpret"))
-def _density_padded(enc, wd, geo_dim, interpret=INTERPRET):
-    return FM.density_call(enc, wd, geo_dim, interpret)
+_density_padded = jax.jit(FM.density_call, static_argnames=("geo_dim",))
 
 
-def density_mlp(enc, params: Dict, cfg: mlp_lib.MLPConfig,
-                interpret: bool = INTERPRET):
+def density_mlp(enc, params: Dict, cfg: mlp_lib.MLPConfig):
     """enc (N, D) -> (sigma (N,), geo (N, G))."""
     n = enc.shape[0]
     G = cfg.geo_feature_dim
     encp = _pad_cols(enc.astype(jnp.float32), FM.P)
     encp, _ = _pad_rows(encp, FM.TILE)
     wd, _wc = packed_weights(params, cfg)
-    out = _density_padded(encp, wd, G, interpret=interpret)[:n]
+    out = _density_padded(encp, wd, geo_dim=G)[:n]
     return out[:, 0], out[:, 1 : 1 + G]
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _color_padded(cin, wc, interpret=INTERPRET):
-    return FM.color_call(cin, wc, interpret)
+_color_padded = jax.jit(FM.color_call)
 
 
-def color_mlp(geo, dirs, params: Dict, cfg: mlp_lib.MLPConfig,
-              interpret: bool = INTERPRET):
+def color_mlp(geo, dirs, params: Dict, cfg: mlp_lib.MLPConfig):
     """(geo (N,G), dirs (N,3)) -> rgb (N,3)."""
     n = geo.shape[0]
     G = cfg.geo_feature_dim
     cin = _sh_padded(dirs, cfg).at[:, :G].set(geo.astype(jnp.float32))
     cin, _ = _pad_rows(cin, FM.TILE)
     _wd, wc = packed_weights(params, cfg)
-    out = _color_padded(cin, wc, interpret=interpret)[:n]
+    out = _color_padded(cin, wc)[:n]
     return out[:, :3]
 
 
 # -------------------------------------------------------------- volume render
 def volume_render(sigmas, anchor_colors, deltas, group: int,
-                  valid=None, white_background: bool = True,
-                  interpret: bool = INTERPRET):
+                  valid=None, white_background: bool = True):
     """Decoupled compositing. sigmas/deltas (R,S), anchors (R,A,3) with
     A = ceil(S/group) -> (rgb (R,3), acc (R,))."""
     R, S = sigmas.shape
@@ -234,7 +243,7 @@ def volume_render(sigmas, anchor_colors, deltas, group: int,
     anch, _ = _pad_rows(anch, VR.RTILE)
     E = VR.expansion_matrix(S, s_pad, A, a_pad, group)
 
-    out = VR.volume_render_call(sig, dlt, anch, E, a_pad, interpret)[:R]
+    out = VR.volume_render_call(sig, dlt, anch, E, a_pad)[:R]
     acc = out[:, 0]
     rgb = out[:, 1:4]
     if white_background:
@@ -259,12 +268,11 @@ class FusedMarchResources:
     call for the params.
     """
 
-    def __init__(self, params: Dict, cfg, interpret: bool = INTERPRET):
+    def __init__(self, params: Dict, cfg):
         self.meta = grid_meta(cfg.grid)
         self.tables = params["grid"].astype(jnp.float32)
         self.wd, self.wc = packed_weights(params["mlps"], cfg.net)
         self.net = cfg.net
-        self.interpret = interpret
 
 
 def fused_march_vmem_bytes(acfg, res: FusedMarchResources,
@@ -328,17 +336,21 @@ def fused_march_blocks(res: FusedMarchResources, acfg, o_b, d_b, budgets,
     and placed at the color-input lanes.  Table supply (VMEM-resident
     stack vs double-buffered DMA streaming) resolves per config via
     ``_select_streaming`` — the two are bit-identical where both run.
+
+    Runs only where Pallas interprets (the CPU): on a TPU it raises
+    ``ValueError(FUSED_MARCH_TPU_REFUSAL)`` rather than interpret there.
     """
+    if not resolve_interpret():
+        raise ValueError(FUSED_MARCH_TPU_REFUSAL)
     N, B, _ = o_b.shape
     o8 = _pad_cols(o_b.astype(jnp.float32).reshape(N * B, 3), FMA.PPAD)
     d_flat = d_b.astype(jnp.float32).reshape(N * B, 3)
     d8 = _pad_cols(d_flat, FMA.PPAD)
     sh = (jnp.zeros((N * B, FMA.P), jnp.float32)
           if density_only else _sh_padded(d_flat, res.net))
-    bud = jnp.zeros((N, 8), jnp.int32).at[:, 0].set(
-        budgets.astype(jnp.int32))
     out = FMA.fused_march_call(
-        o8, d8, sh, bud, res.meta, res.tables, res.wd, res.wc,
+        o8, d8, sh, budgets.astype(jnp.int32), res.meta, res.tables,
+        res.wd, res.wc,
         block_size=B, geo_dim=res.net.geo_feature_dim, group=acfg.group,
         chunk=acfg.chunk, near=scene.NEAR, far=scene.FAR,
         log_eps_t=math.log(rendering.EARLY_TERM_TRANSMITTANCE),
@@ -346,8 +358,7 @@ def fused_march_blocks(res: FusedMarchResources, acfg, o_b, d_b, budgets,
         white_background=acfg.white_background,
         with_color=not density_only,
         stream_tables=_select_streaming(acfg, res),
-        per_ray_exit=getattr(acfg, "per_ray_early_exit", False),
-        interpret=res.interpret)
+        per_ray_exit=getattr(acfg, "per_ray_early_exit", False))
     out = out.reshape(N, B, FMA.OUT_W)
     acc = out[:, :, 0]
     rgb = out[:, :, 1:4]
@@ -361,12 +372,16 @@ def fused_march_blocks(res: FusedMarchResources, acfg, o_b, d_b, budgets,
 def field_fns(params: Dict, cfg) -> FieldFns:
     """Kernel-backed FieldFns (cfg is core.model.NGPConfig).
 
-    Carries FusedMarchResources so ``ASDRConfig.march_backend="fused"``
-    routes Phase II through the single-kernel streaming march.
+    The hash encode is the XLA gather (``core.hashgrid.encode``) on every
+    platform, feeding the fused-MLP kernels: the Pallas encode kernel does
+    not compile for TPU (see hash_encode.py).  Carries
+    FusedMarchResources so ``ASDRConfig.march_backend="fused"`` routes
+    Phase II through the single-kernel streaming march where Pallas
+    interprets, and ``Bound`` so jitted callers pass params as arguments.
     """
 
     def density(points):
-        enc = hash_encode(points, params["grid"], cfg.grid)
+        enc = hashgrid.encode(points, params["grid"], cfg.grid)
         sigma, geo = density_mlp(enc, params["mlps"], cfg.net)
         inside = jnp.all((points >= 0.0) & (points <= 1.0), axis=-1)
         return jnp.where(inside, sigma, 0.0), geo
@@ -375,4 +390,5 @@ def field_fns(params: Dict, cfg) -> FieldFns:
         return color_mlp(geo, dirs, params["mlps"], cfg.net)
 
     return FieldFns(density=density, color=color,
-                    fused=FusedMarchResources(params, cfg))
+                    fused=FusedMarchResources(params, cfg),
+                    bound=Bound(field_fns, cfg, params))

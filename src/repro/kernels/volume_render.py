@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 P = 128        # output lane width
 RTILE = 128    # rays per block program
 
@@ -65,8 +67,7 @@ def _vr_kernel(sig_ref, del_ref, col_ref, e_ref, out_ref, *, a_pad):
     out_ref[...] = packed
 
 
-def volume_render_call(sigmas, deltas, anchors, E, a_pad: int,
-                       interpret: bool = True):
+def volume_render_call(sigmas, deltas, anchors, E, a_pad: int):
     """sigmas/deltas (R, S_pad), anchors (R, 3*A_pad), E (A_pad, S_pad)
     -> packed (R, P) with col0 = acc, cols 1..3 = rgb."""
     R, S_pad = sigmas.shape
@@ -83,5 +84,5 @@ def volume_render_call(sigmas, deltas, anchors, E, a_pad: int,
         ],
         out_specs=pl.BlockSpec((RTILE, P), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, P), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(sigmas, deltas, anchors, E)

@@ -48,14 +48,6 @@ def cell_is_skipped(arch: str, shape: str) -> bool:
     return shape == "long_500k" and arch not in LONG_OK
 
 
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict or (older jax) [dict]."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _spec(axes, rules, mesh):
     return NamedSharding(mesh, rules_lib.resolve_spec(axes, rules, mesh))
 
@@ -216,7 +208,7 @@ def run_asdr_cell(shape_name: str, multi_pod: bool, variant="baseline"):
     compiled = lowered.compile()
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = roofline.collective_bytes(
         compiled.as_text(), body_multiplier=extra.get("scan_multiplier", 1))
     flops = float(cost.get("flops", 0.0)) * extra.get("scan_multiplier", 1)
@@ -275,7 +267,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     mult = extra.get("scan_multiplier", 1)
     coll = roofline.collective_bytes(hlo, body_multiplier=mult)

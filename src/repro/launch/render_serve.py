@@ -348,6 +348,8 @@ def main():
                          "--scenecache-mb; >1 uses the fleet-shared "
                          "ShardedSceneCache routed by key bytes)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.dryrun:
         _dryrun(args.multi_pod)
     else:

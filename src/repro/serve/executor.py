@@ -234,6 +234,10 @@ class DeviceExecutor(_FutureExecutor):
     runs under ``jax.default_device(dev)``, so its jitted probe/warp
     computations compile and execute on that device; its result arrays
     transfer to device 0 implicitly when the commit path consumes them.
+    A neural field's params enter the jitted probe as arguments
+    (``fields.unbind``); built by ordinary jnp ops they are uncommitted,
+    so each probe copies them to ``dev`` (params committed to one device
+    with ``jax.device_put`` would pin the probe there).
 
     Determinism: host platform devices share one codegen, so a probe
     executed on device k is bit-identical to the same probe on device 0
@@ -262,8 +266,14 @@ class DeviceExecutor(_FutureExecutor):
                                thread_name_prefix=f"serve-dev{i}")
             for i in range(len(self.devices))]
         self._rr = 0
+        # closures executed per secondary device (a stolen take runs
+        # inline and is not counted); each slot is written only by its
+        # device's single queue thread
+        self.runs = [0] * len(self.devices)
 
-    def _run(self, dev, fn: Callable):
+    def _run(self, i, fn: Callable):
+        dev = self.devices[i]
+        self.runs[i] += 1
         with jax.default_device(dev):
             # device attr records the PLACEMENT; the lane (serve-dev*)
             # records the per-device queue that executed it
@@ -276,7 +286,7 @@ class DeviceExecutor(_FutureExecutor):
     def _spawn(self, key, fn: Callable) -> Future:
         i = self._rr % len(self.devices)
         self._rr += 1
-        return self._pools[i].submit(self._run, self.devices[i], fn)
+        return self._pools[i].submit(self._run, i, fn)
 
     def close(self):
         super().close()

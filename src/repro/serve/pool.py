@@ -39,31 +39,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import pipeline, scene
+from ..core import fields, pipeline, scene
 from ..obs import trace as trace_lib
 from ..scenecache import key as scenecache_key
 
 # jitted batched marches shared across engine instances: keyed by the
-# (FieldFns, ASDRConfig) pair (both hashable), so an engine restart or a
-# parallel engine over the same scene reuses the compiled executable.
-# LRU-bounded: a reloaded/retrained scene makes fresh FieldFns closures,
-# and without eviction the stale executables (and the params their
-# closures capture) would pile up for the process lifetime.
-# Locked: a fleet runs engine REPLICAS on separate threads (one engine
-# thread each, benchmarks/render_fleet.py), and they share this cache.
-# NOTE: the march closes over fns — fine for analytic fields (no arrays);
-# an NGP-backed production path should pass params as jit ARGS instead,
-# which is exactly what launch/render_serve.build_pooled_march_cell does.
+# field's static key (fields.unbind: (build, cfg) for param-backed fields,
+# the FieldFns itself for analytic ones), the ASDRConfig and the density
+# flag — so an engine restart, a parallel engine over the same scene, or
+# another scene of the same config reuses the compiled executable.
+# Params are jit ARGUMENTS, never closed-over constants: a closure would
+# bake each scene's tables (64 MB at the paper config) into its own
+# executable.  LRU-bounded and locked: a fleet runs engine REPLICAS on
+# separate threads (benchmarks/render_fleet.py) that share this cache.
 _MARCH_CACHE: OrderedDict = OrderedDict()
 _MARCH_CACHE_MAX = 32
 _MARCH_CACHE_LOCK = threading.Lock()
 
 
+def _march(fkey, acfg, density_only, params, o_b, d_b, budgets):
+    return pipeline.march_blocks(fields.rebind(fkey, params), acfg, o_b,
+                                 d_b, budgets, density_only=density_only)
+
+
 def batched_march(fns, acfg, density_only: bool = False):
-    """One jitted (N, B)-block march per (field, config, density flag) —
-    LRU-shared across engine instances AND fleet replica threads (the
-    lock covers only the OrderedDict bookkeeping; jax.jit itself is
-    thread-safe and compilation happens lazily at the first call).
+    """The jitted (N, B)-block march for a field: ``march(o_b, d_b,
+    budgets)`` — LRU-shared across engine instances AND fleet replica
+    threads (the lock covers only the OrderedDict bookkeeping; jax.jit
+    itself is thread-safe and compilation happens lazily at the first
+    call).
 
     Routes through ``pipeline.march_blocks``, so a FieldFns carrying
     fused-march resources under ``march_backend="fused"`` compiles the
@@ -72,16 +76,16 @@ def batched_march(fns, acfg, density_only: bool = False):
     entirely (rgb reads zero) — the cheap acc/depth refresh for rays
     whose radiance came from the warp/radiance tiers.
     """
-    key = (fns, acfg, density_only)
+    fkey, params = fields.unbind(fns)
+    key = (fkey, acfg, density_only)
     with _MARCH_CACHE_LOCK:
         if key not in _MARCH_CACHE:
-            _MARCH_CACHE[key] = jax.jit(partial(
-                pipeline.march_blocks, fns, acfg,
-                density_only=density_only))
+            _MARCH_CACHE[key] = jax.jit(partial(_march, fkey, acfg,
+                                                density_only))
             while len(_MARCH_CACHE) > _MARCH_CACHE_MAX:
                 _MARCH_CACHE.popitem(last=False)
         _MARCH_CACHE.move_to_end(key)
-        return _MARCH_CACHE[key]
+        return partial(_MARCH_CACHE[key], params)
 
 
 @dataclasses.dataclass

@@ -5,16 +5,8 @@ benches must see 1 device; multi-device tests spawn subprocesses that set
 their own count) — but the fast tier DOES append
 ``--xla_backend_optimization_level=0`` below, and child processes inherit
 it unless they overwrite XLA_FLAGS (the subprocess tests do).
-
-When `hypothesis` is unavailable (it is not baked into the container), a
-minimal deterministic stand-in is installed into ``sys.modules`` before
-collection so the property tests still run: ``@given`` sweeps a small
-evenly-spaced subset of the strategy product instead of random sampling.
-Install the real package via requirements-dev.txt for full randomized runs.
 """
 import os
-import sys
-import types
 
 
 # Cheap XLA backend codegen for the fast tier (~20% less compile time on
@@ -27,96 +19,6 @@ if os.environ.get("REPRO_FAST_TESTS", "1") != "0":
     if "--xla_backend_optimization_level" not in _flags:
         os.environ["XLA_FLAGS"] = (
             _flags + " --xla_backend_optimization_level=0").strip()
-
-
-def _install_hypothesis_fallback():
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    import functools
-    import itertools
-
-    class _Strategy:
-        def __init__(self, examples):
-            self._examples = list(examples)
-
-        def examples(self):
-            return self._examples
-
-    def integers(min_value=0, max_value=100):
-        span = max_value - min_value
-        vals = {min_value, max_value, min_value + span // 2,
-                min_value + span // 3, min_value + (2 * span) // 3}
-        return _Strategy(sorted(vals))
-
-    def sampled_from(seq):
-        return _Strategy(seq)
-
-    def floats(min_value=0.0, max_value=1.0, **_kw):
-        mid = 0.5 * (min_value + max_value)
-        return _Strategy([min_value, mid, max_value])
-
-    def booleans():
-        return _Strategy([False, True])
-
-    def just(value):
-        return _Strategy([value])
-
-    class settings:  # noqa: N801 — mirrors hypothesis' API
-        def __init__(self, max_examples=10, deadline=None, **_kw):
-            self.max_examples = max_examples
-
-        def __call__(self, fn):
-            fn._hyp_max_examples = self.max_examples
-            return fn
-
-    def given(*strategies):
-        def deco(fn):
-            import inspect
-
-            sig = inspect.signature(fn)
-            names = list(sig.parameters)
-            # like hypothesis, positional strategies fill params from the
-            # right; anything left of them stays a pytest fixture
-            bound = names[len(names) - len(strategies):]
-            free = [sig.parameters[p] for p in names[:len(names) - len(strategies)]]
-
-            def wrapper(*args, **kw):
-                combos = list(itertools.product(
-                    *[s.examples() for s in strategies]))
-                # the fallback is a deterministic sweep, not a randomized
-                # search — 5 spread examples bound the fast tier's runtime
-                n = min(getattr(wrapper, "_hyp_max_examples", 10), 5)
-                if len(combos) > n:  # even subsample, endpoints included
-                    step = (len(combos) - 1) / (n - 1) if n > 1 else 0
-                    combos = [combos[round(i * step)] for i in range(n)]
-                for combo in combos:
-                    fn(*args, **kw, **dict(zip(bound, combo)))
-
-            functools.update_wrapper(wrapper, fn)
-            del wrapper.__wrapped__  # keep pytest from seeing fn's params
-            wrapper.__signature__ = sig.replace(parameters=free)
-            return wrapper
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    mod.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
-    st_mod = types.ModuleType("hypothesis.strategies")
-    for name, obj in [("integers", integers), ("sampled_from", sampled_from),
-                      ("floats", floats), ("booleans", booleans),
-                      ("just", just)]:
-        setattr(st_mod, name, obj)
-    mod.strategies = st_mod
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st_mod
-
-
-_install_hypothesis_fallback()
 
 
 def pytest_configure(config):

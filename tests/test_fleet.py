@@ -171,6 +171,26 @@ def test_stage_a_lands_on_secondary_devices():
     assert 0 not in placed
     expected = [d.id for d in jax.devices()[1:]]
     assert placed == expected * 2, f"round-robin broken: {placed}"
+    assert ex.runs == [2] * len(ex.devices), ex.runs
+
+
+def test_param_field_probe_runs_on_secondary_device():
+    """A probe of a param-backed field (params passed to the jitted probe
+    as arguments) placed by the DeviceExecutor computes on the secondary
+    device, its params copied there, and equals the probe on device 0."""
+    from repro.configs.ingp_asdr import SMOKE
+    from repro.core import model as model_lib
+    params = model_lib.init_ngp(jax.random.PRNGKey(0), SMOKE.model)
+    fns = model_lib.field_fns(params, SMOKE.model)
+    cam = cam_at(0.6)
+    want = np.asarray(pipeline.probe_phase(fns, ACFG, cam)[0])
+    ex = executor_lib.DeviceExecutor()
+    ex.submit(0, lambda: pipeline.probe_phase(fns, ACFG, cam)[0])
+    got = ex.take(0)
+    ex.close()
+    assert ex.runs[0] == 1
+    assert got.devices() == {ex.devices[0]}
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_single_device_fallback(flds, monkeypatch):

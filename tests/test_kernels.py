@@ -144,3 +144,45 @@ def test_kernel_field_fns_drive_full_pipeline(model):
     cm = mf.color(gm, dirs)
     np.testing.assert_allclose(np.asarray(ck), np.asarray(cm),
                                rtol=1e-4, atol=1e-6)
+
+
+def _pallas_interpret_flags(fn, *args):
+    # fn must be a fresh function: a cached trace would keep the flag of
+    # the platform it was first traced under
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return [e.params["interpret"] for e in jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("on_cpu", [True, False])
+def test_interpret_mode_follows_platform(model, monkeypatch, on_cpu):
+    """Every kernel interprets exactly on the CPU and lowers compiled on
+    any other backend; the fused march refuses to run off the CPU."""
+    from repro import kernels
+    from repro.core import pipeline
+    from repro.kernels import fused_mlp as FM, hash_encode as HE
+    assert kernels.on_cpu() == (jax.default_backend() == "cpu")
+    monkeypatch.setattr(kernels, "on_cpu", lambda: on_cpu)
+    assert kernels.resolve_interpret() is on_cpu
+    cfg, params = model
+    wd, wc = ops.packed_weights(params["mlps"], cfg.net)
+    x = jnp.zeros((FM.TILE, FM.P))
+    pts = jnp.zeros((HE.TILE, HE.PPAD))
+    for fn, args in [
+            (lambda e, w: FM.density_call(e, w, cfg.net.geo_feature_dim),
+             (x, wd)),
+            (lambda c, w: FM.color_call(c, w), (x, wc)),
+            (lambda p, t: HE.hash_encode_call(p, ops.grid_meta(cfg.grid), t),
+             (pts, params["grid"]))]:
+        flags = _pallas_interpret_flags(fn, *args)
+        assert flags and all(bool(f) is on_cpu for f in flags), flags
+    acfg = pipeline.ASDRConfig(ns_full=16, block_size=8, chunk=8,
+                               candidates=(4, 8), march_backend="fused")
+    rays = jnp.zeros((1, 8, 3))
+    args = (ops.field_fns(params, cfg), acfg, rays, rays,
+            jnp.ones((1,), jnp.int32))
+    if on_cpu:
+        pipeline.march_blocks(*args)
+    else:
+        with pytest.raises(ValueError, match="does not compile for TPU"):
+            pipeline.march_blocks(*args)

@@ -69,3 +69,50 @@ def test_block_unsort_roundtrip(setup):
     # same per-ray sample count, same order -> close colors per ray
     err = float(jnp.max(jnp.abs(rgb - ref)))
     assert err < 1e-3  # same sampling grid, same order
+
+
+def _unblocked_probe(fns, acfg, cam, key):
+    """Phase I as one render_fixed_fns call over every probe ray."""
+    from repro.core import adaptive
+    H, W = cam.height, cam.width
+    o, d = scene.camera_rays(cam)
+    jj, ii = jnp.meshgrid(jnp.arange(0, H, acfg.probe_stride),
+                          jnp.arange(0, W, acfg.probe_stride), indexing="ij")
+    idx = (jj * W + ii).reshape(-1)
+    hw = (jj.shape[0], jj.shape[1])
+    rgb, aux = pipeline.render_fixed_fns(fns, o[idx], d[idx], acfg.ns_full,
+                                         key)
+    pc = adaptive.probe_counts(aux["sigmas"], aux["colors"], rgb,
+                               acfg.ns_full, acfg.candidates, acfg.delta)
+    t = rendering.expected_termination_depth(aux["weights"], aux["ts"],
+                                             aux["acc"], scene.FAR)
+    return (adaptive.interpolate_counts(pc, hw, (H, W), acfg.candidates,
+                                        acfg.ns_full),
+            adaptive.interpolate_map(aux["acc"], hw, (H, W)),
+            adaptive.interpolate_map(t, hw, (H, W)))
+
+
+@pytest.mark.parametrize("keyed", [False, True])
+def test_blocked_probe_equals_unblocked(keyed):
+    """The jitted, ray-blocked probe gives the one-call probe's maps at
+    SMOKE size (blocks do not divide the probe rays: pads are cropped)."""
+    import jax
+    import numpy as np
+    from repro.configs.ingp_asdr import SMOKE
+    from repro.core import model as model_lib
+    params = model_lib.init_ngp(jax.random.PRNGKey(0), SMOKE.model)
+    params = dict(params, grid=params["grid"] * 1e3)
+    fns = model_lib.field_fns(params, SMOKE.model)
+    cam = scene.look_at_camera(44, 44, theta=0.6, phi=0.5)  # 11x11 probes
+    key = jax.random.PRNGKey(3) if keyed else None
+    counts, cost, opacity, depth = pipeline.probe_phase(
+        fns, SMOKE.asdr, cam, key, return_opacity=True, return_depth=True)
+    want = _unblocked_probe(fns, SMOKE.asdr, cam, key)
+    rays_per_block = (SMOKE.asdr.block_size * SMOKE.asdr.chunk
+                      // SMOKE.asdr.ns_full)
+    assert 121 % rays_per_block and cost == 121 * SMOKE.asdr.ns_full
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want[0]))
+    np.testing.assert_allclose(np.asarray(opacity), np.asarray(want[1]),
+                               rtol=0, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(depth), np.asarray(want[2]),
+                               rtol=0, atol=1e-6)

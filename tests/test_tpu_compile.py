@@ -1,0 +1,148 @@
+"""Compile the serving path for a described TPU v5e, with no chip attached.
+
+The TPU compiler is installed with JAX and compiles for a topology that is
+described, not present: what it refuses here would be refused on the chip.
+These are compiler verdicts, not chip runs — they say nothing of speed.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and test workers import
+every test file.  ``kernels.on_cpu`` is patched to False so the kernels
+lower compiled, as they do on a TPU; the choice is made at trace time, so
+traces cached under either choice are dropped around each test.
+"""
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import kernels
+from repro.configs.ingp_asdr import CONFIG
+from repro.core import model as model_lib, pipeline, scene
+from repro.kernels import fused_march as FMA
+from repro.kernels import fused_mlp as FM
+from repro.kernels import hash_encode as HE
+from repro.kernels import ops
+
+HBM_BYTES = 16 * 10 ** 9                  # one v5e chip
+ROWS = CONFIG.asdr.block_size * CONFIG.asdr.chunk   # samples per march step
+
+
+@pytest.fixture(scope="module")
+def chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    pytest.importorskip("libtpu", reason="no TPU compiler installed")
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+@pytest.fixture
+def compiled(monkeypatch):
+    jax.clear_caches()
+    monkeypatch.setattr(kernels, "on_cpu", lambda: False)
+    yield
+    jax.clear_caches()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _params(sharding):
+    shapes = jax.eval_shape(
+        lambda: model_lib.init_ngp(jax.random.PRNGKey(0), CONFIG.model))
+    return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), shapes)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("kernel", ["density_call", "color_call"])
+def test_fused_mlp_kernels_compile_at_paper_widths(chip, compiled, kernel):
+    params = _params(chip)["mlps"]
+    n = len(params["density"]) if kernel == "density_call" else len(
+        params["color"])
+    enc = _spec(chip, (ROWS, FM.P))
+    w = _spec(chip, (n, FM.P, FM.P))
+    geo = CONFIG.model.net.geo_feature_dim
+    call = (partial(FM.density_call, geo_dim=geo) if kernel == "density_call"
+            else FM.color_call)
+    exe = _compile(call, enc, w)
+    assert "tpu_custom_call" in exe.as_text()
+
+
+def test_reference_march_block_compiles(chip, compiled):
+    """One 4096-ray block of the engine's march: XLA-gather encode feeding
+    the compiled fused-MLP kernels."""
+    cfg, acfg = CONFIG.model, CONFIG.asdr
+    B = acfg.block_size
+
+    def march(params, o, d, budget):
+        return pipeline._march_block(ops.field_fns(params, cfg), acfg, o, d,
+                                     budget)
+
+    exe = _compile(march, _params(chip), _spec(chip, (B, 3)),
+                   _spec(chip, (B, 3)), _spec(chip, (), jnp.int32))
+    assert "tpu_custom_call" in exe.as_text()
+    assert exe.memory_analysis().temp_size_in_bytes < HBM_BYTES // 2
+
+
+def test_blocked_probe_fits_one_chip(chip, compiled):
+    """The Phase-I probe of an 800x800 frame at 192 samples (4.9M samples)
+    marched in blocks: its temporaries stay well under one chip's HBM."""
+    cfg, acfg = CONFIG.model, CONFIG.asdr
+    H, W = CONFIG.image_hw
+    cam = scene.look_at_camera(H, W, 0.6, 0.5)
+    fkey = (ops.field_fns, cfg)      # fields.unbind's key for ops fields
+    probe = partial(pipeline._probe_maps.__wrapped__, fkey, acfg,
+                    (H, W, cam.focal))
+    exe = _compile(lambda p, rot, org: probe(p, rot, org, None),
+                   _params(chip), _spec(chip, (3, 3)), _spec(chip, (3,)))
+    temps = exe.memory_analysis().temp_size_in_bytes
+    assert temps < HBM_BYTES // 4, temps
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError,
+                   reason="Mosaic refuses the VMEM vector gather table[idx] "
+                          "in encode_level: 'Shape mismatch in input, "
+                          "indices and output'")
+def test_hash_encode_kernel_compiles(chip, compiled):
+    g = CONFIG.model.grid
+    _compile(HE.hash_encode_call,
+             _spec(chip, (ROWS, HE.PPAD)),
+             _spec(chip, (g.n_levels, 8), jnp.int32),
+             _spec(chip, (g.n_levels, g.table_size, g.feature_dim)))
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError,
+                   reason="Mosaic refuses the in-kernel gather table[idx] "
+                          "('Shape mismatch in input, indices and output'); "
+                          "behind it the streamed per-level DMA is refused "
+                          "('Slice shape along dimension 2 must be aligned "
+                          "to tiling (128), but is 2')")
+def test_fused_march_kernel_compiles(chip, compiled):
+    cfg, acfg = CONFIG.model, CONFIG.asdr
+    g, B, N = cfg.grid, acfg.block_size, 16
+    mlps = _params(chip)["mlps"]
+    call = partial(FMA.fused_march_call, block_size=B,
+                   geo_dim=cfg.net.geo_feature_dim, group=acfg.group,
+                   chunk=acfg.chunk, near=scene.NEAR, far=scene.FAR,
+                   log_eps_t=-9.2, early_term=True, white_background=True,
+                   with_color=True, stream_tables=True)
+    _compile(call,
+             _spec(chip, (N * B, FMA.PPAD)), _spec(chip, (N * B, FMA.PPAD)),
+             _spec(chip, (N * B, FMA.P)), _spec(chip, (N,), jnp.int32),
+             _spec(chip, (g.n_levels, 8), jnp.int32),
+             _spec(chip, (g.n_levels, g.table_size, g.feature_dim)),
+             _spec(chip, (len(mlps["density"]), FMA.P, FMA.P)),
+             _spec(chip, (len(mlps["color"]), FMA.P, FMA.P)))
