@@ -246,7 +246,7 @@ def per_ray_exit_section(args):
         pool = pool_lib.BlockPool(acfg, 1, None, counters)
         out = ops.fused_march_blocks(fns.fused, acfg, o_b, d_b, budgets)
         pool.collect(([(_Slot(), 0, None, None, 192, None, None, False)],
-                      [], 0, out, 1, None, time.time()))
+                      [], 0, out, 1))
         skipped = counters.ray_exit_samples_skipped
         if name == "off":
             assert skipped == 0, "counter must stay gated off"

@@ -156,18 +156,19 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
             col_a = fns.color(geo_a, dirs_a).reshape(B, A, 3)
             colors = decouple.interpolate_group_colors(col_a, acfg.group, C)
 
-        alphas = rendering.alphas_from_sigmas(sigma, delta_t)
-        one_m = jnp.clip(1.0 - alphas, 1e-10, 1.0)
-        log_steps = jnp.log(one_m)
-        # transmittance inside the chunk, carried from previous chunks
-        intra = jnp.cumsum(log_steps, axis=-1) - log_steps  # exclusive
-        trans = jnp.exp(log_t[:, None] + intra)
-        w = trans * alphas
-        if not density_only:
-            rgb = rgb + jnp.sum(w[..., None] * colors, axis=1)
-        acc = acc + jnp.sum(w, axis=-1)
-        dep = dep + jnp.sum(w * ts[None, :], axis=-1)
-        log_t = log_t + jnp.sum(log_steps, axis=-1)
+        with jax.named_scope("composite"):
+            alphas = rendering.alphas_from_sigmas(sigma, delta_t)
+            one_m = jnp.clip(1.0 - alphas, 1e-10, 1.0)
+            log_steps = jnp.log(one_m)
+            # transmittance inside the chunk, carried from previous chunks
+            intra = jnp.cumsum(log_steps, axis=-1) - log_steps  # exclusive
+            trans = jnp.exp(log_t[:, None] + intra)
+            w = trans * alphas
+            if not density_only:
+                rgb = rgb + jnp.sum(w[..., None] * colors, axis=1)
+            acc = acc + jnp.sum(w, axis=-1)
+            dep = dep + jnp.sum(w * ts[None, :], axis=-1)
+            log_t = log_t + jnp.sum(log_steps, axis=-1)
         ray_chunks = ray_chunks + alive.astype(jnp.int32)
         return ci + 1, log_t, rgb, acc, dep, ray_chunks
 

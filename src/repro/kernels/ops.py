@@ -195,6 +195,7 @@ def fused_field(enc, dirs, params: Dict, cfg: mlp_lib.MLPConfig):
 _density_padded = jax.jit(FM.density_call, static_argnames=("geo_dim",))
 
 
+@jax.named_scope("density_mlp")
 def density_mlp(enc, params: Dict, cfg: mlp_lib.MLPConfig):
     """enc (N, D) -> (sigma (N,), geo (N, G))."""
     n = enc.shape[0]
@@ -209,6 +210,7 @@ def density_mlp(enc, params: Dict, cfg: mlp_lib.MLPConfig):
 _color_padded = jax.jit(FM.color_call)
 
 
+@jax.named_scope("color_mlp")
 def color_mlp(geo, dirs, params: Dict, cfg: mlp_lib.MLPConfig):
     """(geo (N,G), dirs (N,3)) -> rgb (N,3)."""
     n = geo.shape[0]

@@ -174,45 +174,3 @@ def stall_trigger(threshold_ms: float) -> Callable[[Span], bool]:
     def pred(s: Span) -> bool:
         return s.name == "admission.wait" and s.dur_ms > threshold_ms
     return pred
-
-
-def rate_trigger(name: str, count: int,
-                 window_ms: float) -> Callable[[Span], bool]:
-    """A BURST trigger: fires when the ``count``-th span named ``name``
-    lands within ``window_ms`` of the first of its sliding window.
-
-    Stateful by design: the closure keeps the last ``count`` matching
-    timestamps.  While the owning ``_Trigger`` is disarmed the recorder
-    never calls the predicate, so the window freezes and resumes on
-    ``rearm()`` — still one dump per breach episode."""
-    assert count >= 1
-    times: deque = deque(maxlen=count)
-
-    def pred(s: Span) -> bool:
-        if s.name != name:
-            return False
-        times.append(s.t0)
-        return (len(times) == count
-                and (times[-1] - times[0]) * 1e3 <= window_ms)
-    return pred
-
-
-def evict_storm_trigger(count: int, window_ms: float) -> Callable:
-    """Eviction storm: ``count`` scenecache evictions inside
-    ``window_ms`` — the cache is thrashing (budget too small for the
-    working set, or a scan-shaped workload)."""
-    return rate_trigger("scenecache.evict", count, window_ms)
-
-
-def shed_burst_trigger(count: int, window_ms: float) -> Callable:
-    """Shed burst: ``count`` scheduler degrade steps inside
-    ``window_ms`` — sustained overload, the shed policy is actively
-    trading quality for deadlines."""
-    return rate_trigger("scheduler.shed", count, window_ms)
-
-
-def trigger_path(base, tag: str) -> str:
-    """A trigger's own dump path: ``base`` with ``_tag`` suffixed to the
-    stem, so multiple armed triggers never clobber one file."""
-    p = Path(base)
-    return str(p.with_name(f"{p.stem}_{tag}{p.suffix}"))

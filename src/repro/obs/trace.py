@@ -16,6 +16,14 @@ deterministic counters are bit-identical with tracing on or off: spans
 only READ ids and clocks, never steer scheduling
 (tests/test_obs.py gates both properties).
 
+Profiler bridge: while a JAX profiler session is active
+(``jax.profiler.start_trace``), ``span()`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name and attrs (non-scalar
+attrs stringified), with or without a tracer installed — so the spans
+land on the profiler's host plane, on the same clock as the device ops,
+and a device trace's idle gaps are named after program phases.  With no
+profiler session and no tracer, ``span()`` is still ``NULL_SPAN``.
+
 Span identity: process-wide ids from one atomic counter; each span
 records its parent (the innermost open span on ITS thread), so a
 frame's lineage — admission -> stage_a -> probe/warp -> pool dispatch
@@ -32,7 +40,12 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+import jax
+
 from . import metrics as metrics_lib
+
+# true while a profiler session records host events (~25 ns a call)
+_profiling = jax.profiler.TraceAnnotation.is_enabled
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,15 +66,6 @@ class TraceConfig:
     # auto-arm a flight-recorder trigger: dump when an admission stall
     # span exceeds this many milliseconds (None = no auto trigger)
     stall_dump_ms: Optional[float] = None
-    # rate triggers (export.rate_trigger), each one-shot with rearm like
-    # the stall trigger, each dumping to its own suffixed flight path:
-    # an eviction storm is >= count scenecache.evict instants inside
-    # window_ms; a shed burst is the same over scheduler.shed instants.
-    # count 0 = trigger off.
-    evict_storm_count: int = 0
-    evict_storm_window_ms: float = 1000.0
-    shed_burst_count: int = 0
-    shed_burst_window_ms: float = 1000.0
     metrics_jsonl: Optional[str] = None  # periodic registry snapshots
     metrics_every: int = 16              # rounds between snapshots
     # cross-replica timeline identity: ``replica`` stamps every exported
@@ -147,6 +151,34 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+class _ProfiledSpan:
+    """A span while a profiler session is active: a TraceAnnotation on
+    the profiler's host plane, wrapping the installed tracer's span (if
+    any), which shares ``attrs``."""
+    __slots__ = ("name", "attrs", "_inner", "_annotation")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, attrs: Dict):
+        self.name = name
+        self.attrs = attrs
+        self._inner = None if tracer is None else _SpanCtx(tracer, name,
+                                                            attrs)
+
+    def __enter__(self):
+        self._annotation = jax.profiler.TraceAnnotation(self.name, **{
+            k: v if isinstance(v, (int, float, str)) else str(v)
+            for k, v in self.attrs.items()})
+        self._annotation.__enter__()
+        if self._inner is not None:
+            self._inner.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._inner is not None:
+            self._inner.__exit__(exc_type, exc, tb)
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
 
 
 class Tracer:
@@ -271,9 +303,12 @@ def active() -> Optional[Tracer]:
 
 
 def span(name: str, **attrs):
-    """The instrumented-call-site helper: a real span when a tracer is
-    installed, the shared NULL_SPAN singleton otherwise."""
+    """The instrumented-call-site helper: a profiler annotation while a
+    profiler session is active, a real span when a tracer is installed
+    (both at once when both hold), the shared NULL_SPAN otherwise."""
     t = _active
+    if _profiling():
+        return _ProfiledSpan(t, name, attrs)
     return NULL_SPAN if t is None else t.span(name, **attrs)
 
 

@@ -190,7 +190,7 @@ def prepare(engine, req: RenderRequest) -> Prepared:
     probe/warp maps, and the padded/sorted block layout — without
     touching any cache.  Pure, thread-safe (plans snapshot entry state
     under the cache locks), dispatchable while live requests march."""
-    t0 = time.time()
+    t0 = time.perf_counter()
     acfg: ASDRConfig = engine.acfg
     with trace_lib.span("stage_a.prepare", req=req.rid, scene=req.scene):
         rad = engine.radiance_caches.get(req.scene)
@@ -217,8 +217,8 @@ def prepare(engine, req: RenderRequest) -> Prepared:
                 dens_layout = pool_lib.build_density_layout(
                     acfg, req.cam, maps, warped, budget_scale=scale)
     return Prepared(req, rplan, pplan, maps, layout,
-                    _radiance_token(rplan), time.time() - t0, dens_layout,
-                    tier=tier)
+                    _radiance_token(rplan), time.perf_counter() - t0,
+                    dens_layout, tier=tier)
 
 
 def admit(engine, req: RenderRequest, prepared: Prepared,
@@ -365,10 +365,14 @@ class Slot:
             self.dens_depth = np.zeros((n_dens, block_size), np.float32)
             self.dens_chunks = np.zeros((n_dens,), np.int64)
         self.pending = n_blocks + n_dens
-        # latency clock starts at ENQUEUE (render() entry), not slot
-        # construction — latency_s must cover queue wait + admission
-        # (probe/warp) + march end-to-end under the double-buffered path
-        self.t0 = time.time() if t_enqueue is None else t_enqueue
+        # latency clock (time.perf_counter) starts when the frame is DUE
+        # (render() entry + arrival_s), not at slot construction —
+        # latency_s = queue_s + admit_stall_s + march_s + finalize_s:
+        # due -> admission start (t_admit) -> admission end -> last block
+        # delivered (t_delivered) -> finalized
+        self.t0 = time.perf_counter() if t_enqueue is None else t_enqueue
+        self.t_admit: Optional[float] = None     # set by the scheduler
+        self.t_delivered: Optional[float] = None
         self.admission_s = 0.0        # total Stage-A + Stage-B work time
         self.admit_stall_s = 0.0      # blocking admission time (Stage B
         #                               + any inline/awaited Stage A)
@@ -401,13 +405,18 @@ class Slot:
         if cached:
             self.cached_blocks += 1
             self.cached_chunks += int(chunks)
+        self._delivered_one()
+
+    def _delivered_one(self):
         self.pending -= 1
+        if self.pending == 0:
+            self.t_delivered = time.perf_counter()
 
     def deliver_density(self, bi: int, acc, depth, chunks):
         self.dens_acc[bi] = acc
         self.dens_depth[bi] = depth
         self.dens_chunks[bi] = chunks
-        self.pending -= 1
+        self._delivered_one()
 
     def finalize(self, acfg: ASDRConfig) -> RenderRequest:
         req = self.req
@@ -458,7 +467,12 @@ class Slot:
                 self.depth_full = None
             rays_marched = int(self.march_idx.size)
         req.image = img_flat.reshape(H, W, 3)
-        req.latency_s = time.time() - self.t0
+        t_done = time.perf_counter()
+        req.latency_s = t_done - self.t0
+        t_admit = self.t0 if self.t_admit is None else self.t_admit
+        t_admitted = t_admit + self.admit_stall_s
+        t_last = (t_admitted if self.t_delivered is None
+                  else self.t_delivered)
         # rays delivered straight from the warp: had they marched, the
         # fixed-budget baseline would have spent ns_full samples each —
         # the same convention baseline_samples uses — so zero-march
@@ -493,7 +507,13 @@ class Slot:
             # must too or the fraction inflates (and can exceed 1.0)
             "baseline_samples": Rp * acfg.ns_full,
             "admission_s": self.admission_s,
+            # where the latency went, on the latency clock: waiting for
+            # admission, the blocking admission, marching (admission end
+            # -> last block delivered), and finalizing
+            "queue_s": t_admit - self.t0,
             "admit_stall_s": self.admit_stall_s,
+            "march_s": t_last - t_admitted,
+            "finalize_s": t_done - t_last,
             # request-lifecycle accounting (serve/scheduler.py): the SLO
             # class this frame was served under, the tier it ENDED at,
             # how many degrade steps the scheduler applied, and whether

@@ -77,7 +77,7 @@ class ServingEngine:
     def _run_wave(self, wave: List[Request], plen: int) -> List[Request]:
         scfg = self.scfg
         B = len(wave)
-        t0 = time.time()
+        t0 = time.perf_counter()
         prompts = np.stack([r.prompt for r in wave]).astype(np.int32)
         batch = {"tokens": jnp.asarray(prompts)}
         logits, caches = self.api.prefill_fn(
@@ -97,7 +97,7 @@ class ServingEngine:
             outs.append(np.asarray(tok))
             pos += 1
         gen = np.concatenate(outs, axis=1)
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         for i, r in enumerate(wave):
             r.out = gen[i, : r.max_new]
             r.latency_s = dt

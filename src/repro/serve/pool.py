@@ -206,6 +206,9 @@ class BlockPool:
         self.counters = counters
         self.items: List[tuple] = []
         self._batch_seq = 0          # trace batch ids, per render() call
+        # host clock of the current round's first march launch (None
+        # until one launches): where the engine's march gap ends
+        self.first_launch_t: Optional[float] = None
 
     def __len__(self) -> int:
         return len(self.items)
@@ -320,7 +323,8 @@ class BlockPool:
     def dispatch_round(self, march_for, max_batches: int = 1):
         """The STREAMING scheduler: assemble and DISPATCH up to
         ``max_batches`` batches (device-async) for one round; returns the
-        in-flight handles for ``collect`` in dispatch order.
+        in-flight handles for ``collect`` in dispatch order, and stamps
+        ``first_launch_t`` just before the first launch.
 
         Each batch is drawn from the pool's current largest-budget
         (scene, density-flag) group, so batches stay budget- and
@@ -335,6 +339,7 @@ class BlockPool:
         group to its jitted batched march.
         """
         handles = []
+        self.first_launch_t = None
         with trace_lib.span("pool.dispatch_round",
                             pooled=len(self.items)):
             while self.items and len(handles) < max_batches:
@@ -378,8 +383,7 @@ class BlockPool:
         bid = self._batch_seq
         with trace_lib.span("pool.dispatch", batch=bid, scene=group[0],
                             density=group[1], blocks=len(batch),
-                            reqs=sorted({it[0].req.rid
-                                         for it in batch})) as sp:
+                            reqs=sorted({it[0].req.rid for it in batch})):
             B = self.acfg.block_size
             N = self.blocks_per_batch
             n_pad = N - len(batch)
@@ -391,22 +395,12 @@ class BlockPool:
             budgets = jnp.asarray([it[4] for it in batch] + [1] * n_pad,
                                   jnp.int32)
             # dispatch only — device arrays are fetched in collect(),
-            # after the engine has overlapped Stage-A speculation.
-            # With tracing on, the launch is bracketed with a jax
-            # profiler annotation so a device profile's timeline carries
-            # the same batch id as the host spans.
-            if trace_lib.active() is not None:
-                with jax.profiler.TraceAnnotation(f"fused_march.batch{bid}"):
-                    out = march_for(group[0], group[1])(o_b, d_b, budgets)
-            else:
-                out = march_for(group[0], group[1])(o_b, d_b, budgets)
-        # dispatch-span attrs dict + launch-end timestamp ride the handle:
-        # collect() stamps ``device_ms`` (launch -> arrays ready) back
-        # onto the already-closed span, splitting its host wall time into
-        # queue/assembly vs device execution at export.
-        disp_attrs = getattr(sp, "attrs", None)
-        return (batch, followers, n_pad, out, bid, disp_attrs,
-                time.perf_counter())
+            # after the engine has overlapped Stage-A speculation
+            march = march_for(group[0], group[1])
+            if self.first_launch_t is None:
+                self.first_launch_t = time.perf_counter()
+            out = march(o_b, d_b, budgets)
+        return batch, followers, n_pad, out, bid
 
     def collect(self, inflight):
         """Fetch a dispatched batch and deliver/store its outputs.
@@ -415,15 +409,12 @@ class BlockPool:
         per-batch march time the engine could not overlap; its ``batch``
         id matches the ``pool.dispatch`` span that launched it, so a
         frame's lineage chains admission -> dispatch -> collect."""
-        batch, followers, n_pad, out, bid, disp_attrs, t_launch = inflight
+        batch, followers, n_pad, out, bid = inflight
         with trace_lib.span("pool.collect", batch=bid,
                             blocks=len(batch),
                             reqs=sorted({it[0].req.rid for it in batch})):
             rgb, acc, depth, chunks, ray_chunks = (
                 np.asarray(a) for a in out)
-            if disp_attrs is not None:
-                disp_attrs["device_ms"] = (time.perf_counter()
-                                           - t_launch) * 1e3
             if self.acfg.per_ray_early_exit and batch:
                 # sample work the per-ray exit skipped: rays that went
                 # dead ride chunks - ray_chunks masked chunks each, at

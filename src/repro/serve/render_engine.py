@@ -139,7 +139,7 @@ class RenderServingEngine:
         consumes the prepared work with only the commit left to do.
         """
         rcfg = self.rcfg
-        t_enqueue = time.time()    # latency clock: queue wait counts
+        t_enqueue = time.perf_counter()   # latency clock: queue wait counts
         queue = list(requests)
         live: List[admission.Slot] = []
         done: List[RenderRequest] = []
@@ -157,6 +157,11 @@ class RenderServingEngine:
     def _serve(self, queue, live, done, pool, ex, t_enqueue):
         rcfg = self.rcfg
         sched = self.scheduler
+        c = self.counters
+        # the march gap: host time with no batch in flight, from entry
+        # (then from each round's last collect) to the next first launch,
+        # less the open-loop arrival sleeps inside it
+        gap_t0, waited0 = t_enqueue, c.arrival_wait_s
         while queue or live:
             # admission per the scheduler policy: FIFO by default (the
             # bit-identical pre-scheduler loop), EDF/shed opt-in — see
@@ -167,9 +172,12 @@ class RenderServingEngine:
             # streaming dispatch: up to inflight_batches batches launch
             # back-to-back (next group fills idle launches), ALL in
             # flight before any collect — see pool.dispatch_round
-            t_march = time.time()
+            t_march = time.perf_counter()
             inflights = pool.dispatch_round(
                 self._march_for, max(rcfg.inflight_batches, 1))
+            if inflights:
+                c.march_gap_s += (pool.first_launch_t - gap_t0
+                                  - (c.arrival_wait_s - waited0))
 
             # Stage-A prefetch: speculate admissions for the policy's
             # next arrived requests while the round is in flight
@@ -178,13 +186,14 @@ class RenderServingEngine:
             for inflight in inflights:
                 pool.collect(inflight)
             if inflights:
-                self.counters.note_round(time.time() - t_march,
-                                         len(inflights))
+                gap_t0, waited0 = time.perf_counter(), c.arrival_wait_s
+                c.note_round(gap_t0 - t_march, len(inflights))
 
             still = []
             for slot in live:
                 if slot.pending == 0:
-                    done.append(self._finalize(slot))
+                    with trace_lib.span("engine.finalize", req=slot.req.rid):
+                        done.append(self._finalize(slot))
                 else:
                     still.append(slot)
             live = still

@@ -182,7 +182,7 @@ class Scheduler:
         rcfg = engine.rcfg
         self._observe_depth(ex)
         while queue and len(live) < rcfg.slots:
-            now_rel = time.time() - t_enqueue
+            now_rel = time.perf_counter() - t_enqueue
             idx = self.policy.select(queue, now_rel)
             if idx is None:
                 if live:
@@ -192,7 +192,7 @@ class Scheduler:
             req = queue.pop(idx)
             if self.policy.shed:
                 self._maybe_shed(req, now_rel - req.arrival_s)
-            t0 = time.time()
+            t0 = time.perf_counter()
             # admission.wait covers the BLOCKING admission window
             # (take/steal + inline Stage A + Stage B) — the flight
             # recorder's stall trigger watches this span
@@ -207,7 +207,7 @@ class Scheduler:
                     t_enqueue=t_enqueue + req.arrival_s)
             # blocking admission time; speculated Stage-A work adds
             # its (overlapped) duration to admission_s only
-            slot.admit_stall_s = time.time() - t0
+            slot.admit_stall_s = time.perf_counter() - t0
             slot.admission_s = slot.admit_stall_s + (
                 prepared.prep_s if speculated else 0.0)
             slot.t_admit = t0
@@ -230,7 +230,7 @@ class Scheduler:
         n = max(rcfg.prefetch, 0)
         if n == 0 or not queue:
             return
-        now_rel = time.time() - t_enqueue
+        now_rel = time.perf_counter() - t_enqueue
         ordered = self.policy.prefetch_order(queue, now_rel)[:n]
         for pos, req in enumerate(ordered):
             if self.policy.shed:
@@ -246,10 +246,9 @@ class Scheduler:
         finalize, normalized to full budget) into the EWMA — the shed
         projection basis.  Per-class ledgers live in stats.py."""
         req = slot.req
-        t_admit = getattr(slot, "t_admit", None)
-        if t_admit is not None:
-            norm = (time.time() - t_admit) / max(budget_scale_for(req),
-                                                 1e-6)
+        if slot.t_admit is not None:
+            norm = (time.perf_counter() - slot.t_admit) / max(
+                budget_scale_for(req), 1e-6)
             if self.ewma_service_s == 0.0:
                 self.ewma_service_s = norm
             else:
@@ -277,11 +276,13 @@ class Scheduler:
 
     def _sleep_until_arrival(self, queue, t_enqueue):
         """Open-loop gap: nothing live, nothing arrived — sleep until the
-        earliest queued arrival."""
-        gap = min(r.arrival_s for r in queue) + t_enqueue - time.time()
+        earliest queued arrival, counted in ``arrival_wait_s``."""
+        t0 = time.perf_counter()
+        gap = min(r.arrival_s for r in queue) + t_enqueue - t0
         if gap > 0:
             with trace_lib.span("scheduler.idle", gap_ms=gap * 1e3):
                 time.sleep(gap)
+            self.counters.arrival_wait_s += time.perf_counter() - t0
 
     def _observe_depth(self, ex):
         """Publish the executor's speculation queue depth as gauges so

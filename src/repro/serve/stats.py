@@ -106,6 +106,13 @@ class EngineCounters:
     # a Series ring (recent window) and a Counter histogram.
     march_ms: obs_metrics.Series = dataclasses.field(default_factory=_series)
     batches_per_round: Counter = dataclasses.field(default_factory=Counter)
+    # where the engine held the march back (host clock, engine thread):
+    # seconds with no march batch in flight — render() entry to the first
+    # launch, and each round's last collect to the next round's first
+    # launch — less the open-loop sleeps, which ``arrival_wait_s`` counts
+    # (nothing live, the next frame not yet arrived)
+    march_gap_s: float = 0.0
+    arrival_wait_s: float = 0.0
     # per-request end-to-end ledgers, fed at finalize: first-class
     # latency stats instead of every bench re-aggregating RenderRequest
     # fields by hand
@@ -208,6 +215,8 @@ def engine_stats(counters: EngineCounters, probe_caches: Dict,
         "march_ms_p99": c.march_ms.percentile(99.0),
         "march_rounds": c.march_ms.count,
         "batches_per_round": dict(sorted(c.batches_per_round.items())),
+        "march_gap_s": c.march_gap_s,
+        "arrival_wait_s": c.arrival_wait_s,
         # first-class per-request latency: end-to-end (queue wait +
         # admission + march) and the blocking admission stall, both in
         # ms from the bounded series the finalize path feeds

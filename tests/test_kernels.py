@@ -146,6 +146,23 @@ def test_kernel_field_fns_drive_full_pipeline(model):
                                rtol=1e-4, atol=1e-6)
 
 
+def test_march_hlo_carries_layer_scopes(model):
+    """The pooled march's compiled HLO names its layers in op metadata —
+    the hash encode, both MLPs and the compositing — for the profiler's
+    per-op attribution."""
+    from repro.core import pipeline
+    from repro.serve import pool
+    cfg, params = model
+    acfg = pipeline.ASDRConfig(ns_full=16, candidates=(8, 16),
+                               block_size=8, chunk=8)
+    march = pool.batched_march(ops.field_fns(params, cfg), acfg)
+    z = jnp.zeros((2, acfg.block_size, 3))
+    budgets = jnp.full((2,), acfg.ns_full, jnp.int32)
+    hlo = march.func.lower(*march.args, z, z, budgets).compile().as_text()
+    for scope in ("hash_encode", "density_mlp", "color_mlp", "composite"):
+        assert f"/{scope}/" in hlo, scope
+
+
 def _pallas_interpret_flags(fn, *args):
     # fn must be a fresh function: a cached trace would keep the flag of
     # the platform it was first traced under

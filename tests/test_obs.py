@@ -1,6 +1,6 @@
 """Observability layer: tracing contract, metrics primitives, overhead.
 
-Four property groups (ISSUE-8):
+Five property groups:
 
   * metrics — nearest-rank percentile (small-n off-by-one regression),
     bounded Series/Counter ledgers (>10k-round growth regression),
@@ -17,14 +17,19 @@ Four property groups (ISSUE-8):
     {0, 2} (the device executor case lives in tests/test_fleet.py,
     which owns the forced multi-device runtime), and an exported trace
     reconstructs a frame's full stage lineage with matching req/batch
-    ids.
+    ids;
+  * profiler bridge — under a JAX profiler session the spans land on
+    the profiler's host plane with their ids, tracer or not, and frames
+    + counters stay bit-identical.
 """
 import json
 import sys
 import threading
 import tracemalloc
+from collections import defaultdict
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
@@ -253,46 +258,6 @@ def test_flight_recorder_fires_exactly_once(tmp_path):
     assert check_trace.check_file(path) == []
 
 
-def test_rate_trigger_burst_detection_and_rearm(tmp_path):
-    """ISSUE-9 satellite: a burst trigger (shed storm) fires on the
-    count-th matching span inside the window — spread-out spans never
-    fire — and stays one-shot until rearm(); the window state freezes
-    while disarmed and resumes after."""
-    rec = export.FlightRecorder(capacity=16)
-    path = tmp_path / "fl_shed_burst.json"
-    trig = rec.dump_on(export.shed_burst_trigger(3, 100.0), path)
-
-    def shed(t0, sid):
-        return trace_lib.Span("scheduler.shed", sid, 0, "engine",
-                              t0, t0 + 1e-4, {})
-
-    def other(t0, sid):
-        return trace_lib.Span("pool.march", sid, 0, "engine",
-                              t0, t0 + 1e-4, {})
-
-    # three sheds spread over 310 ms (> window), with unrelated spans
-    # interleaved: no fire
-    rec.record([shed(0.00, 1), other(0.01, 2), shed(0.30, 3),
-                shed(0.31, 4)])
-    assert trig.fired == 0 and not path.exists()
-    # the 4th shed closes a (0.30, 0.31, 0.32) window: fire once
-    fired = rec.record([shed(0.32, 5), shed(0.33, 6)])
-    assert fired == 1 and trig.fired == 1 and trig.fired_on == 5
-    first = path.read_text()
-    rec.record([shed(0.34, 7), shed(0.35, 8), shed(0.36, 9)])
-    assert trig.fired == 1 and path.read_text() == first   # disarmed
-    rec.rearm()
-    rec.record([shed(0.37, 10)])                # resumes the frozen window
-    assert trig.fired == 2 and trig.fired_on == 10
-    assert check_trace.check_file(path) == []
-    # the evict-storm twin watches scenecache.evict spans
-    storm = export.evict_storm_trigger(2, 50.0)
-    ev = lambda t0, sid: trace_lib.Span("scenecache.evict", sid, 0,
-                                        "engine", t0, t0 + 1e-4, {})
-    assert not storm(ev(0.0, 1))
-    assert storm(ev(0.02, 2))
-
-
 def test_replica_pid_export_and_fleet_merge(tmp_path):
     """ISSUE-9 satellite: TraceConfig.replica stamps every exported
     event's Chrome pid (one process group per replica) and a
@@ -495,9 +460,10 @@ def test_engine_trace_reconstructs_lineage(flds, tmp_path):
     for bid, d in dispatches.items():
         assert collects[bid].attrs["reqs"] == d.attrs["reqs"]
         assert d.attrs["scene"] == "mic"
-        # collect stamps launch->arrays-ready device time back onto the
-        # dispatch span, splitting its host time into queue vs device
-        assert d.attrs["device_ms"] > 0.0
+        # the collect span is the host's wait for that batch's outputs:
+        # it follows its dispatch on the same lane
+        c = collects[bid]
+        assert c.lane == d.lane and c.t0 >= d.t1 and c.dur_ms > 0.0
     # the exported file passes the validator too
     assert check_trace.check_file(path) == []
 
@@ -520,3 +486,77 @@ def test_engine_stats_is_registry_read(flds):
     assert f"frames {st['frames']}" in text
     assert max(st["batches_per_round"]) >= 1     # dict keyed by n_batches
     eng.close()
+
+
+# ------------------------------------------------------ profiler bridge
+def _host_events(logdir):
+    """(name, stats) of every host-plane event in a profiler session's
+    xplane file."""
+    from jax.profiler import ProfileData
+    path = sorted(Path(logdir).rglob("*.xplane.pb"))[-1]
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_profiler_session_records_engine_spans(flds, tmp_path):
+    """With no tracer installed, an active profiler session turns the
+    engine's spans into host-plane events carrying their req/batch ids,
+    and the off path returns to NULL_SPAN after the session."""
+    eng = RenderServingEngine(flds, ACFG, RenderServeConfig(
+        slots=2, blocks_per_batch=4))
+    eng.render(traj(2))                          # compile outside
+    reqs = traj(4)
+    with jax.profiler.trace(str(tmp_path)):
+        assert trace_lib.span("pool.collect") is not NULL_SPAN
+        eng.render(reqs)
+    eng.close()
+    assert trace_lib.span("pool.collect") is NULL_SPAN
+    by_name = defaultdict(list)
+    for name, stats in _host_events(tmp_path):
+        by_name[name].append(stats)
+    rids = {r.rid for r in reqs}
+    for name in ("admission.wait", "engine.finalize"):
+        assert {st["req"] for st in by_name[name]} == rids, name
+    batches = {st["batch"] for st in by_name["pool.dispatch"]}
+    assert batches and batches == {st["batch"]
+                                   for st in by_name["pool.collect"]}
+    assert all(isinstance(st["reqs"], str) for st in by_name["pool.collect"])
+
+
+def test_profiled_span_also_records_into_tracer(tmp_path):
+    """Profiler on and a tracer installed: one span lands in both, and
+    attrs stamped after entry reach the tracer's copy."""
+    tr = Tracer(TraceConfig())
+    trace_lib.install(tr)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            with trace_lib.span("pool.collect", batch=3, reqs=[1, 2]) as sp:
+                sp.attrs["kind"] = "late"
+        tr.drain()
+    finally:
+        trace_lib.uninstall(tr)
+    (s,) = tr.spans
+    assert s.name == "pool.collect"
+    assert s.attrs == {"batch": 3, "reqs": [1, 2], "kind": "late"}
+    got = [st for name, st in _host_events(tmp_path) if name == "pool.collect"]
+    assert got == [{"batch": 3, "reqs": "[1, 2]"}]
+
+
+def test_bit_identity_profiler_on_off(flds, tmp_path):
+    """Frames and every deterministic counter identical with a profiler
+    session on vs off: the bridge only reads names and ids."""
+    from repro.framecache import ProbeReuseConfig, RadianceReuseConfig
+    rcfg = RenderServeConfig(
+        slots=2, blocks_per_batch=4,
+        reuse=ProbeReuseConfig(refresh_every=0),
+        radiance=RadianceReuseConfig(refresh_every=0))
+    d_off, st_off, _ = render_pair(flds, rcfg)
+    with jax.profiler.trace(str(tmp_path)):
+        d_on, st_on, _ = render_pair(flds, rcfg)
+    assert d_off.keys() == d_on.keys()
+    for rid in d_off:
+        np.testing.assert_array_equal(d_off[rid].image, d_on[rid].image)
+    for k in DETERMINISTIC_COUNTERS:
+        assert st_off[k] == st_on[k], k

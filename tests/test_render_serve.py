@@ -280,7 +280,6 @@ def test_ray_exit_skip_counter(setup):
     counts lands in ``ray_exit_samples_skipped`` (chunk samples per
     skipped ray-chunk); with the flag off the counter stays zero."""
     import dataclasses
-    import time as time_lib
     from repro.serve import pool as pool_lib, stats as stats_lib
 
     class _FakeReq:
@@ -301,14 +300,14 @@ def test_ray_exit_skip_counter(setup):
     out = (np.zeros((2, B, 3)), np.zeros((2, B)), np.zeros((2, B)),
            np.asarray([4, 1]),                      # block chunks (1 pad)
            np.asarray([[4, 2, 1, 4], [1, 1, 1, 1]]))  # per-ray chunks
-    pool.collect((batch, [], 1, out, 1, None, time_lib.perf_counter()))
+    pool.collect((batch, [], 1, out, 1))
     # real block: (4-4)+(4-2)+(4-1)+(4-4) = 5 skipped ray-chunks; the
     # pad block's gap must NOT count
     assert counters.ray_exit_samples_skipped == 5 * acfg.chunk
     # flag off: identical collect books nothing
     counters2 = stats_lib.EngineCounters()
     pool2 = pool_lib.BlockPool(ACFG, 2, None, counters2)
-    pool2.collect((batch, [], 1, out, 2, None, time_lib.perf_counter()))
+    pool2.collect((batch, [], 1, out, 2))
     assert counters2.ray_exit_samples_skipped == 0
     assert "ray_exit_samples_skipped" in stats_lib.engine_stats(
         counters, {}, {}, None)

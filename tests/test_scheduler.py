@@ -246,6 +246,55 @@ def test_open_loop_arrival_gating(flds):
     assert done[1].latency_s <= wall - 0.35
 
 
+# ----------------------------------------------- where the latency goes
+PHASES = ("queue_s", "admit_stall_s", "march_s", "finalize_s")
+
+
+@pytest.mark.parametrize("open_loop", [False, True])
+def test_frame_phases_sum_to_latency(flds, open_loop):
+    """Per frame, waiting for admission + the blocking admission +
+    marching + finalizing is the whole latency (one clock)."""
+    eng = RenderServingEngine(flds, ACFG, serve_cfg(slots=2))
+    eng.render([_req(0)])               # absorb compile time
+    reqs = [_req(i, arrival=0.05 * i if open_loop else 0.0,
+                 theta=0.7 + 0.05 * i) for i in range(1, 6)]
+    done = eng.render(reqs)
+    eng.close()
+    assert len(done) == len(reqs)
+    for r in done:
+        parts = [r.stats[k] for k in PHASES]
+        assert min(parts) >= 0.0, dict(zip(PHASES, parts))
+        assert sum(parts) == pytest.approx(r.latency_s, abs=1e-3)
+    # a closed batch of 5 frames in 2 slots: later frames queue
+    assert open_loop or max(r.stats["queue_s"] for r in done) > 0.0
+
+
+@pytest.mark.parametrize("open_loop", [False, True])
+def test_march_gap_and_arrival_wait(flds, open_loop):
+    """The engine's march gap lies within the call's wall time; arrival
+    sleeps count only in an open loop with a gap before the first
+    arrival."""
+    eng = RenderServingEngine(flds, ACFG, serve_cfg(slots=2))
+    eng.render([_req(0)])               # absorb compile time
+    st0 = eng.engine_stats()
+    reqs = [_req(1, arrival=0.2 if open_loop else 0.0),
+            _req(2, arrival=0.2 if open_loop else 0.0, theta=0.75),
+            _req(3, theta=0.8)]
+    t0 = time.perf_counter()
+    eng.render(reqs)
+    wall = time.perf_counter() - t0
+    st1 = eng.engine_stats()
+    eng.close()
+    gap = st1["march_gap_s"] - st0["march_gap_s"]
+    waited = st1["arrival_wait_s"] - st0["arrival_wait_s"]
+    assert 0.0 < gap <= wall
+    assert gap + waited <= wall
+    if open_loop:
+        assert waited > 0.0
+    else:
+        assert waited == 0.0
+
+
 # ----------------------------------------------------------------- plumbing
 def test_make_policy_resolution():
     assert type(make_policy(None)) is FifoPolicy

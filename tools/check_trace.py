@@ -44,7 +44,6 @@ LANE_PATTERNS = (
     r"serve-stage-a.*",          # ThreadedExecutor workers
     r"serve-dev\d+.*",           # DeviceExecutor per-device queues
     r"scenecache-fetch.*",       # ShardedSceneCache fetch pool
-    r"shard-.*",
     r"Thread-\d+.*",             # bare threading.Thread (tests/drivers)
     r"Dummy-\d+.*",
     r"(pytest|asyncio).*",
