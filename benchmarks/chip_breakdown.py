@@ -7,8 +7,10 @@ Times, on ``configs/ingp_asdr.CONFIG`` with seeded weights (tables
 scaled x1e4, as in ``chip_smoke.py``):
 
   * the hash encode of one march step (block_size x chunk = 131,072
-    points) in seven formulations of the same gather, each checked
-    against ``hashgrid.encode``;
+    points) in nine formulations of the same gather, each checked
+    against ``hashgrid.encode``: seven gather 8 table rows a level, two
+    gather one corner row a point at the levels with ``res^3 <= T``
+    (``hashgrid.corner_tables``, derived outside the timed call);
   * the fused-MLP Pallas kernels against XLA's own matmuls (``core.mlp``);
   * the field's density (encode + MLP) in a jit that closes over the
     params, i.e. holds them as constants;
@@ -54,8 +56,43 @@ def timeit(name, f, *args, n=5):
     return out
 
 
+def pack_corners(c, lanes=128):
+    """Corner rows (V, W) packed ``lanes // W`` to a row: (ceil(V*W/lanes), lanes)."""
+    c = c.reshape(-1)
+    c = jnp.pad(c, (0, (-c.shape[0]) % lanes))
+    return c.reshape(-1, lanes)
+
+
+def encode_corners_packed(points, tables, packed, g):
+    """``hashgrid.encode`` with the corner levels read from packed corner
+    rows: one 128-lane row a point, its voxel's slice picked by a tree of
+    ``where`` on the low bits of the voxel id (exact: no arithmetic)."""
+    by_level = dict(zip(hashgrid.corner_levels(g), packed))
+    outs = []
+    for l in range(g.n_levels):
+        res = g.level_resolution(l)
+        if l not in by_level:
+            outs.append(hashgrid.encode_level(points, tables[l], res,
+                                              g.level_is_dense(l)))
+            continue
+        width = 8 * g.feature_dim
+        per_row = by_level[l].shape[1] // width
+        base, frac = hashgrid._voxel(points, res)
+        vox = base[:, 0] + res * (base[:, 1] + res * base[:, 2])
+        row = by_level[l][vox // per_row]                  # (N, 128)
+        slot = vox % per_row
+        while row.shape[1] > width:
+            half = row.shape[1] // 2
+            bit = (slot & (half // width)) != 0
+            row = jnp.where(bit[:, None], row[:, half:], row[:, :half])
+        feats = row.reshape(points.shape[0], 8, g.feature_dim)
+        outs.append(hashgrid._blend(feats, frac, points.dtype))
+    return jnp.concatenate(outs, -1)
+
+
 def encoders(g, T, F):
-    """The same multiresolution hash encode, gathered seven ways."""
+    """The same multiresolution hash encode, gathered nine ways: each
+    entry is (name, encode(points, arg), prepare(tables) -> arg)."""
     L = g.n_levels
     res = [(g.level_resolution(l), g.level_is_dense(l)) for l in range(L)]
 
@@ -118,9 +155,24 @@ def encoders(g, T, F):
             tt[l], idx.reshape(-1), axis=1, mode="clip").reshape(
                 F, -1, 8).transpose(1, 2, 0))
 
-    return [("A_rows", rows), ("B_feat1d", feat_1d), ("C_flat1d", flat_1d),
+    def corner_rows(p, tc):                # one (V, 8F) corner row a point
+        return hashgrid.encode(p, tc[0], g, corners=tc[1])
+
+    def corner_packed(p, tc):              # corner rows, 128 lanes a row
+        return encode_corners_packed(p, tc[0], tc[1], g)
+
+    def with_corners(t):
+        return t, hashgrid.corner_tables(t, g)
+
+    def with_packed(t):
+        return t, tuple(pack_corners(c) for c in hashgrid.corner_tables(t, g))
+
+    same = [("A_rows", rows), ("B_feat1d", feat_1d), ("C_flat1d", flat_1d),
             ("E_alllev_rows", all_rows), ("F_alllev_feat1d", all_feat_1d),
             ("G_rows_promise", rows_promise), ("H_take_axis1", take_axis1)]
+    return [(n, f, lambda t: t) for n, f in same] + [
+        ("I_corner_rows", corner_rows, with_corners),
+        ("J_corner_packed", corner_packed, with_packed)]
 
 
 def main(argv=None) -> int:
@@ -146,9 +198,10 @@ def main(argv=None) -> int:
     # A formulation that does not compile for this backend is a result,
     # not a fault: print the refusal and time the next one.
     ref = None
-    for name, f in encoders(cfg.grid, T, F):
+    for name, f, prepare in encoders(cfg.grid, T, F):
         try:
-            out = timeit(f"encode {name} P={P}", jax.jit(f), pts, tables)
+            arg = jax.block_until_ready(prepare(tables))
+            out = timeit(f"encode {name} P={P}", jax.jit(f), pts, arg)
         except Exception as e:  # noqa: BLE001
             print(f"encode {name}: FAILED {type(e).__name__}: "
                   f"{str(e)[:300]}", flush=True)

@@ -9,6 +9,20 @@ size exceeds the table.  That is exactly the split we implement: a level is
 (perfect locality, the TPU analogue of conflict-free crossbar rows) and
 high-res levels use Instant-NGP's spatial hash (Eq. 2).
 
+Serving reads the coarse levels from *corner rows* (``corner_tables``): a
+level whose voxel count ``res^3`` is at most the table's row count ``T``
+stores, for each voxel, its 8 corners' features in one row of ``8*F``
+floats, so a point gathers one row there instead of 8.  The rows are
+fetched through ``level_indices`` (dense and hashed alike, collisions
+included) and blended with the same weights, so the encoding is the same.
+Only those levels qualify because ``res^3 <= T`` bounds the copy at 8x
+that level's table; finer levels would need more rows than the table has.
+At the paper's config (T = 2^19, F = 2) levels 0-5 (res 16, 22, 30, 42,
+58, 80; level 5 is hashed) qualify: 822,944 voxels of 64 B, i.e. 0.26,
+0.68, 1.73, 4.74, 12.49 and 32.77 MB, 52.7 MB in all, beside the 64 MB
+of stacked tables.  Training keeps the stacked tables alone: it updates
+them every step and takes gradients into them.
+
 All functions are pure; parameters are plain pytrees.
 """
 from __future__ import annotations
@@ -97,34 +111,90 @@ def level_indices(coords: jnp.ndarray, res: int, dense: bool, table_size: int) -
     return (h % np.uint32(table_size)).astype(jnp.int32)
 
 
-def encode_level(
-    points: jnp.ndarray, table: jnp.ndarray, res: int, dense: bool
-) -> jnp.ndarray:
-    """Encode points (N, 3) in [0,1]^3 against one level's table (T, F)."""
+def _voxel(points: jnp.ndarray, res: int):
+    """The voxel holding each point (N, 3) at resolution ``res``: its
+    lower corner (clipped into the grid) and the point's offset in it."""
     scaled = points * res  # (N, 3)
     base = jnp.floor(scaled).astype(jnp.int32)
     base = jnp.clip(base, 0, res - 1)
-    frac = scaled - base  # (N, 3) in [0, 1)
+    return base, scaled - base  # frac (N, 3) in [0, 1)
 
-    corners = base[:, None, :] + _corner_offsets()[None, :, :]  # (N, 8, 3)
-    idx = level_indices(corners, res, dense, table.shape[0])  # (N, 8)
-    feats = table[idx]  # (N, 8, F)  -- XLA gather
 
-    # Trilinear weights: prod over axes of (1-frac) or frac per corner bit.
-    offs = _corner_offsets().astype(points.dtype)  # (8, 3)
+def _blend(feats: jnp.ndarray, frac: jnp.ndarray, dtype) -> jnp.ndarray:
+    """Trilinear blend of the 8 corners' features (N, 8, F) -> (N, F)."""
+    # weights: prod over axes of (1-frac) or frac per corner bit
+    offs = _corner_offsets().astype(dtype)  # (8, 3)
     w = jnp.where(offs[None, :, :] == 1.0, frac[:, None, :], 1.0 - frac[:, None, :])
     w = jnp.prod(w, axis=-1)  # (N, 8)
     return jnp.sum(feats * w[..., None], axis=1)  # (N, F)
 
 
-def encode(points: jnp.ndarray, tables: jnp.ndarray, cfg: HashGridConfig) -> jnp.ndarray:
+def encode_level(
+    points: jnp.ndarray, table: jnp.ndarray, res: int, dense: bool
+) -> jnp.ndarray:
+    """Encode points (N, 3) in [0,1]^3 against one level's table (T, F)."""
+    base, frac = _voxel(points, res)
+    corners = base[:, None, :] + _corner_offsets()[None, :, :]  # (N, 8, 3)
+    idx = level_indices(corners, res, dense, table.shape[0])  # (N, 8)
+    feats = table[idx]  # (N, 8, F)  -- XLA gather
+    return _blend(feats, frac, points.dtype)
+
+
+def corner_levels(cfg: HashGridConfig) -> Tuple[int, ...]:
+    """The levels served from corner rows: those with ``res^3 <= T``."""
+    return tuple(l for l in range(cfg.n_levels)
+                 if cfg.level_resolution(l) ** 3 <= cfg.table_size)
+
+
+def corner_table(table: jnp.ndarray, res: int, dense: bool) -> jnp.ndarray:
+    """One level's corner rows (res^3, 8*F) from its table (T, F).
+
+    Row ``v = x + res*(y + res*z)`` holds the features of voxel (x, y, z)'s
+    8 corners in ``_corner_offsets()`` order, each fetched through
+    ``level_indices``: a hashed level's row holds what its hash reads,
+    collisions included."""
+    r = jnp.arange(res, dtype=jnp.int32)
+    z, y, x = jnp.meshgrid(r, r, r, indexing="ij")
+    base = jnp.stack([x, y, z], axis=-1).reshape(-1, 3)  # row-major voxels
+    corners = base[:, None, :] + _corner_offsets()[None, :, :]
+    idx = level_indices(corners, res, dense, table.shape[0])  # (res^3, 8)
+    return table[idx].reshape(res**3, -1)
+
+
+def corner_tables(tables: jnp.ndarray, cfg: HashGridConfig) -> Tuple[jnp.ndarray, ...]:
+    """``corner_table`` of every level in ``corner_levels(cfg)``, from the
+    stacked tables (L, T, F)."""
+    return tuple(
+        corner_table(tables[l], cfg.level_resolution(l), cfg.level_is_dense(l))
+        for l in corner_levels(cfg))
+
+
+def encode_corner_level(points: jnp.ndarray, corners: jnp.ndarray, res: int) -> jnp.ndarray:
+    """``encode_level`` from the level's corner rows (res^3, 8*F): one
+    gathered row a point, the same features and the same blend."""
+    base, frac = _voxel(points, res)
+    vox = base[:, 0] + res * (base[:, 1] + res * base[:, 2])
+    feats = corners[vox].reshape(points.shape[0], 8, -1)  # (N, 8, F)
+    return _blend(feats, frac, points.dtype)
+
+
+def encode(points: jnp.ndarray, tables: jnp.ndarray, cfg: HashGridConfig,
+           corners: Tuple[jnp.ndarray, ...] = ()) -> jnp.ndarray:
     """Full multi-resolution encoding: (N, 3) -> (N, n_levels * feature_dim).
-    Its ops carry the ``hash_encode`` scope in the profiler's metadata."""
+
+    With ``corners`` (``corner_tables(tables, cfg)``) the levels of
+    ``corner_levels(cfg)`` gather one corner row a point instead of 8
+    table rows; the result is the same.  Its ops carry the
+    ``hash_encode`` scope in the profiler's metadata."""
+    by_level = dict(zip(corner_levels(cfg), corners))
     with jax.named_scope("hash_encode"):
         outs = []
         for l in range(cfg.n_levels):
             res = cfg.level_resolution(l)
-            outs.append(encode_level(points, tables[l], res, cfg.level_is_dense(l)))
+            if l in by_level:
+                outs.append(encode_corner_level(points, by_level[l], res))
+            else:
+                outs.append(encode_level(points, tables[l], res, cfg.level_is_dense(l)))
         return jnp.concatenate(outs, axis=-1)
 
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 import math
 import threading
 from collections import OrderedDict
+from functools import partial
 from typing import Dict
 
 import jax
@@ -371,19 +372,38 @@ def fused_march_blocks(res: FusedMarchResources, acfg, o_b, d_b, budgets,
 
 
 # ------------------------------------------------------------------- FieldFns
+@partial(jax.jit, static_argnums=(1,))
+def corner_tables(tables, grid):
+    """``hashgrid.corner_tables`` as one executable of its own name."""
+    return hashgrid.corner_tables(tables, grid)
+
+
 def field_fns(params: Dict, cfg) -> FieldFns:
     """Kernel-backed FieldFns (cfg is core.model.NGPConfig).
 
     The hash encode is the XLA gather (``core.hashgrid.encode``) on every
     platform, feeding the fused-MLP kernels: the Pallas encode kernel does
-    not compile for TPU (see hash_encode.py).  Carries
-    FusedMarchResources so ``ASDRConfig.march_backend="fused"`` routes
-    Phase II through the single-kernel streaming march where Pallas
-    interprets, and ``Bound`` so jitted callers pass params as arguments.
+    not compile for TPU (see hash_encode.py).  The levels with
+    ``res^3 <= T`` gather one corner row a point: their corner tables are
+    derived here from ``params["grid"]``, once per call, and carried in
+    the ``Bound`` params beside it, so jitted callers (``fields.unbind``)
+    pass them as arguments and ``rebind`` inside a trace only reads them.
+    Carries FusedMarchResources so ``ASDRConfig.march_backend="fused"``
+    routes Phase II through the single-kernel streaming march where
+    Pallas interprets; that march encodes from the stacked tables.
     """
+    # waited for here, so a field is ready when built: the derivation
+    # never runs on the device inside the caller's first march
+    corners = jax.block_until_ready(corner_tables(params["grid"], cfg.grid))
+    return _bound_field_fns(dict(params, corners=corners), cfg)
+
+
+def _bound_field_fns(params: Dict, cfg) -> FieldFns:
+    """``field_fns`` over params that already carry their corner tables."""
 
     def density(points):
-        enc = hashgrid.encode(points, params["grid"], cfg.grid)
+        enc = hashgrid.encode(points, params["grid"], cfg.grid,
+                              params["corners"])
         sigma, geo = density_mlp(enc, params["mlps"], cfg.net)
         inside = jnp.all((points >= 0.0) & (points <= 1.0), axis=-1)
         return jnp.where(inside, sigma, 0.0), geo
@@ -393,4 +413,19 @@ def field_fns(params: Dict, cfg) -> FieldFns:
 
     return FieldFns(density=density, color=color,
                     fused=FusedMarchResources(params, cfg),
-                    bound=Bound(field_fns, cfg, params))
+                    bound=Bound(_bound_field_fns, cfg, params))
+
+
+def encode_gather_stats(fns: FieldFns) -> Dict[str, int]:
+    """How a field's hash encode gathers: ``corner_levels``, the levels it
+    reads from corner rows (one index a point each), and
+    ``encode_gather_rows_per_sample``, the gather indices per encoded
+    point summed over levels (8 at every other level).  Zero for a field
+    with no hash grid."""
+    bound = fns.bound
+    grid = getattr(bound.cfg, "grid", None) if bound is not None else None
+    if grid is None:
+        return {"encode_gather_rows_per_sample": 0, "corner_levels": 0}
+    n = len(bound.params.get("corners", ()))
+    return {"encode_gather_rows_per_sample": n + 8 * (grid.n_levels - n),
+            "corner_levels": n}

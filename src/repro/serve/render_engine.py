@@ -239,4 +239,5 @@ class RenderServingEngine:
     def engine_stats(self) -> Dict:
         return stats_lib.engine_stats(self.counters, self.probe_caches,
                                       self.radiance_caches, self.scenecache,
-                                      registry=self.metrics)
+                                      registry=self.metrics,
+                                      fields=self.fields)

@@ -172,7 +172,8 @@ DETERMINISTIC_COUNTERS = (
 
 def engine_stats(counters: EngineCounters, probe_caches: Dict,
                  radiance_caches: Dict, scenecache,
-                 registry: Optional[obs_metrics.Registry] = None) -> Dict:
+                 registry: Optional[obs_metrics.Registry] = None,
+                 fields: Optional[Dict] = None) -> Dict:
     """The engine's aggregate stats dict (the public ``engine_stats()``).
 
     With a registry, every key is published as a gauge and the returned
@@ -262,14 +263,19 @@ def engine_stats(counters: EngineCounters, probe_caches: Dict,
     # weight stacks AVOIDED on engine restarts / multi-scene hot-swap.
     # Lazy import: serve/ stays importable without the kernels package
     # loaded (pure-field engines never touch it).
-    try:
-        from ..kernels import ops as _kops
-        pstats = _kops.pack_cache_stats()
-    except ImportError:  # pragma: no cover — kernels always ship here
-        pstats = {"hits": 0, "misses": 0, "size": 0}
+    from ..kernels import ops as _kops
+    pstats = _kops.pack_cache_stats()
     out["pack_cache_hits"] = pstats["hits"]
     out["pack_cache_misses"] = pstats["misses"]
     out["pack_cache_size"] = pstats["size"]
+    # how the hash encode gathers (kernels.ops.encode_gather_stats): the
+    # gather indices per encoded point and the levels read from corner
+    # rows, of the field that gathers the most when scenes differ
+    enc = max((_kops.encode_gather_stats(f) for f in (fields or {}).values()),
+              key=lambda e: e["encode_gather_rows_per_sample"],
+              default={"encode_gather_rows_per_sample": 0,
+                       "corner_levels": 0})
+    out.update(enc)
     if registry is not None:
         for k, v in out.items():
             registry.set_value(k, v)

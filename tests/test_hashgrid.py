@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import hashgrid as hg
@@ -91,3 +92,47 @@ def test_storage_utilization_improves_like_paper():
             assert c >= 1
         else:
             assert c == 1
+
+
+def _corner_case(case):
+    """(grid, points) for the corner-row encode's cases."""
+    from repro.configs.ingp_asdr import CONFIG, SMOKE
+    pts = jax.random.uniform(jax.random.PRNGKey(3), (257, 3))
+    if case == "smoke":
+        return SMOKE.model.grid, pts
+    if case == "config":
+        return CONFIG.model.grid, pts
+    if case == "hashed_collisions":
+        # res 16 is hashed ((16+1)^3 > 2^12) yet 16^3 = 2^12 voxels fit
+        return hg.HashGridConfig(n_levels=3, log2_table_size=12,
+                                 max_resolution=64), pts
+    # every corner, edge midpoint and face centre of the unit cube
+    g = jnp.asarray([0.0, 0.5, 1.0])
+    cube = jnp.stack(jnp.meshgrid(g, g, g, indexing="ij"), -1).reshape(-1, 3)
+    return SMOKE.model.grid, jnp.concatenate([cube, pts])
+
+
+@pytest.mark.parametrize("case", ["smoke", "config", "hashed_collisions",
+                                  "unit_cube_points"])
+def test_corner_row_encode_is_bit_identical(case):
+    """Levels with res^3 <= T read one corner row a point; every other
+    level gathers its 8 table rows.  The encoding is the same to the bit."""
+    cfg, pts = _corner_case(case)
+    want = {"smoke": (0, 1), "config": (0, 1, 2, 3, 4, 5),
+            "hashed_collisions": (0,), "unit_cube_points": (0, 1)}[case]
+    assert hg.corner_levels(cfg) == want
+    tables = hg.init_hashgrid(jax.random.PRNGKey(2), cfg)
+    corners = hg.corner_tables(tables, cfg)
+    for l, c in zip(want, corners):
+        res = cfg.level_resolution(l)
+        assert c.shape == (res ** 3, 8 * cfg.feature_dim)
+    if case == "hashed_collisions":
+        res = cfg.level_resolution(0)
+        assert not cfg.level_is_dense(0)
+        verts = jnp.stack(jnp.meshgrid(*[jnp.arange(res + 1)] * 3,
+                                       indexing="ij"), -1).reshape(-1, 3)
+        rows = hg.level_indices(verts, res, False, cfg.table_size)
+        assert len(np.unique(np.asarray(rows))) < (res + 1) ** 3
+    got = hg.encode(pts, tables, cfg, corners)
+    ref = hg.encode(pts, tables, cfg)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))

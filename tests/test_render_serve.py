@@ -348,3 +348,71 @@ def test_density_refresh_enables_radiance_chaining(setup):
         p = float(rendering.psnr(jnp.asarray(dr[rid].image),
                                  jnp.asarray(df[rid].image)))
         assert p > 30.0, (rid, p)
+
+
+@pytest.fixture(scope="module")
+def smoke_field():
+    """SMOKE's Instant-NGP with seeded weights, tables scaled so the
+    field has structure, served from the kernel-backed field."""
+    import jax
+    from repro.configs.ingp_asdr import SMOKE
+    from repro.core import model as model_lib
+    cfg = SMOKE.model
+    params = model_lib.init_ngp(jax.random.PRNGKey(7), cfg)
+    params = dict(params, grid=params["grid"] * 1e4)
+    return cfg, params
+
+
+def test_kernel_field_carries_corner_tables(smoke_field, monkeypatch):
+    """ops.field_fns derives the corner tables from the grid once, hands
+    them over as bound params, and a jitted rebind only reads them."""
+    import jax
+    from repro.core import hashgrid
+    from repro.kernels import ops
+    from repro.serve import pool
+    cfg, params = smoke_field
+    fns = ops.field_fns(params, cfg)
+    corners = fns.bound.params["corners"]
+    want = hashgrid.corner_tables(params["grid"], cfg.grid)
+    assert len(corners) == len(hashgrid.corner_levels(cfg.grid)) == 2
+    for got, ref in zip(corners, want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+    calls = []
+    real = ops.corner_tables
+    monkeypatch.setattr(ops, "corner_tables",
+                        lambda *a: calls.append(a) or real(*a))
+    fkey, bound = fields.unbind(fns)
+    pts = jnp.linspace(0.0, 1.0, 96).reshape(32, 3)
+    jax.jit(lambda p, x: fields.rebind(fkey, p).density(x)[0])(bound, pts)
+    acfg = pipeline.ASDRConfig(ns_full=16, candidates=(8, 16),
+                               block_size=8, chunk=8)
+    z = jnp.zeros((2, 8, 3))
+    pool.batched_march(fns, acfg)(z, z, jnp.full((2,), 16, jnp.int32))
+    assert calls == []
+
+
+def test_engine_frame_over_corner_rows_matches_model_field(smoke_field):
+    """A frame served over the kernel-backed field (corner rows, fused-MLP
+    kernels) matches the same frame over the training path's field
+    (``model.field_fns``: 8 table rows a level, jnp MLPs), and
+    engine_stats() reports how each encode gathers."""
+    from repro.core import model as model_lib
+    from repro.kernels import ops
+    cfg, params = smoke_field
+    cam = scene.look_at_camera(16, 16, theta=0.7, phi=0.5)
+    out = {}
+    for name, fns in (("kernel", ops.field_fns(params, cfg)),
+                      ("model", model_lib.field_fns(params, cfg))):
+        eng = RenderServingEngine({"s": fns}, ACFG, RenderServeConfig(
+            slots=1, blocks_per_batch=2, reuse=None))
+        req, = eng.render([RenderRequest(rid=0, scene="s", cam=cam)])
+        out[name] = (req.image, eng.engine_stats())
+        eng.close()
+    assert out["kernel"][0].std() > 1e-3          # not a blank frame
+    np.testing.assert_allclose(out["kernel"][0], out["model"][0],
+                               rtol=1e-4, atol=1e-5)
+    assert out["kernel"][1]["corner_levels"] == 2
+    assert out["kernel"][1]["encode_gather_rows_per_sample"] == 2 + 8 * 6
+    assert out["model"][1]["corner_levels"] == 0
+    assert out["model"][1]["encode_gather_rows_per_sample"] == 8 * 8

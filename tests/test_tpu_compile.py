@@ -20,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import kernels
 from repro.configs.ingp_asdr import CONFIG
-from repro.core import model as model_lib, pipeline, scene
+from repro.core import hashgrid, model as model_lib, pipeline, scene
 from repro.kernels import fused_march as FMA
 from repro.kernels import fused_mlp as FM
 from repro.kernels import hash_encode as HE
@@ -63,6 +63,16 @@ def _params(sharding):
     return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), shapes)
 
 
+def _serving_params(sharding):
+    """The params the serving field binds: the stacked tables, the MLPs
+    and the corner tables ``ops.field_fns`` derives from the tables."""
+    params = _params(sharding)
+    corners = jax.eval_shape(lambda t: hashgrid.corner_tables(
+        t, CONFIG.model.grid), params["grid"])
+    return dict(params, corners=jax.tree.map(
+        lambda s: _spec(sharding, s.shape, s.dtype), corners))
+
+
 def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
@@ -82,16 +92,17 @@ def test_fused_mlp_kernels_compile_at_paper_widths(chip, compiled, kernel):
 
 
 def test_reference_march_block_compiles(chip, compiled):
-    """One 4096-ray block of the engine's march: XLA-gather encode feeding
-    the compiled fused-MLP kernels."""
+    """One 4096-ray block of the engine's march: XLA-gather encode (corner
+    rows at the levels with res^3 <= T) feeding the compiled fused-MLP
+    kernels."""
     cfg, acfg = CONFIG.model, CONFIG.asdr
     B = acfg.block_size
 
     def march(params, o, d, budget):
-        return pipeline._march_block(ops.field_fns(params, cfg), acfg, o, d,
-                                     budget)
+        return pipeline._march_block(ops._bound_field_fns(params, cfg), acfg,
+                                     o, d, budget)
 
-    exe = _compile(march, _params(chip), _spec(chip, (B, 3)),
+    exe = _compile(march, _serving_params(chip), _spec(chip, (B, 3)),
                    _spec(chip, (B, 3)), _spec(chip, (), jnp.int32))
     assert "tpu_custom_call" in exe.as_text()
     assert exe.memory_analysis().temp_size_in_bytes < HBM_BYTES // 2
@@ -103,11 +114,12 @@ def test_blocked_probe_fits_one_chip(chip, compiled):
     cfg, acfg = CONFIG.model, CONFIG.asdr
     H, W = CONFIG.image_hw
     cam = scene.look_at_camera(H, W, 0.6, 0.5)
-    fkey = (ops.field_fns, cfg)      # fields.unbind's key for ops fields
+    fkey = (ops._bound_field_fns, cfg)   # fields.unbind's key for ops fields
     probe = partial(pipeline._probe_maps.__wrapped__, fkey, acfg,
                     (H, W, cam.focal))
     exe = _compile(lambda p, rot, org: probe(p, rot, org, None),
-                   _params(chip), _spec(chip, (3, 3)), _spec(chip, (3,)))
+                   _serving_params(chip), _spec(chip, (3, 3)),
+                   _spec(chip, (3,)))
     temps = exe.memory_analysis().temp_size_in_bytes
     assert temps < HBM_BYTES // 4, temps
 
