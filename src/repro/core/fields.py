@@ -7,21 +7,26 @@ two-phase ASDR pipeline, the Pallas volume-render kernel driver) consumes a
   * the trained Instant-NGP network      (``model.field_fns``),
   * the exact analytic scene field       (``analytic_field_fns``) — used by
     tests to validate the *algorithm* independently of training error,
-  * a Pallas-kernel-backed fused network (``kernels.ops.field_fns``).
+  * a Pallas-kernel-backed fused network (``kernels.ops.field_fns``),
+  * TensoRF's VM-decomposed field          (``tensorf.field_fns``).
 
 ``density(points) -> (sigma (N,), geo (N, G))`` and
-``color(geo, dirs) -> rgb (N, 3)``.  For analytic fields the "geo feature"
-is simply the ground-truth color and ``color`` is a projection.
+``color(geo, dirs) -> rgb (N, 3)``.  ``geo`` is whatever per-point input
+the color side needs, handed over unchanged (and only at the samples
+whose color runs, under decoupling): Instant-NGP's geometry feature, the
+ground-truth color of an analytic field (``color`` is then a
+projection), or TensoRF's sample point itself, whose appearance tables
+``color`` reads.
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple
+from typing import Callable, Dict, NamedTuple
 
 import jax.numpy as jnp
 
 
 class FieldFns(NamedTuple):
-    density: Callable  # (N,3) -> (sigma (N,), geo (N,G))
+    density: Callable  # (N,3) -> (sigma (N,), geo (N,G): any per-point input of color)
     color: Callable    # (geo (N,G), dirs (N,3)) -> rgb (N,3)
     # Optional fused-march resources (kernels.ops.FusedMarchResources).
     # When present AND ASDRConfig.march_backend == "fused", Phase II runs
@@ -42,7 +47,9 @@ class Bound:
 
     A plain class (identity hash) so a FieldFns carrying one stays
     hashable; ``unbind`` keys jitted code on ``(build, cfg)`` only, so
-    fields that differ only in their params share one executable.
+    fields that differ only in their params share one executable.  A
+    ``cfg`` with a ``gather_stats(params)`` method reports how the field
+    gathers its tables (``gather_stats`` below).
     """
 
     def __init__(self, build: Callable, cfg, params):
@@ -68,6 +75,17 @@ def rebind(key, params) -> FieldFns:
         return key
     build, cfg = key
     return build(params, cfg)
+
+
+def gather_stats(fns: FieldFns) -> Dict[str, int]:
+    """The table rows the field gathers a point, as its config reports
+    them: ``encode_gather_rows_per_sample`` (density side, every marched
+    sample), ``color_gather_rows_per_sample`` (color side, every point
+    its color runs) and any counts of the field's own.  Empty for a field
+    that reads no tables."""
+    b = fns.bound
+    report = getattr(b.cfg, "gather_stats", None) if b is not None else None
+    return report(b.params) if report is not None else {}
 
 
 def analytic_field_fns(field) -> FieldFns:

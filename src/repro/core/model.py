@@ -48,6 +48,15 @@ class NGPConfig:
             paper_mlp=paper_mlp,
         )
 
+    def gather_stats(self, params) -> Dict[str, int]:
+        """Hash-table rows gathered a point: one at each of the
+        ``corner_levels`` levels whose corner rows ``params`` carries
+        (``params["corners"]``, the kernel-backed field), 8 at every other
+        level; the color side reads no table."""
+        n = len(params.get("corners", ()))
+        return {"encode_gather_rows_per_sample": n + 8 * (self.grid.n_levels - n),
+                "corner_levels": n, "color_gather_rows_per_sample": 0}
+
 
 def init_ngp(key: jax.Array, cfg: NGPConfig) -> Dict:
     k1, k2 = jax.random.split(key)

@@ -415,17 +415,3 @@ def _bound_field_fns(params: Dict, cfg) -> FieldFns:
                     fused=FusedMarchResources(params, cfg),
                     bound=Bound(_bound_field_fns, cfg, params))
 
-
-def encode_gather_stats(fns: FieldFns) -> Dict[str, int]:
-    """How a field's hash encode gathers: ``corner_levels``, the levels it
-    reads from corner rows (one index a point each), and
-    ``encode_gather_rows_per_sample``, the gather indices per encoded
-    point summed over levels (8 at every other level).  Zero for a field
-    with no hash grid."""
-    bound = fns.bound
-    grid = getattr(bound.cfg, "grid", None) if bound is not None else None
-    if grid is None:
-        return {"encode_gather_rows_per_sample": 0, "corner_levels": 0}
-    n = len(bound.params.get("corners", ()))
-    return {"encode_gather_rows_per_sample": n + 8 * (grid.n_levels - n),
-            "corner_levels": n}

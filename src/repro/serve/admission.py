@@ -497,6 +497,14 @@ class Slot:
                  + (int(self.dens_chunks.sum())
                     if self.dens_layout is not None else 0))
                 * self.block_size * acfg.chunk,
+            # samples whose color side ran: the march's decoupling
+            # anchors (ceil(chunk / group) a chunk of a block's rays; a
+            # density-refresh or scene-store chunk runs none) and every
+            # probe sample
+            "color_samples":
+                (int(self.chunks.sum()) - self.cached_chunks)
+                * self.block_size * -(-acfg.chunk // acfg.group)
+                + (0 if self.maps is None else self.maps.cost),
             "density_rays": (0 if self.dens_layout is None
                              else int(self.dens_layout.march_idx.size)),
             "samples_reused": self.cached_chunks

@@ -34,6 +34,7 @@ import dataclasses
 from collections import Counter
 from typing import Dict, Optional
 
+from ..core import fields as _fields
 from ..obs import metrics as obs_metrics
 from ..obs.metrics import percentile as _percentile  # noqa: F401 (compat)
 
@@ -92,6 +93,7 @@ class EngineCounters:
     deadline_misses: int = 0
     samples_processed: int = 0
     samples_reused: int = 0
+    color_samples: int = 0        # march anchors + probe samples colored
     # sample work the fused march's per-RAY early exit skipped (pool
     # collect, gated on ASDRConfig.per_ray_early_exit): rays whose
     # transmittance saturated before their block's exit chunk stop
@@ -131,6 +133,7 @@ class EngineCounters:
         self.rays_total += req_stats["rays_total"]
         self.samples_processed += req_stats["samples_processed"]
         self.samples_reused += req_stats["samples_reused"]
+        self.color_samples += req_stats.get("color_samples", 0)
         self.latency_ms.observe(latency_s * 1e3)
         self.admit_stall_ms.observe(req_stats["admit_stall_s"] * 1e3)
         # scheduler accounting: every frame is either full-tier or shed
@@ -167,7 +170,7 @@ DETERMINISTIC_COUNTERS = (
     "frames", "admissions", "probe_hits", "probe_misses", "probe_skips",
     "probe_refreshes", "full_radiance_hits", "radiance_hits",
     "radiance_misses", "rays_marched", "rays_total", "samples_processed",
-    "samples_reused", "blocks_marched")
+    "samples_reused", "color_samples", "blocks_marched")
 
 
 def engine_stats(counters: EngineCounters, probe_caches: Dict,
@@ -207,6 +210,7 @@ def engine_stats(counters: EngineCounters, probe_caches: Dict,
                         for name, led in sorted(c.by_class.items())},
         "samples_processed": c.samples_processed,
         "samples_reused": c.samples_reused,
+        "color_samples": c.color_samples,
         "ray_exit_samples_skipped": c.ray_exit_samples_skipped,
         # streaming-dispatch round observability: march wall-time
         # percentiles + how many batches each round launched (a
@@ -268,14 +272,12 @@ def engine_stats(counters: EngineCounters, probe_caches: Dict,
     out["pack_cache_hits"] = pstats["hits"]
     out["pack_cache_misses"] = pstats["misses"]
     out["pack_cache_size"] = pstats["size"]
-    # how the hash encode gathers (kernels.ops.encode_gather_stats): the
-    # gather indices per encoded point and the levels read from corner
-    # rows, of the field that gathers the most when scenes differ
-    enc = max((_kops.encode_gather_stats(f) for f in (fields or {}).values()),
-              key=lambda e: e["encode_gather_rows_per_sample"],
-              default={"encode_gather_rows_per_sample": 0,
-                       "corner_levels": 0})
-    out.update(enc)
+    # how the fields gather their tables (fields.gather_stats, reported
+    # by each field's own config): rows a point on the density and color
+    # sides, the most of any scene's field where scenes differ
+    for f in (fields or {}).values():
+        for k, v in _fields.gather_stats(f).items():
+            out[k] = max(out.get(k, v), v)
     if registry is not None:
         for k, v in out.items():
             registry.set_value(k, v)
