@@ -28,9 +28,12 @@ anchors of color-density decoupling.  ``color`` clamps a point to the
 box (TensoRF never evaluates color outside it; an anchor just past the
 box can still be interpolated into a sample inside).
 
-Tables are stored a row a grid point: planes ``(3, n*n, R)`` (row
-``i_a * n + i_b``), lines ``(3, n, R)``.  A point gathers 4 plane rows
-and 2 line rows a pair: 18 rows a side.
+``init_params`` stores the tables a row a grid point: planes
+``(3, n*n, R)`` (row ``i_a * n + i_b``), lines ``(3, n, R)``.
+``field_fns`` serves them packed (``pack_tables``): a plane row holds the
+texel's four bilinear corners side by side, a line row its segment's two
+ends, so a point gathers one plane row and one line row a pair: 6 rows
+a side, each a one-index gather.
 """
 from __future__ import annotations
 
@@ -45,7 +48,7 @@ from .fields import Bound, FieldFns
 
 PLANE_AXES = ((0, 1), (0, 2), (1, 2))   # matMode
 LINE_AXES = (2, 1, 0)                   # vecMode
-ROWS_PER_POINT = 3 * 4 + 3 * 2          # plane corners + line ends, 3 pairs
+ROWS_PER_POINT = 3 + 3                  # a packed plane row + line row, 3 pairs
 FEATURE_PE = VIEW_PE = 2                # fea_pe, view_pe
 DENSITY_SHIFT = -10.0
 DISTANCE_SCALE = 25.0
@@ -106,26 +109,50 @@ def init_params(key, cfg: TensoRFConfig, scale: float = 0.1) -> Dict:
             "mlp": {"weights": weights, "biases": biases}}
 
 
+def _pack_side(side):
+    """A side's packed tables: ``corners`` ``(3, n*n, 4R)``, row ``i_a*n +
+    i_b`` holding plane rows ``row, row+1, row+n, row+n+1``; ``ends``
+    ``(3, n, 2R)``, row ``i`` holding line rows ``i, i+1``.  Rows with an
+    index ``n-1`` on an axis are never read (``base <= n-2``) and hold
+    zeros past the table's edge."""
+    planes, lines = side["planes"], side["lines"]
+    n, R = lines.shape[1], lines.shape[2]
+    p = jnp.pad(planes.reshape(3, n, n, R), ((0, 0), (0, 1), (0, 1), (0, 0)))
+    corners = jnp.concatenate([p[:, :n, :n], p[:, :n, 1:], p[:, 1:, :n], p[:, 1:, 1:]],
+                              axis=-1)
+    v = jnp.pad(lines, ((0, 0), (0, 1), (0, 0)))
+    return {"corners": corners.reshape(3, n * n, 4 * R),
+            "ends": jnp.concatenate([v[:, :n], v[:, 1:]], axis=-1)}
+
+
+@jax.jit
+def pack_tables(params: Dict) -> Dict:
+    """Both sides of ``init_params``' tables packed (``_pack_side``), as
+    one executable of its own name."""
+    return {k: _pack_side(params[k]) for k in ("density", "appearance")}
+
+
 def vm_features(points, tables, n: int):
     """(N, 3) points in the unit cube (clamped to it) -> (N, 3, R): the
-    products ``P_{m,r} * V_{m,r}`` of the three pairs, 18 rows gathered
-    a point.  Each corner of each pair is a gather of its own, one index
-    a point: the TPU compiler takes ~80 s for one gather of 12 indices a
-    point over 131,072 points, and under a second for each of twelve."""
-    R = tables["planes"].shape[-1]
+    products ``P_{m,r} * V_{m,r}`` of the three pairs from a side's packed
+    tables (``_pack_side``), 6 rows gathered a point.  Each row is a
+    gather of its own, one index a point: the TPU compiler takes ~80 s
+    for one gather of 12 indices a point over 131,072 points, and under a
+    second for each one-index gather."""
+    R = tables["ends"].shape[-1] // 2
     g = jnp.clip(points.astype(jnp.float32), 0.0, 1.0) * (n - 1)
     base = jnp.minimum(jnp.floor(g), n - 2).astype(jnp.int32)     # (N, 3)
     frac = g - base
-    planes = tables["planes"].reshape(3 * n * n, R)
-    lines = tables["lines"].reshape(3 * n, R)
+    corners = tables["corners"].reshape(3 * n * n, 4 * R)
+    ends = tables["ends"].reshape(3 * n, 2 * R)
     out = []
     for m, ((a, b), c) in enumerate(zip(PLANE_AXES, LINE_AXES)):
         fa, fb, fc = frac[:, a:a + 1], frac[:, b:b + 1], frac[:, c:c + 1]
-        row = m * n * n + base[:, a] * n + base[:, b]
-        p = (planes[row] * ((1 - fa) * (1 - fb)) + planes[row + 1] * ((1 - fa) * fb)
-             + planes[row + n] * (fa * (1 - fb)) + planes[row + n + 1] * (fa * fb))
-        line = m * n + base[:, c]
-        v = lines[line] * (1 - fc) + lines[line + 1] * fc
+        q = corners[m * n * n + base[:, a] * n + base[:, b]]
+        p = (q[:, :R] * ((1 - fa) * (1 - fb)) + q[:, R:2 * R] * ((1 - fa) * fb)
+             + q[:, 2 * R:3 * R] * (fa * (1 - fb)) + q[:, 3 * R:] * (fa * fb))
+        e = ends[m * n + base[:, c]]
+        v = e[:, :R] * (1 - fc) + e[:, R:] * fc
         out.append(p * v)
     return jnp.stack(out, axis=1)
 
@@ -137,8 +164,19 @@ def positional_encoding(x, freqs: int):
 
 
 def field_fns(params: Dict, cfg: TensoRFConfig) -> FieldFns:
-    """The VM field over ``params`` (``init_params``'s layout), bound so
-    jitted callers pass the tables as arguments (``fields.unbind``)."""
+    """The VM field over ``params`` (``init_params``' layout).  Its tables
+    are packed here, once per call (``pack_tables``), and the packed ones
+    alone are carried in the ``Bound`` params, so jitted callers
+    (``fields.unbind``) pass them as arguments and ``rebind`` inside a
+    trace only reads them."""
+    # waited for here, so a field is ready when built: the packing never
+    # runs on the device inside the caller's first march
+    packed = jax.block_until_ready(pack_tables(params))
+    return _bound_field_fns(dict(params, **packed), cfg)
+
+
+def _bound_field_fns(params: Dict, cfg: TensoRFConfig) -> FieldFns:
+    """``field_fns`` over params whose tables are already packed."""
     n = cfg.grid
 
     def density(points):
@@ -170,4 +208,4 @@ def field_fns(params: Dict, cfg: TensoRFConfig) -> FieldFns:
             return jax.nn.sigmoid(x)
 
     return FieldFns(density=density, color=color,
-                    bound=Bound(field_fns, cfg, params))
+                    bound=Bound(_bound_field_fns, cfg, params))

@@ -1,9 +1,10 @@
 """TensoRF's VM field (core/tensorf.py) at SMOKE size on seeded random
 weights: its sigma and rgb against the plain reference's equations
-(bench/reference/tensorf.py), frames served by RenderServingEngine against
-the reference render, a bfloat16 field failing the same tolerance, the
-tables passed to the march as arguments, and a tiny benchmark cell
-judged correct by the harness."""
+(bench/reference/tensorf.py), the packed encode against the one-gather-
+a-corner formulation, frames served by RenderServingEngine against the
+reference render, a bfloat16 field failing the same tolerance, the packed
+tables passed to the march as arguments, and a tiny benchmark cell judged
+correct by the harness."""
 import json
 import shutil
 import sys
@@ -16,7 +17,7 @@ import numpy as np
 import pytest
 
 from repro.configs.tensorf_vm192 import CONFIG, SMOKE
-from repro.core import fields, scene, tensorf
+from repro.core import fields, pipeline, scene, tensorf
 from repro.serve import pool
 from repro.serve.render_engine import (RenderRequest, RenderServeConfig,
                                        RenderServingEngine)
@@ -103,6 +104,94 @@ def test_density_spreads_its_reads_outside_the_box(params, monkeypatch):
     assert np.all(np.asarray(sigma)[1:] == 0)
 
 
+def features_18(points, side, n):
+    """The oracle: ``vm_features`` over the unpacked tables, one gather a
+    plane corner and a line end (18 indices a point), in the same order
+    of operations."""
+    R = side["planes"].shape[-1]
+    g = jnp.clip(points.astype(jnp.float32), 0.0, 1.0) * (n - 1)
+    base = jnp.minimum(jnp.floor(g), n - 2).astype(jnp.int32)
+    frac = g - base
+    planes = side["planes"].reshape(3 * n * n, R)
+    lines = side["lines"].reshape(3 * n, R)
+    out = []
+    for m, ((a, b), c) in enumerate(zip(tensorf.PLANE_AXES, tensorf.LINE_AXES)):
+        fa, fb, fc = frac[:, a:a + 1], frac[:, b:b + 1], frac[:, c:c + 1]
+        row = m * n * n + base[:, a] * n + base[:, b]
+        p = (planes[row] * ((1 - fa) * (1 - fb)) + planes[row + 1] * ((1 - fa) * fb)
+             + planes[row + n] * (fa * (1 - fb)) + planes[row + n + 1] * (fa * fb))
+        line = m * n + base[:, c]
+        v = lines[line] * (1 - fc) + lines[line + 1] * fc
+        out.append(p * v)
+    return jnp.stack(out, axis=1)
+
+
+def _points(where, n):
+    """Test points: inside the box, on its faces and corners, in the last
+    voxel of an axis (``base == n - 2``), and past the box as the density
+    side reads them (modulo the box)."""
+    k = jax.random.PRNGKey(5)
+    if where == "inside":
+        return jax.random.uniform(k, (512, 3))
+    if where == "faces":
+        x = jax.random.uniform(k, (512, 3))
+        axis = jnp.arange(512) % 3
+        return x.at[jnp.arange(512), axis].set(jnp.arange(512) // 3 % 2 * 1.0)
+    if where == "last_voxel":
+        lo = (n - 2) / (n - 1)
+        return jax.random.uniform(k, (512, 3), minval=lo, maxval=1.0).at[:8].set(1.0)
+    return jnp.mod(jax.random.uniform(k, (512, 3), minval=-0.5, maxval=1.5), 1.0)
+
+
+@pytest.mark.parametrize("side", ["density", "appearance"])
+@pytest.mark.parametrize("where", ["inside", "faces", "last_voxel", "past_box"])
+def test_packed_features_are_bit_identical(params, side, where):
+    """The packed encode (6 rows a point) equals the 18-gather one bit for
+    bit on the CPU."""
+    n = SMOKE.model.grid
+    x = _points(where, n)
+    if where == "last_voxel":
+        assert np.all(np.floor(np.asarray(x) * (n - 1)) >= n - 2)
+    packed = tensorf.pack_tables(params)[side]
+    got = jax.jit(lambda p, t: tensorf.vm_features(p, t, n))(x, packed)
+    want = jax.jit(lambda p, t: features_18(p, t, n))(x, params[side])
+    assert np.abs(np.asarray(want)).max() > 0.1
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_field_carries_packed_tables(params, monkeypatch):
+    """``field_fns`` packs the tables once and binds the packed ones alone;
+    a jitted rebind, the pooled march and the probe only read them."""
+    n, R = SMOKE.model.grid, SMOKE.model.appearance_components
+    fns = tensorf.field_fns(params, SMOKE.model)
+    bound = fns.bound.params
+    assert bound["appearance"]["corners"].shape == (3, n * n, 4 * R)
+    assert bound["appearance"]["ends"].shape == (3, n, 2 * R)
+    for side in ("density", "appearance"):
+        assert set(bound[side]) == {"corners", "ends"}
+        P = np.asarray(params[side]["planes"]).reshape(3, n, n, -1)
+        L = np.asarray(params[side]["lines"])
+        q = np.asarray(bound[side]["corners"]).reshape(3, n, n, 4, -1)
+        e = np.asarray(bound[side]["ends"]).reshape(3, n, 2, -1)
+        for k, (da, db) in enumerate(((0, 0), (0, 1), (1, 0), (1, 1))):
+            np.testing.assert_array_equal(q[:, :n - 1, :n - 1, k],
+                                          P[:, da:n - 1 + da, db:n - 1 + db])
+        np.testing.assert_array_equal(e[:, :n - 1], np.stack([L[:, :-1], L[:, 1:]], 2))
+    assert bound["basis"] is params["basis"] and bound["mlp"] is params["mlp"]
+
+    calls = []
+    real = tensorf.pack_tables
+    monkeypatch.setattr(tensorf, "pack_tables", lambda *a: calls.append(a) or real(*a))
+    fkey, b = fields.unbind(fns)
+    pts = jnp.linspace(0.0, 1.0, 96).reshape(32, 3)
+    jax.jit(lambda p, x: fields.rebind(fkey, p).color(x, x))(b, pts)
+    acfg = SMOKE.asdr
+    z = jnp.zeros((2, acfg.block_size, 3))
+    pool.batched_march(fns, acfg)(z, z, jnp.full((2,), acfg.ns_full, jnp.int32))
+    pipeline.probe_phase(fns, acfg, scene.look_at_camera(8, 8, 0.7, 0.5))
+    assert calls == []
+
+
 def _serve(p, cams):
     eng = RenderServingEngine({"s": tensorf.field_fns(p, SMOKE.model)}, SMOKE.asdr,
                               RenderServeConfig(slots=2, blocks_per_batch=4, reuse=None))
@@ -135,7 +224,7 @@ def test_engine_frames_match_reference(params, served):
     assert got.std() > 0.05                               # not one color
     rmse, max_abs = _errors(got, want)
     assert rmse < RMSE_TOL and max_abs < MAX_ABS_TOL, (rmse, max_abs)
-    assert st["encode_gather_rows_per_sample"] == st["color_gather_rows_per_sample"] == 18
+    assert st["encode_gather_rows_per_sample"] == st["color_gather_rows_per_sample"] == 6
     a = SMOKE.asdr
     anchors = sum(r.stats["samples_processed"] for r in reqs) // a.chunk * (a.chunk // a.group)
     probes = sum(r.stats["probe_samples"] for r in reqs)
@@ -165,9 +254,9 @@ def _largest_constant_bytes(hlo_text: str) -> int:
 
 
 def test_march_takes_tables_as_arguments():
-    """The jitted pooled march holds no constant over 1 MB: the tables
-    (1.2 MB of appearance at a 128^2 plane) are its arguments, while a
-    closure over the same field bakes them in."""
+    """The jitted pooled march holds no constant over 1 MB: the packed
+    tables (4.7 MB of appearance corners at a 128^2 plane) are its
+    arguments, while a closure over the same field bakes them in."""
     cfg = tensorf.TensoRFConfig(grid=128, density_components=2,
                                 appearance_components=6, feature_dim=5, hidden=16)
     p = tensorf.init_params(jax.random.PRNGKey(0), cfg, scale=1.0)
@@ -180,7 +269,8 @@ def test_march_takes_tables_as_arguments():
     text = march.func.lower(*march.args, z, z, budgets).as_text()
     assert _largest_constant_bytes(text) < 1 << 20
     fkey, bound = fields.unbind(fns)
-    assert bound is p
+    assert bound is fns.bound.params and bound["basis"] is p["basis"]
+    assert bound["appearance"]["corners"].nbytes == 4 * p["appearance"]["planes"].nbytes
     baked = jax.jit(lambda x: fns.color(x, x)).lower(z[0]).as_text()
     assert _largest_constant_bytes(baked) > 1 << 20
 
@@ -188,7 +278,7 @@ def test_march_takes_tables_as_arguments():
 def test_harness_runs_a_tiny_tensorf_cell_correct(tmp_path):
     """``harness.run`` on a TensoRF cell at SMOKE size on 16x16 fresh views,
     holding the fresh-128 cell's limits, is correct, and the traced run
-    reads the VM rows a marched sample (18 + 18 / group = 27)."""
+    reads the VM rows a marched sample (6 + 6 / group = 9)."""
     from bench import harness
     root = tmp_path / "checkout"
     shutil.copytree(ROOT / "bench", root / "bench",
@@ -224,7 +314,7 @@ def test_harness_runs_a_tiny_tensorf_cell_correct(tmp_path):
                         require_chip=False, root=root)
         assert r["correct"] and r["failed"] == 0, r["checks"]
         if trace:
-            assert r["metrics"]["vm_rows_per_sample.tensorf"]["value"] == 27.0
+            assert r["metrics"]["vm_rows_per_sample.tensorf"]["value"] == 9.0
         else:
             assert {"rays_per_s", "setup_s"} <= set(r["metrics"])
 

@@ -125,25 +125,30 @@ def test_blocked_probe_fits_one_chip(chip, compiled):
     assert temps < HBM_BYTES // 4, temps
 
 
-def _tensorf_params(sharding):
-    shapes = jax.eval_shape(lambda: tensorf.init_params(
-        jax.random.PRNGKey(0), tensorf_vm192.CONFIG.model))
+def _tensorf_serving_params(sharding):
+    """The params the serving field binds: the packed tables
+    ``tensorf.field_fns`` derives, the basis and the MLP."""
+    def serving():
+        params = tensorf.init_params(jax.random.PRNGKey(0), tensorf_vm192.CONFIG.model)
+        return dict(params, **tensorf.pack_tables(params))
+    shapes = jax.eval_shape(serving)
     return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), shapes)
 
 
 def test_tensorf_march_block_compiles(chip, compiled):
     """One 4096-ray block of TensoRF VM-192's march at its published widths
-    (300^2 planes, 16/48 components, the 150-128-128-3 MLP, 1196 samples):
-    one gather a plane corner or line end keeps the compile to seconds,
-    and a chunk step's temporaries well under one chip's HBM."""
+    (300^2 planes, 16/48 components, the 150-128-128-3 MLP, 1196 samples)
+    over the packed tables, as the engine runs it: one one-index gather a
+    packed plane or line row keeps the compile to seconds, and a chunk
+    step's temporaries well under one chip's HBM."""
     cfg, acfg = tensorf_vm192.CONFIG.model, tensorf_vm192.CONFIG.asdr
     B = acfg.block_size
 
     def march(params, o, d, budget):
-        return pipeline._march_block(tensorf.field_fns(params, cfg), acfg,
+        return pipeline._march_block(tensorf._bound_field_fns(params, cfg), acfg,
                                      o, d, budget)
 
-    exe = _compile(march, _tensorf_params(chip), _spec(chip, (B, 3)),
+    exe = _compile(march, _tensorf_serving_params(chip), _spec(chip, (B, 3)),
                    _spec(chip, (B, 3)), _spec(chip, (), jnp.int32))
     assert exe.memory_analysis().temp_size_in_bytes < HBM_BYTES // 8
 
