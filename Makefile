@@ -7,7 +7,7 @@ export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 .PHONY: test test-fleet test-full lint bench-serve bench-serve-sweep \
         bench-serve-latency bench-serve-workers bench-obs \
         bench-scenecache bench-scenecache-budgets bench-fleet \
-        bench-march bench-march-smoke bench-slo dryrun-serve
+        bench-slo dryrun-serve
 
 test:
 	$(PY) -m pytest -x -q
@@ -52,19 +52,6 @@ bench-scenecache:
 
 bench-scenecache-budgets:
 	$(PY) benchmarks/scene_cache.py --budgets
-
-# fused single-kernel march vs chunked reference: <=0.1 dB + speedup
-# >=1.0 gates on a trained NGP, the FULL-config (64 MB tables) streamed
-# section at >=2x with the resident pin refused, the per-ray-exit skip
-# counter, and the streaming-dispatch round gate; writes the canonical
-# BENCH_fused_march.json at the repo root
-bench-march:
-	$(PY) benchmarks/fused_march.py --quick
-
-# nightly regression smoke: one small replay frame asserting chunks
-# parity + the 0.1 dB ceiling (no root summary rewrite)
-bench-march-smoke:
-	$(PY) benchmarks/fused_march.py --smoke
 
 # SLO gate: open-loop Poisson overload — at the deepest factor
 # ShedPolicy must hold rt-class p99 under the FIFO baseline with

@@ -5,7 +5,7 @@ Drives the main path once at full width: ``configs/ingp_asdr.CONFIG``
 (16 x 2^19 x 2 hash tables, the paper MLP, 800x800 frames, up to 192
 samples, block 4096, chunk 32) with random weights made from a seed, served
 through ``RenderServingEngine`` with the default ``RenderServeConfig``
-(probe reuse on) and the reference march.  Every frame is checked against
+(probe reuse on) and the chunked march.  Every frame is checked against
 an independent float32 render on the chip; frame 0, whose probe is fresh,
 is also checked tightly against the single-image ASDR pipeline.
 
@@ -18,7 +18,6 @@ finds no TPU or when any check fails.  The last line of stdout is
 ``{"ok": true, "device": {"platform", "kind", "count"}}``.
 """
 import argparse
-import dataclasses
 import functools
 import json
 import math
@@ -200,8 +199,6 @@ def smoke_one_chip(bundle, hw, clock, on_tpu: bool = True):
     print(f"serve config: slots={rcfg.slots} "
           f"blocks_per_batch={rcfg.blocks_per_batch} "
           f"prefetch={rcfg.prefetch} probe_reuse={rcfg.reuse is not None}")
-    print(f"march_backend: {acfg.march_backend}")
-    check(acfg.march_backend == "reference", "CONFIG marches 'reference'")
 
     interpret = resolve_interpret()
     n_calls = march_kernel_calls(fns, acfg)
@@ -213,14 +210,6 @@ def smoke_one_chip(bundle, hw, clock, on_tpu: bool = True):
     if on_tpu:
         check(not interpret and n_calls >= 2,
               "fused-MLP kernels compile into the march")
-        fused = dataclasses.replace(acfg, march_backend="fused")
-        rays = jnp.zeros((1, acfg.block_size, 3))
-        try:
-            pipeline.march_blocks(fns, fused, rays, rays,
-                                  jnp.ones((1,), jnp.int32))
-            check(False, "march_backend='fused' raises on a TPU")
-        except ValueError as e:
-            print(f"pallas fused_march_call: refused on TPU ({e})")
 
     # warm-up: compile the engine's two large executables, the probe at
     # this frame size and the pooled march batch, outside the timed
@@ -285,7 +274,7 @@ def smoke_four_chips(bundle, hw, clock, on_tpu: bool = True):
     params_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
     fns = ops.field_fns(params, cfg)
     print(f"config: {bundle.name} frame={hw[0]}x{hw[1]} "
-          f"march_backend={acfg.march_backend} params_bytes={params_bytes}")
+          f"params_bytes={params_bytes}")
 
     # One slot: the other requests queue, so each round speculates Stage A
     # for the next ones (prefetch 2), and the device executor places that

@@ -1,13 +1,13 @@
 """Field abstraction — the composability seam of the renderer.
 
 Everything downstream of sampling (decoupling, adaptive probing, the
-two-phase ASDR pipeline, the Pallas volume-render kernel driver) consumes a
-``FieldFns`` pair instead of a concrete model, so the same pipeline runs
+two-phase ASDR pipeline and its chunked march) consumes a ``FieldFns``
+pair instead of a concrete model, so the same pipeline runs
 
   * the trained Instant-NGP network      (``model.field_fns``),
   * the exact analytic scene field       (``analytic_field_fns``) — used by
     tests to validate the *algorithm* independently of training error,
-  * a Pallas-kernel-backed fused network (``kernels.ops.field_fns``),
+  * Instant-NGP on the Pallas MLP kernels (``kernels.ops.field_fns``),
   * TensoRF's VM-decomposed field          (``tensorf.field_fns``).
 
 ``density(points) -> (sigma (N,), geo (N, G))`` and
@@ -26,14 +26,9 @@ import jax.numpy as jnp
 
 
 class FieldFns(NamedTuple):
+    """A field's two closures and, optionally, the arrays they capture."""
     density: Callable  # (N,3) -> (sigma (N,), geo (N,G): any per-point input of color)
     color: Callable    # (geo (N,G), dirs (N,3)) -> rgb (N,3)
-    # Optional fused-march resources (kernels.ops.FusedMarchResources).
-    # When present AND ASDRConfig.march_backend == "fused", Phase II runs
-    # the single-kernel streaming march (kernels/fused_march.py) instead
-    # of chunked density/color calls.  None everywhere else — analytic
-    # and pure-jnp fields keep the reference chunked march.
-    fused: object = None
     # Optional ``Bound``: the arrays the closures above capture, and how
     # to rebuild the closures around any value of them.  Jitted callers
     # use it (``unbind``/``rebind``) to pass the params as ARGUMENTS; a

@@ -10,7 +10,7 @@ Phase II — sort rays into difficulty-homogeneous blocks; march each block
 
 The pipeline is written against the ``FieldFns`` protocol (fields.py): the
 same code renders the trained Instant-NGP network, the exact analytic
-field (tests), or the Pallas fused-MLP kernel path.
+field (tests), or the Pallas MLP kernel path.
 
 Blocks are the data-parallel unit: `render_adaptive` exposes a pure
 per-block function that launch/ shards over the ``data`` mesh axis.
@@ -46,25 +46,6 @@ class ASDRConfig:
     # (count, probe-interpolated opacity) groups saturating rays into the
     # same blocks so whole blocks exit early (EXPERIMENTS.md §Perf).
     sort_by_opacity: bool = False
-    # Phase-II march backend: "reference" = chunked density/color calls
-    # per chunk (this module), "fused" = single-kernel streaming march
-    # (kernels/fused_march.py) when the FieldFns carries fused-march
-    # resources (fields without them fall back to the reference march).
-    march_backend: str = "reference"
-    # Fused-march table supply: "auto" keeps the hash-table stack
-    # VMEM-resident when it fits and streams levels through a
-    # double-buffered DMA pair when it does not (the only option at
-    # full-config table sizes); "resident"/"streamed" pin the choice
-    # (kernels.ops._select_streaming; "resident" refuses configs that
-    # exceed the VMEM budget).  Ignored by the reference backend.
-    march_table_streaming: str = "auto"
-    # Per-RAY early exit: rays whose transmittance saturates stop
-    # contributing sample work (their sigmas are masked) instead of
-    # riding until the whole block exits.  chunks_done and ray_chunks
-    # are unchanged by the flag — a dead ray's log-transmittance is
-    # already frozen below the threshold — and the rgb/acc deviation is
-    # bounded by the EARLY_TERM_TRANSMITTANCE tail.
-    per_ray_early_exit: bool = False
 
 
 def render_fixed_fns(
@@ -111,8 +92,7 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
     ``E[t] + (1 - acc) * FAR``, the full-resolution replacement for the
     probe's stride-d proxy depth (framecache warps register against it
     at depth edges); ray_chunks counts the chunks each ray entered
-    still live (un-saturated), the per-RAY refinement of chunks_done
-    that prices early-exit savings.
+    still live (un-saturated), the per-RAY refinement of chunks_done.
 
     With ``density_only`` (static) the color MLP never runs and rgb stays
     zero — the march only produces acc/depth, for rays whose radiance is
@@ -131,9 +111,7 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
     def body(state):
         ci, log_t, rgb, acc, dep, ray_chunks = state
         # per-ray liveness at chunk start: saturated rays stop counting
-        # toward ray_chunks; with per_ray_early_exit their sigma is also
-        # masked (freezing log_t), which cannot change the block-level
-        # exit chunk — a dead ray's log_t is already below the threshold
+        # toward ray_chunks
         alive = log_t > LOG_EPS_T
         idx = ci * C + jnp.arange(C)
         valid = idx < budget
@@ -143,8 +121,6 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
         sigma, geo = fns.density(flat)
         sigma = sigma.reshape(B, C)
         sigma = jnp.where(valid[None, :], sigma, 0.0)
-        if acfg.per_ray_early_exit:
-            sigma = jnp.where(alive[:, None], sigma, 0.0)
 
         if not density_only:
             geo = geo.reshape(B, C, -1)
@@ -195,17 +171,8 @@ def march_blocks(fns: FieldFns, acfg: ASDRConfig, o_b, d_b, budgets,
     (rgb (N,B,3), acc (N,B), depth (N,B), chunks (N,), ray_chunks
     (N,B) int32).
 
-    The backend seam for Phase II: with ``march_backend == "fused"`` and a
-    FieldFns carrying fused-march resources (kernels.ops.field_fns), the
-    whole batch runs as ONE streaming Pallas kernel; otherwise each block
-    runs the chunked reference march above under ``lax.map``.  Both paths
-    honor the same while_loop early-termination contract (identical
-    chunks_done, budgets masked identically).
+    Phase II: each block runs the chunked march above under ``lax.map``.
     """
-    if acfg.march_backend == "fused" and fns.fused is not None:
-        from ..kernels import ops as _kops  # lazy: core stays kernel-free
-        return _kops.fused_march_blocks(
-            fns.fused, acfg, o_b, d_b, budgets, density_only=density_only)
     march = partial(_march_block, fns, acfg, density_only=density_only)
     return jax.lax.map(lambda args: march(*args), (o_b, d_b, budgets))
 
@@ -279,8 +246,7 @@ def render_adaptive(fns: FieldFns, acfg: ASDRConfig, origins, dirs, counts,
         "baseline_samples": R * acfg.ns_full,
         "chunks_per_block": chunks,
         # per-ray live-chunk counts (block-sorted order): the gap to
-        # chunks_per_block * B is the sample work per-ray early exit
-        # can skip on saturated trajectories
+        # chunks_per_block is the chunks a ray rode after it saturated
         "ray_chunks_per_block": ray_chunks,
         "budgets": budgets,
         # full-resolution termination depth (ROADMAP item): replaces the

@@ -50,10 +50,7 @@ def encode_level(pts, res, is_dense, rows, table):
     """One level's trilinear hash encode: (M, 3) points x (T, F) table ->
     (M, F) features.
 
-    The in-kernel building block shared by this module's per-level grid
-    steps AND the fused march (fused_march.py), where the same math runs
-    against either the resident table stack or a double-buffered VMEM
-    streaming slot — one implementation, so the two kernels cannot drift.
+    The in-kernel building block of this module's per-level grid steps.
     ``res``/``is_dense``/``rows`` are traced scalars (one metadata row).
     """
     scaled = pts * res.astype(jnp.float32)

@@ -3,22 +3,24 @@
 Each wrapper owns the padding/layout contract documented in the kernel
 modules and exposes the *logical* shapes used by core/:
 
-  hash_encode(points, tables, cfg)        -> (N, L*F)
   density_mlp(enc, params, cfg)           -> (sigma (N,), geo (N, G))
   color_mlp(geo, dirs, params, cfg)       -> rgb (N, 3)
-  fused_field(points|enc, ...)            -> (sigma, rgb)
+  fused_field(enc, dirs, params, cfg)     -> (sigma, rgb, geo)
+  hash_encode(points, tables, cfg)        -> (N, L*F)
   volume_render(sigmas, anchors, deltas, group) -> (rgb, acc)
 
-``field_fns(params, cfg)`` returns a kernels-backed FieldFns so the whole
-ASDR pipeline (core/pipeline.py) can run on the kernel path; tests assert
-it matches the pure-jnp model path.
+``field_fns(params, cfg)`` returns a kernels-backed FieldFns — the XLA
+gather encode feeding ``density_mlp``/``color_mlp`` — so the ASDR
+pipeline (core/pipeline.py) marches on the MLP kernels; tests assert it
+matches the pure-jnp model path.  ``hash_encode`` and ``volume_render``
+are on no served path: Mosaic refuses the encode kernel's gather, and
+the march composites in XLA (kernels/README.md).
 
 The kernels interpret exactly on the CPU and run compiled everywhere else
 (``repro.kernels.resolve_interpret``); no wrapper overrides that.
 """
 from __future__ import annotations
 
-import math
 import threading
 from collections import OrderedDict
 from functools import partial
@@ -29,24 +31,10 @@ import jax.numpy as jnp
 
 from ..core import hashgrid
 from ..core import mlp as mlp_lib
-from ..core import rendering, scene
 from ..core.fields import Bound, FieldFns
-from . import fused_march as FMA
 from . import fused_mlp as FM
 from . import hash_encode as HE
-from . import resolve_interpret
 from . import volume_render as VR
-
-# Why the fused march cannot run compiled on a TPU (tests/test_tpu_compile.py
-# pins each refusal); ``fused_march_blocks`` raises this instead of
-# interpreting.
-FUSED_MARCH_TPU_REFUSAL = (
-    "march_backend='fused' does not compile for TPU: Mosaic refuses the "
-    "in-kernel VMEM gather table[idx] of hash_encode.encode_level ('Shape "
-    "mismatch in input, indices and output'), and the streamed supply's "
-    "per-level DMA tables_ref.at[level] ('Slice shape along dimension 2 "
-    "must be aligned to tiling (128), but is 2': the (T, F=2) tables pad "
-    "to 128 lanes). Use march_backend='reference'.")
 
 
 def _pad_rows(x, mult):
@@ -254,123 +242,6 @@ def volume_render(sigmas, anchor_colors, deltas, group: int,
     return rgb, acc
 
 
-# ---------------------------------------------------------------- fused march
-# Per-core VMEM budget the resident/streamed auto-select lowers against.
-# Re-exported from the kernel module so tests can shrink it (monkeypatch
-# THIS name) and force the streamed path on scaled-down shapes.
-FUSED_MARCH_VMEM_LIMIT = FMA.VMEM_LIMIT_BYTES
-
-
-class FusedMarchResources:
-    """Device-resident inputs for the fused streaming march kernel.
-
-    A plain class (identity hash/eq, like the FieldFns closures) so a
-    FieldFns carrying one stays hashable for serve/pool.py's jitted-march
-    LRU.  Holds the grid meta/tables and the memoized packed weight
-    stacks — building one is cheap after the first ``packed_weights``
-    call for the params.
-    """
-
-    def __init__(self, params: Dict, cfg):
-        self.meta = grid_meta(cfg.grid)
-        self.tables = params["grid"].astype(jnp.float32)
-        self.wd, self.wc = packed_weights(params["mlps"], cfg.net)
-        self.net = cfg.net
-
-
-def fused_march_vmem_bytes(acfg, res: FusedMarchResources,
-                           streamed: bool = False) -> int:
-    """Estimated VMEM bytes one fused-march grid step holds live.
-
-    The accounting behind the resident/streamed auto-select: the table
-    term is the whole (L, T, F) stack when resident but only the
-    (2, T, F) double-buffer pair when streamed — at the full config
-    (16 x 2^19 x 2 x 4 B = 64 MB stack vs an 8 MB pair against a 16 MB
-    VMEM) that difference is exactly why residency cannot ship.  The
-    weight stacks, ray/SH tiles, meta and output tile are counted too
-    so the select stays honest for fat blocks or deep MLPs.
-    """
-    L, T, F = res.tables.shape
-    B = acfg.block_size
-    f32 = 4
-    tables = (2 if streamed else L) * T * F * f32
-    weights = (res.wd.shape[0] + res.wc.shape[0]) * FMA.P * FMA.P * f32
-    rays = 2 * B * FMA.PPAD * f32          # origins + dirs tiles
-    sh = B * FMA.P * f32                   # per-ray SH color input
-    out = B * FMA.OUT_W * f32
-    meta = (L + 1) * 8 * 4                 # grid meta rows + budget row
-    return tables + weights + rays + sh + out + meta
-
-
-def _select_streaming(acfg, res: FusedMarchResources) -> bool:
-    """Resolve ``ASDRConfig.march_table_streaming`` to a concrete path.
-
-    "auto" streams exactly when the resident footprint would blow the
-    VMEM budget; "resident" is an explicit pin that refuses (rather
-    than silently overflows) configs that do not fit.
-    """
-    mode = getattr(acfg, "march_table_streaming", "auto")
-    if mode == "streamed":
-        return True
-    resident_bytes = fused_march_vmem_bytes(acfg, res, streamed=False)
-    if mode == "resident":
-        if resident_bytes > FUSED_MARCH_VMEM_LIMIT:
-            raise ValueError(
-                f"resident fused march needs {resident_bytes} B of VMEM "
-                f"(> {FUSED_MARCH_VMEM_LIMIT} B limit); this config only "
-                "runs with march_table_streaming='streamed' (or 'auto')")
-        return False
-    if mode != "auto":
-        raise ValueError(f"march_table_streaming={mode!r} not in "
-                         "('auto', 'resident', 'streamed')")
-    return resident_bytes > FUSED_MARCH_VMEM_LIMIT
-
-
-def fused_march_blocks(res: FusedMarchResources, acfg, o_b, d_b, budgets,
-                       density_only: bool = False):
-    """Run the single-kernel streaming march over a batch of blocks.
-
-    o_b/d_b (N, B, 3), budgets (N,) int32 -> (rgb (N,B,3), acc (N,B),
-    depth (N,B), chunks (N,), ray_chunks (N,B)) with
-    core.pipeline._march_block semantics (same chunk count, budget
-    masking, early termination; ray_chunks counts the chunks each ray
-    was still live for).  SH features are computed once per RAY here
-    (the reference path recomputes them per anchor-sample every chunk)
-    and placed at the color-input lanes.  Table supply (VMEM-resident
-    stack vs double-buffered DMA streaming) resolves per config via
-    ``_select_streaming`` — the two are bit-identical where both run.
-
-    Runs only where Pallas interprets (the CPU): on a TPU it raises
-    ``ValueError(FUSED_MARCH_TPU_REFUSAL)`` rather than interpret there.
-    """
-    if not resolve_interpret():
-        raise ValueError(FUSED_MARCH_TPU_REFUSAL)
-    N, B, _ = o_b.shape
-    o8 = _pad_cols(o_b.astype(jnp.float32).reshape(N * B, 3), FMA.PPAD)
-    d_flat = d_b.astype(jnp.float32).reshape(N * B, 3)
-    d8 = _pad_cols(d_flat, FMA.PPAD)
-    sh = (jnp.zeros((N * B, FMA.P), jnp.float32)
-          if density_only else _sh_padded(d_flat, res.net))
-    out = FMA.fused_march_call(
-        o8, d8, sh, budgets.astype(jnp.int32), res.meta, res.tables,
-        res.wd, res.wc,
-        block_size=B, geo_dim=res.net.geo_feature_dim, group=acfg.group,
-        chunk=acfg.chunk, near=scene.NEAR, far=scene.FAR,
-        log_eps_t=math.log(rendering.EARLY_TERM_TRANSMITTANCE),
-        early_term=acfg.early_termination,
-        white_background=acfg.white_background,
-        with_color=not density_only,
-        stream_tables=_select_streaming(acfg, res),
-        per_ray_exit=getattr(acfg, "per_ray_early_exit", False))
-    out = out.reshape(N, B, FMA.OUT_W)
-    acc = out[:, :, 0]
-    rgb = out[:, :, 1:4]
-    depth = out[:, :, 4]
-    chunks = out[:, 0, 5].astype(jnp.int32)
-    ray_chunks = out[:, :, 6].astype(jnp.int32)
-    return rgb, acc, depth, chunks, ray_chunks
-
-
 # ------------------------------------------------------------------- FieldFns
 @partial(jax.jit, static_argnums=(1,))
 def corner_tables(tables, grid):
@@ -388,9 +259,6 @@ def field_fns(params: Dict, cfg) -> FieldFns:
     derived here from ``params["grid"]``, once per call, and carried in
     the ``Bound`` params beside it, so jitted callers (``fields.unbind``)
     pass them as arguments and ``rebind`` inside a trace only reads them.
-    Carries FusedMarchResources so ``ASDRConfig.march_backend="fused"``
-    routes Phase II through the single-kernel streaming march where
-    Pallas interprets; that march encodes from the stacked tables.
     """
     # waited for here, so a field is ready when built: the derivation
     # never runs on the device inside the caller's first march
@@ -412,6 +280,5 @@ def _bound_field_fns(params: Dict, cfg) -> FieldFns:
         return color_mlp(geo, dirs, params["mlps"], cfg.net)
 
     return FieldFns(density=density, color=color,
-                    fused=FusedMarchResources(params, cfg),
                     bound=Bound(_bound_field_fns, cfg, params))
 

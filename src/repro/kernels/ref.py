@@ -59,12 +59,12 @@ def ref_fused_field(enc, sh, wd, wc):
     return sigma, rgb, geo
 
 
-# --------------------------------------------------------------- fused march
-def ref_fused_march(fns, acfg, o_b, d_b, budgets, density_only=False):
-    """Oracle for kernels/fused_march.py: the chunked reference march
-    (core/pipeline._march_block) over a pure-jnp FieldFns — the exact
-    while_loop early-termination contract the fused kernel must keep
-    (chunks_done equality is asserted, not just value closeness)."""
+# --------------------------------------------------------------------- march
+def ref_march(fns, acfg, o_b, d_b, budgets, density_only=False):
+    """The chunked march (core/pipeline._march_block) under ``lax.map``,
+    over whatever FieldFns the caller passes — with a pure-jnp field it is
+    the oracle a kernel-backed field's march must match, chunk counters
+    exactly."""
     from ..core import pipeline
 
     march = lambda a: pipeline._march_block(  # noqa: E731

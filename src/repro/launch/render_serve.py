@@ -159,8 +159,6 @@ def _concrete(args):
               if args.scenecache_mb > 0 else None)
     shared = (ShardedSceneCache(sc_cfg, shards=args.shards)
               if sc_cfg is not None and args.shards > 1 else None)
-    if args.march_backend != "reference":
-        acfg = dataclasses.replace(acfg, march_backend=args.march_backend)
     # observability switchboard: any of --trace / --trace-jsonl /
     # --metrics-jsonl / --flight-recorder turns the tracer on; all off
     # (the default) keeps every call site on the null-span fast path
@@ -296,12 +294,6 @@ def main():
                          "streaming scheduler; >1 lets the next-largest "
                          "scene group fill idle launches and double-"
                          "buffers host<->device transfers)")
-    ap.add_argument("--march-backend", choices=("reference", "fused"),
-                    default="reference",
-                    help="Phase-II march backend; 'fused' runs the "
-                         "single-kernel streaming Pallas march for "
-                         "FieldFns that carry fused resources (analytic "
-                         "fields fall back to the reference march)")
     ap.add_argument("--density-refresh", action="store_true",
                     help="march warp-served rays through the color-free "
                          "density march so warped frames regain exact "

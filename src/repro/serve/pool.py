@@ -69,12 +69,10 @@ def batched_march(fns, acfg, density_only: bool = False):
     itself is thread-safe and compilation happens lazily at the first
     call).
 
-    Routes through ``pipeline.march_blocks``, so a FieldFns carrying
-    fused-march resources under ``march_backend="fused"`` compiles the
-    single-kernel streaming march; everything else gets the chunked
-    reference march.  ``density_only`` marches skip the color MLP
-    entirely (rgb reads zero) — the cheap acc/depth refresh for rays
-    whose radiance came from the warp/radiance tiers.
+    Compiles ``pipeline.march_blocks``, the chunked march.
+    ``density_only`` marches skip the color MLP entirely (rgb reads zero)
+    — the cheap acc/depth refresh for rays whose radiance came from the
+    warp/radiance tiers.
     """
     fkey, params = fields.unbind(fns)
     key = (fkey, acfg, density_only)
@@ -413,16 +411,7 @@ class BlockPool:
         with trace_lib.span("pool.collect", batch=bid,
                             blocks=len(batch),
                             reqs=sorted({it[0].req.rid for it in batch})):
-            rgb, acc, depth, chunks, ray_chunks = (
-                np.asarray(a) for a in out)
-            if self.acfg.per_ray_early_exit and batch:
-                # sample work the per-ray exit skipped: rays that went
-                # dead ride chunks - ray_chunks masked chunks each, at
-                # chunk samples per ray per chunk (real blocks only)
-                nb = len(batch)
-                skipped = (chunks[:nb, None] - ray_chunks[:nb]).sum()
-                self.counters.ray_exit_samples_skipped += (
-                    int(skipped) * self.acfg.chunk)
+            rgb, acc, depth, chunks = (np.asarray(a) for a in out[:4])
             for i, it in enumerate(batch):
                 if it[7]:
                     it[0].deliver_density(it[1], acc[i], depth[i],

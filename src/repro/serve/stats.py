@@ -94,13 +94,6 @@ class EngineCounters:
     samples_processed: int = 0
     samples_reused: int = 0
     color_samples: int = 0        # march anchors + probe samples colored
-    # sample work the fused march's per-RAY early exit skipped (pool
-    # collect, gated on ASDRConfig.per_ray_early_exit): rays whose
-    # transmittance saturated before their block's exit chunk stop
-    # running the field, chunk-granular.  Stays 0 with the flag off, so
-    # it is deliberately NOT in DETERMINISTIC_COUNTERS — it prices an
-    # opt-in approximation tier, like the shed counters.
-    ray_exit_samples_skipped: int = 0
     # per-round streaming-dispatch observability (engine thread only):
     # wall time of each dispatch_round->collect window and how many
     # batches it launched.  Wall times are TIMING, not scheduling — they
@@ -211,7 +204,6 @@ def engine_stats(counters: EngineCounters, probe_caches: Dict,
         "samples_processed": c.samples_processed,
         "samples_reused": c.samples_reused,
         "color_samples": c.color_samples,
-        "ray_exit_samples_skipped": c.ray_exit_samples_skipped,
         # streaming-dispatch round observability: march wall-time
         # percentiles + how many batches each round launched (a
         # histogram {n_batches: rounds}); batches_per_round > 1 is the

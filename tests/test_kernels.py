@@ -174,9 +174,8 @@ def _pallas_interpret_flags(fn, *args):
 @pytest.mark.parametrize("on_cpu", [True, False])
 def test_interpret_mode_follows_platform(model, monkeypatch, on_cpu):
     """Every kernel interprets exactly on the CPU and lowers compiled on
-    any other backend; the fused march refuses to run off the CPU."""
+    any other backend."""
     from repro import kernels
-    from repro.core import pipeline
     from repro.kernels import fused_mlp as FM, hash_encode as HE
     assert kernels.on_cpu() == (jax.default_backend() == "cpu")
     monkeypatch.setattr(kernels, "on_cpu", lambda: on_cpu)
@@ -193,13 +192,3 @@ def test_interpret_mode_follows_platform(model, monkeypatch, on_cpu):
              (pts, params["grid"]))]:
         flags = _pallas_interpret_flags(fn, *args)
         assert flags and all(bool(f) is on_cpu for f in flags), flags
-    acfg = pipeline.ASDRConfig(ns_full=16, block_size=8, chunk=8,
-                               candidates=(4, 8), march_backend="fused")
-    rays = jnp.zeros((1, 8, 3))
-    args = (ops.field_fns(params, cfg), acfg, rays, rays,
-            jnp.ones((1,), jnp.int32))
-    if on_cpu:
-        pipeline.march_blocks(*args)
-    else:
-        with pytest.raises(ValueError, match="does not compile for TPU"):
-            pipeline.march_blocks(*args)

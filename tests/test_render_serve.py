@@ -274,45 +274,6 @@ def test_engine_stats_expose_pack_cache(setup):
     assert st1["pack_cache_size"] >= 1
 
 
-def test_ray_exit_skip_counter(setup):
-    """pool.collect prices per-ray early exit: with the flag on, the
-    gap between each block's chunk count and its rays' live-chunk
-    counts lands in ``ray_exit_samples_skipped`` (chunk samples per
-    skipped ray-chunk); with the flag off the counter stays zero."""
-    import dataclasses
-    from repro.serve import pool as pool_lib, stats as stats_lib
-
-    class _FakeReq:
-        rid, scene = 0, "mic"
-
-    class _FakeSlot:
-        req = _FakeReq()
-
-        def deliver(self, bi, rgb, acc, depth, chunks, cached=False):
-            pass
-
-    B = 4
-    acfg = dataclasses.replace(ACFG, block_size=B, per_ray_early_exit=True)
-    counters = stats_lib.EngineCounters()
-    pool = pool_lib.BlockPool(acfg, 2, None, counters)
-    slot = _FakeSlot()
-    batch = [(slot, 0, None, None, 64, None, None, False)]
-    out = (np.zeros((2, B, 3)), np.zeros((2, B)), np.zeros((2, B)),
-           np.asarray([4, 1]),                      # block chunks (1 pad)
-           np.asarray([[4, 2, 1, 4], [1, 1, 1, 1]]))  # per-ray chunks
-    pool.collect((batch, [], 1, out, 1))
-    # real block: (4-4)+(4-2)+(4-1)+(4-4) = 5 skipped ray-chunks; the
-    # pad block's gap must NOT count
-    assert counters.ray_exit_samples_skipped == 5 * acfg.chunk
-    # flag off: identical collect books nothing
-    counters2 = stats_lib.EngineCounters()
-    pool2 = pool_lib.BlockPool(ACFG, 2, None, counters2)
-    pool2.collect((batch, [], 1, out, 2))
-    assert counters2.ray_exit_samples_skipped == 0
-    assert "ray_exit_samples_skipped" in stats_lib.engine_stats(
-        counters, {}, {}, None)
-
-
 def test_density_refresh_enables_radiance_chaining(setup):
     """Opt-in density refresh: partially-warped frames re-march their
     warp-valid rays color-free, recovering marched acc/depth — so they

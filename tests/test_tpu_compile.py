@@ -22,7 +22,6 @@ from repro import kernels
 from repro.configs import tensorf_vm192
 from repro.configs.ingp_asdr import CONFIG
 from repro.core import hashgrid, model as model_lib, pipeline, scene, tensorf
-from repro.kernels import fused_march as FMA
 from repro.kernels import fused_mlp as FM
 from repro.kernels import hash_encode as HE
 from repro.kernels import ops
@@ -163,27 +162,3 @@ def test_hash_encode_kernel_compiles(chip, compiled):
              _spec(chip, (ROWS, HE.PPAD)),
              _spec(chip, (g.n_levels, 8), jnp.int32),
              _spec(chip, (g.n_levels, g.table_size, g.feature_dim)))
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="Mosaic refuses the in-kernel gather table[idx] "
-                          "('Shape mismatch in input, indices and output'); "
-                          "behind it the streamed per-level DMA is refused "
-                          "('Slice shape along dimension 2 must be aligned "
-                          "to tiling (128), but is 2')")
-def test_fused_march_kernel_compiles(chip, compiled):
-    cfg, acfg = CONFIG.model, CONFIG.asdr
-    g, B, N = cfg.grid, acfg.block_size, 16
-    mlps = _params(chip)["mlps"]
-    call = partial(FMA.fused_march_call, block_size=B,
-                   geo_dim=cfg.net.geo_feature_dim, group=acfg.group,
-                   chunk=acfg.chunk, near=scene.NEAR, far=scene.FAR,
-                   log_eps_t=-9.2, early_term=True, white_background=True,
-                   with_color=True, stream_tables=True)
-    _compile(call,
-             _spec(chip, (N * B, FMA.PPAD)), _spec(chip, (N * B, FMA.PPAD)),
-             _spec(chip, (N * B, FMA.P)), _spec(chip, (N,), jnp.int32),
-             _spec(chip, (g.n_levels, 8), jnp.int32),
-             _spec(chip, (g.n_levels, g.table_size, g.feature_dim)),
-             _spec(chip, (len(mlps["density"]), FMA.P, FMA.P)),
-             _spec(chip, (len(mlps["color"]), FMA.P, FMA.P)))
