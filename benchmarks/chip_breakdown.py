@@ -2,6 +2,7 @@
 
     python3 benchmarks/chip_breakdown.py           # on a TPU, CONFIG
     python3 benchmarks/chip_breakdown.py --small   # SMOKE, any backend
+    python3 benchmarks/chip_breakdown.py --levels  # the per-level part alone
 
 Times, on ``configs/ingp_asdr.CONFIG`` with seeded weights (tables
 scaled x1e4, as in ``chip_smoke.py``):
@@ -9,8 +10,12 @@ scaled x1e4, as in ``chip_smoke.py``):
   * the hash encode of one march step (block_size x chunk = 131,072
     points) in nine formulations of the same gather, each checked
     against ``hashgrid.encode``: seven gather 8 table rows a level, two
-    gather one corner row a point at the levels with ``res^3 <= T``
-    (``hashgrid.corner_tables``, derived outside the timed call);
+    gather one corner row a point at the levels the device's memory
+    holds (``hashgrid.corner_tables``, derived outside the timed call);
+  * the corner-row build the served field runs (``ops.corner_tables``),
+    then each level past ``res^3 <= T`` that it covers, one level at a
+    time: its 8-row gather (``encode_level``) against its corner row
+    (``encode_corner_level``), checked equal;
   * the fused-MLP Pallas kernels against XLA's own matmuls (``core.mlp``);
   * the field's density (encode + MLP) in a jit that closes over the
     params, i.e. holds them as constants;
@@ -162,7 +167,7 @@ def encoders(g, T, F):
         return encode_corners_packed(p, tc[0], tc[1], g)
 
     def with_corners(t):
-        return t, hashgrid.corner_tables(t, g)
+        return t, hashgrid.corner_tables(t, g, ops.device_memory_bytes(t))
 
     def with_packed(t):
         return t, tuple(pack_corners(c) for c in hashgrid.corner_tables(t, g))
@@ -175,10 +180,34 @@ def encoders(g, T, F):
         ("J_corner_packed", corner_packed, with_packed)]
 
 
+def per_level(tables, g, pts):
+    """Build the served corner rows, then time each level past
+    ``res^3 <= T`` they cover by its 8 table rows and by its corner row."""
+    memory = ops.device_memory_bytes(tables)
+    levels = hashgrid.corner_levels(g, memory)
+    print(f"corner levels {levels} of memory_bytes={memory}: "
+          f"{sum(hashgrid.corner_bytes(g, l) for l in levels)} B", flush=True)
+    corners = timeit("corner_tables build", ops.corner_tables, tables, g,
+                     memory, n=3)
+    for l in levels:
+        res = g.level_resolution(l)
+        if res ** 3 <= g.table_size:
+            continue
+        rows = timeit(f"level {l} res={res} 8 rows", jax.jit(
+            lambda p, t: hashgrid.encode_level(p, t, res, g.level_is_dense(l))),
+            pts, tables[l])
+        row = timeit(f"level {l} res={res} corner row", jax.jit(
+            lambda p, c: hashgrid.encode_corner_level(p, c, res)),
+            pts, corners[l])
+        print(f"  max_abs={float(jnp.max(jnp.abs(rows - row))):.3e}", flush=True)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--small", action="store_true",
                     help="SMOKE config, 48x48 frame (a rehearsal)")
+    ap.add_argument("--levels", action="store_true",
+                    help="only the corner-row build and the per-level gathers")
     args = ap.parse_args(argv)
     dev = jax.devices()[0]
     if not args.small and dev.platform != "tpu":
@@ -194,6 +223,12 @@ def main(argv=None) -> int:
     _, T, F = tables.shape
     P = 512 if args.small else acfg.block_size * acfg.chunk
     pts = jax.random.uniform(jax.random.PRNGKey(1), (P, 3))
+
+    if args.levels:
+        per_level(tables, cfg.grid, pts)
+        print("peak_bytes_in_use",
+              (dev.memory_stats() or {}).get("peak_bytes_in_use"))
+        return 0
 
     # A formulation that does not compile for this backend is a result,
     # not a fault: print the refusal and time the next one.

@@ -9,19 +9,23 @@ size exceeds the table.  That is exactly the split we implement: a level is
 (perfect locality, the TPU analogue of conflict-free crossbar rows) and
 high-res levels use Instant-NGP's spatial hash (Eq. 2).
 
-Serving reads the coarse levels from *corner rows* (``corner_tables``): a
-level whose voxel count ``res^3`` is at most the table's row count ``T``
-stores, for each voxel, its 8 corners' features in one row of ``8*F``
-floats, so a point gathers one row there instead of 8.  The rows are
-fetched through ``level_indices`` (dense and hashed alike, collisions
-included) and blended with the same weights, so the encoding is the same.
-Only those levels qualify because ``res^3 <= T`` bounds the copy at 8x
-that level's table; finer levels would need more rows than the table has.
-At the paper's config (T = 2^19, F = 2) levels 0-5 (res 16, 22, 30, 42,
-58, 80; level 5 is hashed) qualify: 822,944 voxels of 64 B, i.e. 0.26,
-0.68, 1.73, 4.74, 12.49 and 32.77 MB, 52.7 MB in all, beside the 64 MB
-of stacked tables.  Training keeps the stacked tables alone: it updates
-them every step and takes gradients into them.
+Serving reads its coarser levels from *corner rows* (``corner_tables``):
+such a level stores, for each voxel, its 8 corners' features in one row
+of ``8*F`` floats, so a point gathers one row there instead of 8.  The
+rows are fetched through ``level_indices`` (dense and hashed alike,
+collisions included) and blended with the same weights, so the encoding
+is the same at any level; only memory bounds which levels get them
+(``corner_levels``).  A level's rows take ``res^3 * 8 * F * 4`` bytes:
+levels are taken in order of resolution while their rows fit in a
+quarter of the serving device's memory.  On a 16 GB chip at the paper's
+config (T = 2^19, F = 2) that is levels 0-9 (res 16 ... 294; 6-9 are
+hashed past ``res^3 <= T``): 40.7M voxels of 64 B, 2.6 GB beside the
+64 MB of stacked tables; level 10 alone would take 4.28 GB.  Where the
+device reports no memory size, the levels with ``res^3 <= T`` (0-5,
+52.7 MB) qualify.  The rows are built z-slab by z-slab from each slab's
+vertex features (one index a vertex), so a level's build holds a small
+fraction of its rows in temporaries.  Training keeps the stacked tables
+alone: it updates them every step and takes gradients into them.
 
 All functions are pure; parameters are plain pytrees.
 """
@@ -33,9 +37,18 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 # Instant-NGP's hash primes (Eq. 2 of the ASDR paper / Müller et al. 2022).
 PRIMES = (1, 2654435761, 805459861)
+
+# The 8 corners of a unit voxel as (dx, dy, dz) offsets, in the order a
+# corner row stores them.
+CORNERS = np.stack(np.meshgrid([0, 1], [0, 1], [0, 1], indexing="ij"),
+                   axis=-1).reshape(8, 3)
+
+# Voxels a corner-row slab holds: 64 MB of rows at F = 2.
+SLAB_VOXELS = 1 << 20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,8 +103,7 @@ def init_hashgrid(key: jax.Array, cfg: HashGridConfig, dtype=jnp.float32):
 
 def _corner_offsets() -> jnp.ndarray:
     """The 8 corners of a unit voxel, shape (8, 3), int32."""
-    offs = np.stack(np.meshgrid([0, 1], [0, 1], [0, 1], indexing="ij"), axis=-1)
-    return jnp.asarray(offs.reshape(8, 3), dtype=jnp.int32)
+    return jnp.asarray(CORNERS, dtype=jnp.int32)
 
 
 def level_indices(coords: jnp.ndarray, res: int, dense: bool, table_size: int) -> jnp.ndarray:
@@ -140,14 +152,33 @@ def encode_level(
     return _blend(feats, frac, points.dtype)
 
 
-def corner_levels(cfg: HashGridConfig) -> Tuple[int, ...]:
-    """The levels served from corner rows: those with ``res^3 <= T``."""
-    return tuple(l for l in range(cfg.n_levels)
-                 if cfg.level_resolution(l) ** 3 <= cfg.table_size)
+def corner_bytes(cfg: HashGridConfig, level: int) -> int:
+    """Bytes of one level's corner rows (res^3, 8*F) in float32."""
+    return cfg.level_resolution(level) ** 3 * 8 * cfg.feature_dim * 4
+
+
+def corner_levels(cfg: HashGridConfig, memory_bytes: int | None = None) -> Tuple[int, ...]:
+    """The levels served from corner rows, always the first few.
+
+    With ``memory_bytes`` (the serving device's memory), the levels in
+    order of resolution while their corner rows together fit in a
+    quarter of it; without, those with ``res^3 <= T``."""
+    if memory_bytes is None:
+        return tuple(l for l in range(cfg.n_levels)
+                     if cfg.level_resolution(l) ** 3 <= cfg.table_size)
+    levels, used = [], 0
+    for l in range(cfg.n_levels):
+        used += corner_bytes(cfg, l)
+        if used > memory_bytes // 4:
+            break
+        levels.append(l)
+    return tuple(levels)
 
 
 def corner_table(table: jnp.ndarray, res: int, dense: bool) -> jnp.ndarray:
-    """One level's corner rows (res^3, 8*F) from its table (T, F).
+    """One level's corner rows (res^3, 8*F) from its table (T, F), by
+    definition: ``corner_table_slabs`` builds the same rows in bounded
+    memory.
 
     Row ``v = x + res*(y + res*z)`` holds the features of voxel (x, y, z)'s
     8 corners in ``_corner_offsets()`` order, each fetched through
@@ -161,12 +192,50 @@ def corner_table(table: jnp.ndarray, res: int, dense: bool) -> jnp.ndarray:
     return table[idx].reshape(res**3, -1)
 
 
-def corner_tables(tables: jnp.ndarray, cfg: HashGridConfig) -> Tuple[jnp.ndarray, ...]:
-    """``corner_table`` of every level in ``corner_levels(cfg)``, from the
-    stacked tables (L, T, F)."""
+def _slab_rows(table, res: int, dense: bool, z0, planes: int) -> jnp.ndarray:
+    """Corner rows (planes*res^2, 8*F) of the voxel planes z0 ..
+    z0+planes-1: their vertices' features gathered once each (one index
+    a vertex), then each row assembled from 8 shifted slices.  Feature
+    major, so the slices keep x on the minor axis: the TPU compiler takes
+    ~10 s for level 9's build this way, ~35 s with F on the minor axis
+    (or with the transpose done on the 2-D (8*F, V) view)."""
+    r = jnp.arange(res + 1, dtype=jnp.int32)
+    zs = z0 + jnp.arange(planes + 1, dtype=jnp.int32)
+    z, y, x = jnp.meshgrid(zs, r, r, indexing="ij")
+    idx = level_indices(jnp.stack([x, y, z], axis=-1), res, dense, table.shape[0])
+    F = table.shape[1]
+    verts = jnp.stack([table[:, f][idx] for f in range(F)])
+    feats = jnp.stack([verts[:, dz:dz + planes, dy:dy + res, dx:dx + res]
+                       for dx, dy, dz in CORNERS])  # (8, F, planes, res, res)
+    return feats.reshape(8, F, -1).transpose(2, 0, 1).reshape(-1, 8 * F)
+
+
+def corner_table_slabs(table: jnp.ndarray, res: int, dense: bool,
+                       planes: int | None = None) -> jnp.ndarray:
+    """``corner_table``, built ``planes`` z-planes of voxels at a time
+    (default: ``SLAB_VOXELS`` voxels a slab) into the rows in place.
+    Where ``planes`` does not divide ``res`` the last slab starts at
+    ``res - planes`` and writes some rows again, with the same values."""
+    if planes is None:
+        planes = SLAB_VOXELS // res**2
+    planes = max(1, min(planes, res))
+
+    def slab(i, rows):
+        z0 = jnp.minimum(i * planes, res - planes)
+        return lax.dynamic_update_slice(
+            rows, _slab_rows(table, res, dense, z0, planes), (z0 * res * res, 0))
+
+    rows = jnp.zeros((res**3, 8 * table.shape[1]), table.dtype)
+    return lax.fori_loop(0, -(-res // planes), slab, rows)
+
+
+def corner_tables(tables: jnp.ndarray, cfg: HashGridConfig,
+                  memory_bytes: int | None = None) -> Tuple[jnp.ndarray, ...]:
+    """The corner rows of every level in ``corner_levels(cfg,
+    memory_bytes)``, from the stacked tables (L, T, F)."""
     return tuple(
-        corner_table(tables[l], cfg.level_resolution(l), cfg.level_is_dense(l))
-        for l in corner_levels(cfg))
+        corner_table_slabs(tables[l], cfg.level_resolution(l), cfg.level_is_dense(l))
+        for l in corner_levels(cfg, memory_bytes))
 
 
 def encode_corner_level(points: jnp.ndarray, corners: jnp.ndarray, res: int) -> jnp.ndarray:
@@ -182,17 +251,16 @@ def encode(points: jnp.ndarray, tables: jnp.ndarray, cfg: HashGridConfig,
            corners: Tuple[jnp.ndarray, ...] = ()) -> jnp.ndarray:
     """Full multi-resolution encoding: (N, 3) -> (N, n_levels * feature_dim).
 
-    With ``corners`` (``corner_tables(tables, cfg)``) the levels of
-    ``corner_levels(cfg)`` gather one corner row a point instead of 8
-    table rows; the result is the same.  Its ops carry the
+    With ``corners`` (``corner_tables``: rows of the first
+    ``len(corners)`` levels) those levels gather one corner row a point
+    instead of 8 table rows; the result is the same.  Its ops carry the
     ``hash_encode`` scope in the profiler's metadata."""
-    by_level = dict(zip(corner_levels(cfg), corners))
     with jax.named_scope("hash_encode"):
         outs = []
         for l in range(cfg.n_levels):
             res = cfg.level_resolution(l)
-            if l in by_level:
-                outs.append(encode_corner_level(points, by_level[l], res))
+            if l < len(corners):
+                outs.append(encode_corner_level(points, corners[l], res))
             else:
                 outs.append(encode_level(points, tables[l], res, cfg.level_is_dense(l)))
         return jnp.concatenate(outs, axis=-1)

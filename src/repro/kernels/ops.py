@@ -243,10 +243,17 @@ def volume_render(sigmas, anchor_colors, deltas, group: int,
 
 
 # ------------------------------------------------------------------- FieldFns
-@partial(jax.jit, static_argnums=(1,))
-def corner_tables(tables, grid):
+@partial(jax.jit, static_argnums=(1, 2))
+def corner_tables(tables, grid, memory_bytes):
     """``hashgrid.corner_tables`` as one executable of its own name."""
-    return hashgrid.corner_tables(tables, grid)
+    return hashgrid.corner_tables(tables, grid, memory_bytes)
+
+
+def device_memory_bytes(x) -> int | None:
+    """The memory of the devices that hold ``x`` (the least
+    ``bytes_limit``), or None where the backend reports none (the CPU)."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in x.devices()]
+    return None if None in limits else min(limits)
 
 
 def field_fns(params: Dict, cfg) -> FieldFns:
@@ -254,15 +261,20 @@ def field_fns(params: Dict, cfg) -> FieldFns:
 
     The hash encode is the XLA gather (``core.hashgrid.encode``) on every
     platform, feeding the fused-MLP kernels: the Pallas encode kernel does
-    not compile for TPU (see hash_encode.py).  The levels with
-    ``res^3 <= T`` gather one corner row a point: their corner tables are
-    derived here from ``params["grid"]``, once per call, and carried in
-    the ``Bound`` params beside it, so jitted callers (``fields.unbind``)
-    pass them as arguments and ``rebind`` inside a trace only reads them.
+    not compile for TPU (see hash_encode.py).  The coarsest levels gather
+    one corner row a point: as many as ``hashgrid.corner_levels`` fits in
+    a quarter of the memory of the device holding ``params["grid"]``
+    (levels 0-9, 2.6 GB, on a 16 GB chip at the paper's config).  Their
+    corner tables are derived here from ``params["grid"]``, once per call,
+    and carried in the ``Bound`` params beside it, so jitted callers
+    (``fields.unbind``) pass them as arguments and ``rebind`` inside a
+    trace only reads them.
     """
     # waited for here, so a field is ready when built: the derivation
     # never runs on the device inside the caller's first march
-    corners = jax.block_until_ready(corner_tables(params["grid"], cfg.grid))
+    grid = params["grid"]
+    corners = jax.block_until_ready(
+        corner_tables(grid, cfg.grid, device_memory_bytes(grid)))
     return _bound_field_fns(dict(params, corners=corners), cfg)
 
 

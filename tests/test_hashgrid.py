@@ -94,41 +94,68 @@ def test_storage_utilization_improves_like_paper():
             assert c == 1
 
 
+def test_corner_levels_fit_a_quarter_of_the_device():
+    """Under a 16 GiB chip the paper's grid serves levels 0-9 from corner
+    rows (2.6 GB, within 4 GiB); level 10's 4.28 GB would not fit.
+    Without a memory size the levels with res^3 <= T (0-5) do."""
+    from repro.configs.ingp_asdr import CONFIG
+    g = CONFIG.model.grid
+    assert hg.corner_levels(g, 16 << 30) == tuple(range(10))
+    assert sum(hg.corner_bytes(g, l) for l in range(10)) <= (16 << 30) // 4
+    assert sum(hg.corner_bytes(g, l) for l in range(11)) > (16 << 30) // 4
+    assert hg.corner_levels(g) == tuple(range(6))
+    assert hg.corner_levels(g, 0) == ()
+
+
 def _corner_case(case):
-    """(grid, points) for the corner-row encode's cases."""
+    """(grid, points, device memory) for the corner-row encode's cases."""
     from repro.configs.ingp_asdr import CONFIG, SMOKE
     pts = jax.random.uniform(jax.random.PRNGKey(3), (257, 3))
     if case == "smoke":
-        return SMOKE.model.grid, pts
+        return SMOKE.model.grid, pts, None
     if case == "config":
-        return CONFIG.model.grid, pts
+        return CONFIG.model.grid, pts, None
     if case == "hashed_collisions":
         # res 16 is hashed ((16+1)^3 > 2^12) yet 16^3 = 2^12 voxels fit
         return hg.HashGridConfig(n_levels=3, log2_table_size=12,
-                                 max_resolution=64), pts
+                                 max_resolution=64), pts, None
+    # res 16, 20, 25, all hashed; 20^3 and 25^3 voxels exceed T = 2^12
+    past = hg.HashGridConfig(n_levels=3, log2_table_size=12,
+                             max_resolution=25)
+    if case == "hashed_past_table":
+        return past, pts, 1 << 30
+    if case == "memory_cuts_levels":
+        # a quarter of it holds levels 0-1's rows (774,144 B), not level 2's
+        return past, pts, 4 * 800_000
     # every corner, edge midpoint and face centre of the unit cube
     g = jnp.asarray([0.0, 0.5, 1.0])
     cube = jnp.stack(jnp.meshgrid(g, g, g, indexing="ij"), -1).reshape(-1, 3)
-    return SMOKE.model.grid, jnp.concatenate([cube, pts])
+    return SMOKE.model.grid, jnp.concatenate([cube, pts]), None
 
 
 @pytest.mark.parametrize("case", ["smoke", "config", "hashed_collisions",
-                                  "unit_cube_points"])
+                                  "unit_cube_points", "hashed_past_table",
+                                  "memory_cuts_levels"])
 def test_corner_row_encode_is_bit_identical(case):
-    """Levels with res^3 <= T read one corner row a point; every other
-    level gathers its 8 table rows.  The encoding is the same to the bit."""
-    cfg, pts = _corner_case(case)
+    """The levels ``corner_levels`` picks read one corner row a point;
+    every other level gathers its 8 table rows.  The encoding is the
+    same to the bit, at hashed levels past res^3 <= T too."""
+    cfg, pts, memory = _corner_case(case)
     want = {"smoke": (0, 1), "config": (0, 1, 2, 3, 4, 5),
-            "hashed_collisions": (0,), "unit_cube_points": (0, 1)}[case]
-    assert hg.corner_levels(cfg) == want
+            "hashed_collisions": (0,), "unit_cube_points": (0, 1),
+            "hashed_past_table": (0, 1, 2),
+            "memory_cuts_levels": (0, 1)}[case]
+    assert hg.corner_levels(cfg, memory) == want
     tables = hg.init_hashgrid(jax.random.PRNGKey(2), cfg)
-    corners = hg.corner_tables(tables, cfg)
+    corners = hg.corner_tables(tables, cfg, memory)
     for l, c in zip(want, corners):
         res = cfg.level_resolution(l)
         assert c.shape == (res ** 3, 8 * cfg.feature_dim)
-    if case == "hashed_collisions":
-        res = cfg.level_resolution(0)
-        assert not cfg.level_is_dense(0)
+    if case in ("hashed_collisions", "hashed_past_table"):
+        l = want[-1]
+        res = cfg.level_resolution(l)
+        assert not cfg.level_is_dense(l)
+        assert (res ** 3 > cfg.table_size) == (case == "hashed_past_table")
         verts = jnp.stack(jnp.meshgrid(*[jnp.arange(res + 1)] * 3,
                                        indexing="ij"), -1).reshape(-1, 3)
         rows = hg.level_indices(verts, res, False, cfg.table_size)
@@ -136,3 +163,20 @@ def test_corner_row_encode_is_bit_identical(case):
     got = hg.encode(pts, tables, cfg, corners)
     ref = hg.encode(pts, tables, cfg)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("res,dense,planes", [
+    (15, True, 4),      # dense; 4 does not divide 15: the last slab overlaps
+    (20, False, 6),     # hashed past T = 2^12, 6 does not divide 20
+    (20, False, 1),     # one plane a slab
+    (20, False, None),  # the default: the whole level in one slab
+    (25, False, 25),
+])
+def test_slab_built_corner_rows_equal_corner_table(res, dense, planes):
+    """The slab build (vertex features gathered once, rows written in
+    place slab by slab) gives ``corner_table``'s rows to the bit."""
+    table = jax.random.normal(jax.random.PRNGKey(4), (1 << 12, 2))
+    build = jax.jit(hg.corner_table_slabs, static_argnums=(1, 2, 3))
+    np.testing.assert_array_equal(
+        np.asarray(build(table, res, dense, planes)),
+        np.asarray(hg.corner_table(table, res, dense)))

@@ -377,3 +377,56 @@ def test_engine_frame_over_corner_rows_matches_model_field(smoke_field):
     assert out["kernel"][1]["encode_gather_rows_per_sample"] == 2 + 8 * 6
     assert out["model"][1]["corner_levels"] == 0
     assert out["model"][1]["encode_gather_rows_per_sample"] == 8 * 8
+
+
+def test_field_on_a_device_with_memory_serves_levels_past_the_table(
+        smoke_field, monkeypatch):
+    """Where the device reports its memory, ``ops.field_fns`` serves more
+    levels from corner rows than ``res^3 <= T`` allows: SMOKE's levels
+    2-3 (res 35 and 52, 2^14 rows) among them.  A frame over it matches
+    ``model.field_fns``, engine_stats() reports the levels, and neither a
+    jitted rebind nor the pooled march builds the tables again."""
+    import jax
+    from repro.core import hashgrid
+    from repro.core import model as model_lib
+    from repro.kernels import ops
+    from repro.serve import pool
+    cfg, params = smoke_field
+    g = cfg.grid
+    # a quarter of it holds levels 0-3's rows (12.8 MB), not level 4's
+    monkeypatch.setattr(ops, "device_memory_bytes", lambda x: 80 * 10 ** 6)
+    fns = ops.field_fns(params, cfg)
+    corners = fns.bound.params["corners"]
+    assert len(corners) == 4
+    assert g.level_resolution(3) ** 3 > g.table_size
+    for l, c in enumerate(corners):
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(
+            hashgrid.corner_table(params["grid"][l], g.level_resolution(l),
+                                  g.level_is_dense(l))))
+
+    cam = scene.look_at_camera(16, 16, theta=0.7, phi=0.5)
+    out = {}
+    for name, f in (("kernel", fns), ("model", model_lib.field_fns(params, cfg))):
+        eng = RenderServingEngine({"s": f}, ACFG, RenderServeConfig(
+            slots=1, blocks_per_batch=2, reuse=None))
+        req, = eng.render([RenderRequest(rid=0, scene="s", cam=cam)])
+        out[name] = (req.image, eng.engine_stats())
+        eng.close()
+    assert out["kernel"][0].std() > 1e-3
+    np.testing.assert_allclose(out["kernel"][0], out["model"][0],
+                               rtol=1e-4, atol=1e-5)
+    assert out["kernel"][1]["corner_levels"] == 4
+    assert out["kernel"][1]["encode_gather_rows_per_sample"] == 4 + 8 * 4
+
+    calls = []
+    real = ops.corner_tables
+    monkeypatch.setattr(ops, "corner_tables",
+                        lambda *a: calls.append(a) or real(*a))
+    fkey, bound = fields.unbind(fns)
+    pts = jnp.linspace(0.0, 1.0, 96).reshape(32, 3)
+    jax.jit(lambda p, x: fields.rebind(fkey, p).density(x)[0])(bound, pts)
+    acfg = pipeline.ASDRConfig(ns_full=16, candidates=(8, 16),
+                               block_size=8, chunk=8)
+    z = jnp.zeros((2, 8, 3))
+    pool.batched_march(fns, acfg)(z, z, jnp.full((2,), 16, jnp.int32))
+    assert calls == []

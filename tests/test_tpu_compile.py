@@ -64,11 +64,13 @@ def _params(sharding):
 
 
 def _serving_params(sharding):
-    """The params the serving field binds: the stacked tables, the MLPs
-    and the corner tables ``ops.field_fns`` derives from the tables."""
+    """The params the serving field binds on one v5e: the stacked tables,
+    the MLPs and the ten corner tables (levels 0-9) ``ops.field_fns``
+    derives from the tables for a chip of ``HBM_BYTES``."""
     params = _params(sharding)
     corners = jax.eval_shape(lambda t: hashgrid.corner_tables(
-        t, CONFIG.model.grid), params["grid"])
+        t, CONFIG.model.grid, HBM_BYTES), params["grid"])
+    assert len(corners) == 10
     return dict(params, corners=jax.tree.map(
         lambda s: _spec(sharding, s.shape, s.dtype), corners))
 
@@ -93,8 +95,7 @@ def test_fused_mlp_kernels_compile_at_paper_widths(chip, compiled, kernel):
 
 def test_reference_march_block_compiles(chip, compiled):
     """One 4096-ray block of the engine's march: XLA-gather encode (corner
-    rows at the levels with res^3 <= T) feeding the compiled fused-MLP
-    kernels."""
+    rows at levels 0-9) feeding the compiled fused-MLP kernels."""
     cfg, acfg = CONFIG.model, CONFIG.asdr
     B = acfg.block_size
 
@@ -122,6 +123,19 @@ def test_blocked_probe_fits_one_chip(chip, compiled):
                    _spec(chip, (3,)))
     temps = exe.memory_analysis().temp_size_in_bytes
     assert temps < HBM_BYTES // 4, temps
+
+
+def test_level9_corner_rows_build_in_slabs(chip, compiled):
+    """Level 9's corner rows (294^3 voxels, 1.63 GB) build slab by slab:
+    the build's temporaries stay under a quarter of the rows it writes."""
+    g = CONFIG.model.grid
+    res = g.level_resolution(9)
+    build = partial(hashgrid.corner_table_slabs, res=res,
+                    dense=g.level_is_dense(9))
+    exe = _compile(build, _spec(chip, (g.table_size, g.feature_dim)))
+    mem = exe.memory_analysis()
+    assert mem.output_size_in_bytes >= res ** 3 * 8 * g.feature_dim * 4
+    assert mem.temp_size_in_bytes < mem.output_size_in_bytes // 4, mem
 
 
 def _tensorf_serving_params(sharding):
