@@ -187,8 +187,8 @@ def per_level(tables, g, pts):
     levels = hashgrid.corner_levels(g, memory)
     print(f"corner levels {levels} of memory_bytes={memory}: "
           f"{sum(hashgrid.corner_bytes(g, l) for l in levels)} B", flush=True)
-    corners = timeit("corner_tables build", ops.corner_tables, tables, g,
-                     memory, n=3)
+    (corners,) = timeit("corner_tables build", ops.corner_tables, (tables,), (g,),
+                        memory, n=3)
     for l in levels:
         res = g.level_resolution(l)
         if res ** 3 <= g.table_size:

@@ -8,7 +8,9 @@ pair instead of a concrete model, so the same pipeline runs
   * the exact analytic scene field       (``analytic_field_fns``) — used by
     tests to validate the *algorithm* independently of training error,
   * Instant-NGP on the Pallas MLP kernels (``kernels.ops.field_fns``),
-  * TensoRF's VM-decomposed field          (``tensorf.field_fns``).
+  * TensoRF's VM-decomposed field          (``tensorf.field_fns``),
+  * nerfacto's contracted field with its proposal sampler
+                                           (``nerfacto.field_fns``).
 
 ``density(points) -> (sigma (N,), geo (N, G))`` and
 ``color(geo, dirs) -> rgb (N, 3)``.  ``geo`` is whatever per-point input
@@ -17,6 +19,15 @@ whose color runs, under decoupling): Instant-NGP's geometry feature, the
 ground-truth color of an analytic field (``color`` is then a
 projection), or TensoRF's sample point itself, whose appearance tables
 ``color`` reads.
+
+A field may bring its own ``sampler``, which Stage A runs over every
+pixel ray in place of ASDR's probe: ``sampler.edges(origins, dirs)``
+gives each ray's interval edges ``(R, n + 1)`` in the sampler's spacing
+space (ascending in [0, 1]), ``sampler.ts(edges)`` maps them to program
+distances along the ray, and ``sampler.samples_per_ray`` counts the
+field evaluations ``edges`` makes a ray.  The march then takes its
+samples at those intervals' midpoints, each as wide as its interval,
+instead of ASDR's uniform ones (``pipeline.march_blocks``).
 """
 from __future__ import annotations
 
@@ -35,6 +46,16 @@ class FieldFns(NamedTuple):
     # closure over concrete arrays would bake them into the executable
     # as constants (64 MB of hash tables at the paper config).
     bound: object = None
+    # Optional ``Sampler``: the field places its own samples, and Stage A
+    # runs it in place of ASDR's probe.
+    sampler: object = None
+
+
+class Sampler(NamedTuple):
+    """A field's own sampler (see the module docstring)."""
+    edges: Callable          # (origins (R,3), dirs (R,3)) -> (R, S+1) in [0, 1]
+    ts: Callable             # spacing-space edges -> program distances
+    samples_per_ray: int     # field evaluations ``edges`` makes a ray
 
 
 class Bound:

@@ -22,10 +22,13 @@ config (T = 2^19, F = 2) that is levels 0-9 (res 16 ... 294; 6-9 are
 hashed past ``res^3 <= T``): 40.7M voxels of 64 B, 2.6 GB beside the
 64 MB of stacked tables; level 10 alone would take 4.28 GB.  Where the
 device reports no memory size, the levels with ``res^3 <= T`` (0-5,
-52.7 MB) qualify.  The rows are built z-slab by z-slab from each slab's
-vertex features (one index a vertex), so a level's build holds a small
-fraction of its rows in temporaries.  Training keeps the stacked tables
-alone: it updates them every step and takes gradients into them.
+52.7 MB) qualify.  A field of several grids (nerfacto's main grid and
+two proposal grids) shares one such budget (``field_corner_levels``):
+levels of all its grids in order of their rows' size.  The rows are
+built z-slab by z-slab from each slab's vertex features (one index a
+vertex), so a level's build holds a small fraction of its rows in
+temporaries.  Training keeps the stacked tables alone: it updates them
+every step and takes gradients into them.
 
 All functions are pure; parameters are plain pytrees.
 """
@@ -163,16 +166,29 @@ def corner_levels(cfg: HashGridConfig, memory_bytes: int | None = None) -> Tuple
     With ``memory_bytes`` (the serving device's memory), the levels in
     order of resolution while their corner rows together fit in a
     quarter of it; without, those with ``res^3 <= T``."""
+    return field_corner_levels((cfg,), memory_bytes)[0]
+
+
+def field_corner_levels(grids: Tuple[HashGridConfig, ...],
+                        memory_bytes: int | None = None) -> Tuple[Tuple[int, ...], ...]:
+    """``corner_levels`` for each of a field's grids under one budget.
+
+    With ``memory_bytes``, the levels of all the grids in order of their
+    rows' size while together they fit in a quarter of it, so each grid
+    gets its first few; a field of one grid gets what ``corner_levels``
+    describes.  Without, each grid's levels with ``res^3 <= T``."""
     if memory_bytes is None:
-        return tuple(l for l in range(cfg.n_levels)
-                     if cfg.level_resolution(l) ** 3 <= cfg.table_size)
-    levels, used = [], 0
-    for l in range(cfg.n_levels):
-        used += corner_bytes(cfg, l)
+        return tuple(tuple(l for l in range(g.n_levels)
+                           if g.level_resolution(l) ** 3 <= g.table_size)
+                     for g in grids)
+    taken, used = [[] for _ in grids], 0
+    for size, i, l in sorted((corner_bytes(g, l), i, l)
+                             for i, g in enumerate(grids) for l in range(g.n_levels)):
+        used += size
         if used > memory_bytes // 4:
             break
-        levels.append(l)
-    return tuple(levels)
+        taken[i].append(l)
+    return tuple(tuple(t) for t in taken)
 
 
 def corner_table(table: jnp.ndarray, res: int, dense: bool) -> jnp.ndarray:
@@ -233,9 +249,18 @@ def corner_tables(tables: jnp.ndarray, cfg: HashGridConfig,
                   memory_bytes: int | None = None) -> Tuple[jnp.ndarray, ...]:
     """The corner rows of every level in ``corner_levels(cfg,
     memory_bytes)``, from the stacked tables (L, T, F)."""
+    return field_corner_tables((tables,), (cfg,), memory_bytes)[0]
+
+
+def field_corner_tables(tables, grids: Tuple[HashGridConfig, ...],
+                        memory_bytes: int | None = None):
+    """``corner_tables`` of each of a field's grids (stacked tables
+    ``tables[i]`` of ``grids[i]``) under ``field_corner_levels``' one
+    budget."""
     return tuple(
-        corner_table_slabs(tables[l], cfg.level_resolution(l), cfg.level_is_dense(l))
-        for l in corner_levels(cfg, memory_bytes))
+        tuple(corner_table_slabs(t[l], g.level_resolution(l), g.level_is_dense(l))
+              for l in levels)
+        for t, g, levels in zip(tables, grids, field_corner_levels(grids, memory_bytes)))
 
 
 def encode_corner_level(points: jnp.ndarray, corners: jnp.ndarray, res: int) -> jnp.ndarray:

@@ -64,9 +64,12 @@ def render_fixed_fns(
     return render_at(fns, origins, dirs, ts, white_background)
 
 
-def render_at(fns: FieldFns, origins, dirs, ts, white_background=True):
-    """``render_fixed_fns`` at given sample distances ts (R, S)."""
-    pts, deltas = scene.points_at(origins, dirs, ts)
+def render_at(fns: FieldFns, origins, dirs, ts, white_background=True,
+              deltas=None):
+    """``render_fixed_fns`` at given sample distances ts (R, S), each
+    sample ``deltas`` (R, S) wide (default: ASDR's uniform width)."""
+    pts, uniform = scene.points_at(origins, dirs, ts)
+    deltas = uniform if deltas is None else deltas
     R, S = pts.shape[:2]
     flat = pts.reshape(-1, 3)
     dflat = jnp.repeat(dirs, S, axis=0)
@@ -82,11 +85,26 @@ def render_at(fns: FieldFns, origins, dirs, ts, white_background=True):
     return rgb, aux
 
 
+def _interval_samples(fns: FieldFns, edges, chunk: int):
+    """The samples of a field's own intervals: midpoints and widths
+    (B, S') in program distances from spacing-space edges (B, S + 1)
+    (``fns.sampler.ts``), padded with zero-width samples to whole
+    chunks."""
+    t = fns.sampler.ts(edges)
+    pad = ((0, 0), (0, -(t.shape[1] - 1) % chunk))
+    return (jnp.pad(0.5 * (t[:, 1:] + t[:, :-1]), pad),
+            jnp.pad(t[:, 1:] - t[:, :-1], pad))
+
+
 def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
-                 density_only: bool = False):
+                 density_only: bool = False, edges=None):
     """March one block of rays with a traced per-block sample budget.
 
-    origins/dirs: (B, 3); budget: traced int32 scalar.
+    origins/dirs: (B, 3); budget: traced int32 scalar.  With ``edges``
+    (B, S + 1), the spacing-space intervals of a field's own sampler,
+    sample i of a ray sits at its interval's midpoint and is as wide as
+    the interval; else ASDR's ``budget`` uniform midpoints over
+    [NEAR, FAR].
     Returns (rgb (B,3), acc (B,), depth (B,), chunks_done scalar,
     ray_chunks (B,) int32) — depth is the per-ray termination depth
     ``E[t] + (1 - acc) * FAR``, the full-resolution replacement for the
@@ -100,7 +118,10 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
     """
     B = origins.shape[0]
     C = acfg.chunk
-    delta_t = (scene.FAR - scene.NEAR) / budget.astype(jnp.float32)
+    if edges is None:
+        delta_t = (scene.FAR - scene.NEAR) / budget.astype(jnp.float32)
+    else:
+        mids, widths = _interval_samples(fns, edges, C)
     n_chunks = (budget + C - 1) // C
 
     def cond(state):
@@ -115,8 +136,14 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
         alive = log_t > LOG_EPS_T
         idx = ci * C + jnp.arange(C)
         valid = idx < budget
-        ts = scene.NEAR + (idx.astype(jnp.float32) + 0.5) * delta_t
-        pts = origins[:, None, :] + ts[None, :, None] * dirs[:, None, :]
+        if edges is None:
+            ts = scene.NEAR + (idx.astype(jnp.float32) + 0.5) * delta_t
+            pts = origins[:, None, :] + ts[None, :, None] * dirs[:, None, :]
+            dt = delta_t
+        else:
+            ts = jax.lax.dynamic_slice_in_dim(mids, ci * C, C, axis=1)
+            dt = jax.lax.dynamic_slice_in_dim(widths, ci * C, C, axis=1)
+            pts = origins[:, None, :] + ts[..., None] * dirs[:, None, :]
         flat = pts.reshape(-1, 3)
         sigma, geo = fns.density(flat)
         sigma = sigma.reshape(B, C)
@@ -133,7 +160,7 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
             colors = decouple.interpolate_group_colors(col_a, acfg.group, C)
 
         with jax.named_scope("composite"):
-            alphas = rendering.alphas_from_sigmas(sigma, delta_t)
+            alphas = rendering.alphas_from_sigmas(sigma, dt)
             one_m = jnp.clip(1.0 - alphas, 1e-10, 1.0)
             log_steps = jnp.log(one_m)
             # transmittance inside the chunk, carried from previous chunks
@@ -143,7 +170,8 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
             if not density_only:
                 rgb = rgb + jnp.sum(w[..., None] * colors, axis=1)
             acc = acc + jnp.sum(w, axis=-1)
-            dep = dep + jnp.sum(w * ts[None, :], axis=-1)
+            dep = dep + jnp.sum(w * (ts if edges is not None else ts[None, :]),
+                                axis=-1)
             log_t = log_t + jnp.sum(log_steps, axis=-1)
         ray_chunks = ray_chunks + alive.astype(jnp.int32)
         return ci + 1, log_t, rgb, acc, dep, ray_chunks
@@ -166,14 +194,18 @@ def _march_block(fns: FieldFns, acfg: ASDRConfig, origins, dirs, budget,
 
 
 def march_blocks(fns: FieldFns, acfg: ASDRConfig, o_b, d_b, budgets,
-                 density_only: bool = False):
+                 density_only: bool = False, edges=None):
     """March a batch of blocks: o_b/d_b (N, B, 3), budgets (N,) int32 ->
     (rgb (N,B,3), acc (N,B), depth (N,B), chunks (N,), ray_chunks
-    (N,B) int32).
+    (N,B) int32).  ``edges`` (N, B, S + 1): the rays' intervals, for a
+    field that brings its own sampler.
 
     Phase II: each block runs the chunked march above under ``lax.map``.
     """
     march = partial(_march_block, fns, acfg, density_only=density_only)
+    if edges is not None:
+        return jax.lax.map(lambda args: march(*args[:3], edges=args[3]),
+                           (o_b, d_b, budgets, edges))
     return jax.lax.map(lambda args: march(*args), (o_b, d_b, budgets))
 
 
@@ -340,6 +372,35 @@ def probe_phase(fns: FieldFns, acfg: ASDRConfig, cam, probe_key=None,
     if not return_depth:
         return counts, probe_cost, opacity
     return counts, probe_cost, opacity, depth
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def proposal_edges(fkey, block: int, geom, params, rot, origin):
+    """Stage A of a field that brings its own sampler, jitted with the
+    field params as arguments: every pixel ray's interval edges (H*W,
+    S + 1) from ``sampler.edges``, in blocks of ``block`` rays under
+    ``lax.map`` (pad rays cropped)."""
+    H, W, focal = geom
+    o, d = scene.camera_rays(scene.Camera(H, W, focal, rot, origin))
+    R = H * W
+    pad = (-R) % block
+    o = jnp.concatenate([o, jnp.zeros((pad, 3))], axis=0)
+    d = jnp.concatenate([d, jnp.tile(jnp.asarray([[0.0, 0.0, 1.0]]), (pad, 1))],
+                        axis=0)
+    sampler = fields.rebind(fkey, params).sampler
+    e = jax.lax.map(lambda od: sampler.edges(*od),
+                    (o.reshape(-1, block, 3), d.reshape(-1, block, 3)))
+    return e.reshape(R + pad, -1)[:R]
+
+
+def proposal_phase(fns: FieldFns, acfg: ASDRConfig, cam):
+    """``proposal_edges`` for one camera, ``acfg.block_size`` rays a
+    block: (H*W, S + 1) spacing-space edges."""
+    fkey, params = fields.unbind(fns)
+    block = min(acfg.block_size, cam.height * cam.width)
+    return proposal_edges(fkey, block, (cam.height, cam.width, cam.focal),
+                          params, jnp.asarray(cam.c2w_rot),
+                          jnp.asarray(cam.origin))
 
 
 def render_asdr_image(fns: FieldFns, acfg: ASDRConfig, cam, probe_key=None):

@@ -75,11 +75,17 @@ class ProbeMaps:
     transferring it unwarped would misregister anything built on it.
     (The radiance store no longer consumes this map at all — finished
     frames are cached under the Phase-II march's own termination depth,
-    which is pose-aligned by construction.)"""
+    which is pose-aligned by construction.)
+
+    For a field that brings its own sampler, Phase I is that sampler:
+    ``edges`` (H*W, S + 1) holds every ray's interval edges, each count
+    is S, opacity is zero, depth None and cost the sampler's own samples
+    (``_proposal_maps``)."""
     counts: jnp.ndarray
     opacity: jnp.ndarray
     depth: jnp.ndarray | None
     cost: int
+    edges: jnp.ndarray | None = None
 
 
 @dataclasses.dataclass
@@ -148,7 +154,20 @@ class ProbeCache(PoseKeyedCache):
                                                last_used=clock))
 
 
+def _proposal_maps(fns: FieldFns, acfg: ASDRConfig, cam) -> ProbeMaps:
+    """Phase I of a field that brings its own sampler: its interval
+    edges for every pixel ray, one executable (``pipeline.proposal_edges``)."""
+    R = cam.height * cam.width
+    with trace_lib.span("stage_a.proposal", rays=R):
+        edges = pipeline.proposal_phase(fns, acfg, cam)
+    S = edges.shape[1] - 1
+    return ProbeMaps(jnp.full((R,), S, jnp.int32), jnp.zeros((R,)), None,
+                     R * fns.sampler.samples_per_ray, edges)
+
+
 def _fresh_probe(fns: FieldFns, acfg: ASDRConfig, cam, probe_key) -> ProbeMaps:
+    if fns.sampler is not None:
+        return _proposal_maps(fns, acfg, cam)
     counts, cost, opacity, depth = pipeline.probe_phase(
         fns, acfg, cam, probe_key, return_opacity=True, return_depth=True)
     return ProbeMaps(counts, opacity, depth, cost)

@@ -244,9 +244,19 @@ def volume_render(sigmas, anchor_colors, deltas, group: int,
 
 # ------------------------------------------------------------------- FieldFns
 @partial(jax.jit, static_argnums=(1, 2))
-def corner_tables(tables, grid, memory_bytes):
-    """``hashgrid.corner_tables`` as one executable of its own name."""
-    return hashgrid.corner_tables(tables, grid, memory_bytes)
+def corner_tables(tables, grids, memory_bytes):
+    """``hashgrid.field_corner_tables`` as one executable of its own name."""
+    return hashgrid.field_corner_tables(tables, grids, memory_bytes)
+
+
+def field_corner_tables(tables, grids):
+    """The corner rows of a field's grids (``tables[i]`` the stacked
+    tables of ``grids[i]``) under one budget, a quarter of the memory of
+    the device holding ``tables[0]`` (``hashgrid.field_corner_levels``).
+    Waited for here, so a field is ready when built: the derivation never
+    runs on the device inside the caller's first march."""
+    return jax.block_until_ready(corner_tables(
+        tuple(tables), tuple(grids), device_memory_bytes(tables[0])))
 
 
 def device_memory_bytes(x) -> int | None:
@@ -264,17 +274,14 @@ def field_fns(params: Dict, cfg) -> FieldFns:
     not compile for TPU (see hash_encode.py).  The coarsest levels gather
     one corner row a point: as many as ``hashgrid.corner_levels`` fits in
     a quarter of the memory of the device holding ``params["grid"]``
-    (levels 0-9, 2.6 GB, on a 16 GB chip at the paper's config).  Their
-    corner tables are derived here from ``params["grid"]``, once per call,
-    and carried in the ``Bound`` params beside it, so jitted callers
-    (``fields.unbind``) pass them as arguments and ``rebind`` inside a
-    trace only reads them.
+    (levels 0-9, 2.6 GB, on a 16 GB chip at the paper's config), the
+    budget of the field's one grid (a field of several grids shares one
+    budget over them: ``field_corner_tables``).  Its corner tables are
+    derived here from ``params["grid"]``, once per call, and carried in
+    the ``Bound`` params beside it, so jitted callers (``fields.unbind``)
+    pass them as arguments and ``rebind`` inside a trace only reads them.
     """
-    # waited for here, so a field is ready when built: the derivation
-    # never runs on the device inside the caller's first march
-    grid = params["grid"]
-    corners = jax.block_until_ready(
-        corner_tables(grid, cfg.grid, device_memory_bytes(grid)))
+    (corners,) = field_corner_tables((params["grid"],), (cfg.grid,))
     return _bound_field_fns(dict(params, corners=corners), cfg)
 
 

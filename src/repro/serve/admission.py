@@ -33,6 +33,7 @@ import time
 from typing import Dict, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core import scene
@@ -172,7 +173,7 @@ class Prepared:
         m, rays = self.maps, self.layout.rays
         executor_lib.block_until_ready(
             rays[0], rays[1],
-            *((m.counts, m.opacity, m.depth) if m is not None else ()))
+            *((m.counts, m.opacity, m.depth, m.edges) if m is not None else ()))
 
 
 # Engine-thread-only depth counter marking the Stage-B commit section —
@@ -384,6 +385,15 @@ class Slot:
         d_s = dirs[self.order].reshape(-1, B, 3)
         for bi in range(self.budgets.shape[0]):
             yield (self, bi, o_s[bi], d_s[bi], int(self.budgets[bi]))
+
+    def block_edges(self, bi: int, density: bool = False):
+        """Block ``bi``'s interval edges (B, S + 1), its rays in march
+        order, for a field that brings its own sampler; else None."""
+        lay = self.dens_layout if density else self.layout
+        if lay.edges is None:
+            return None
+        B = self.block_size
+        return lay.edges[jnp.asarray(lay.order[bi * B:(bi + 1) * B])]
 
     def emit_density_blocks(self):
         """Density-refresh work items, same shape as ``emit_blocks`` —

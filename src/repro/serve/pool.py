@@ -57,17 +57,19 @@ _MARCH_CACHE_MAX = 32
 _MARCH_CACHE_LOCK = threading.Lock()
 
 
-def _march(fkey, acfg, density_only, params, o_b, d_b, budgets):
+def _march(fkey, acfg, density_only, params, o_b, d_b, budgets, edges=None):
     return pipeline.march_blocks(fields.rebind(fkey, params), acfg, o_b,
-                                 d_b, budgets, density_only=density_only)
+                                 d_b, budgets, density_only=density_only,
+                                 edges=edges)
 
 
 def batched_march(fns, acfg, density_only: bool = False):
     """The jitted (N, B)-block march for a field: ``march(o_b, d_b,
-    budgets)`` — LRU-shared across engine instances AND fleet replica
-    threads (the lock covers only the OrderedDict bookkeeping; jax.jit
-    itself is thread-safe and compilation happens lazily at the first
-    call).
+    budgets)``, or ``march(o_b, d_b, budgets, edges)`` for a field that
+    brings its own sampler (``edges`` (N, B, S + 1)) — LRU-shared across
+    engine instances AND fleet replica threads (the lock covers only the
+    OrderedDict bookkeeping; jax.jit itself is thread-safe and
+    compilation happens lazily at the first call).
 
     Compiles ``pipeline.march_blocks``, the chunked march.
     ``density_only`` marches skip the color MLP entirely (rgb reads zero)
@@ -104,6 +106,26 @@ class BlockLayout:
     march_idx: Optional[np.ndarray] = None
     base_rgb: Optional[np.ndarray] = None
     valid_fraction: float = 0.0
+    # the padded rays' interval edges, for a field that brings its own
+    # sampler (Stage A's ``ProbeMaps.edges``); None otherwise
+    edges: Optional[jnp.ndarray] = None
+
+
+def _pad_edges(rows: int, n_edges: int):
+    """Interval edges of pad rays: ``n_edges`` spread evenly over the
+    spacing space [0, 1], ``rows`` times."""
+    return jnp.broadcast_to(jnp.linspace(0.0, 1.0, n_edges), (rows, n_edges))
+
+
+def _layout_edges(maps, sel, pad: int):
+    """The marched rays' edges (``sel`` of them, None = all) padded like
+    their rays, or None where Stage A made none."""
+    edges = maps.edges
+    if edges is None:
+        return None
+    if sel is not None:
+        edges = edges[sel]
+    return jnp.concatenate([edges, _pad_edges(pad, edges.shape[1])], axis=0)
 
 
 def _scale_counts(counts, budget_scale: float):
@@ -140,9 +162,11 @@ def build_layout(acfg, cam, maps, warped,
         order = np.zeros((0,), np.int64)
         budgets = np.zeros((0,), np.int64)
         pad = 0
+        edges = None
     else:
         o, d = scene.camera_rays(cam)
         counts, opacity = maps.counts, maps.opacity
+        sel = None
         if march_idx is not None:
             sel = jnp.asarray(march_idx, jnp.int32)
             o, d = o[sel], d[sel]
@@ -154,7 +178,9 @@ def build_layout(acfg, cam, maps, warped,
         order_j, budgets_j = pipeline.block_sort(acfg, counts, opacity)
         rays = (o, d)
         order, budgets = np.asarray(order_j), np.asarray(budgets_j)
-    return BlockLayout(rays, order, budgets, pad, march_idx, base_rgb, vf)
+        edges = _layout_edges(maps, sel, pad)
+    return BlockLayout(rays, order, budgets, pad, march_idx, base_rgb, vf,
+                       edges)
 
 
 def build_density_layout(acfg, cam, maps, warped,
@@ -183,7 +209,7 @@ def build_density_layout(acfg, cam, maps, warped,
         acfg, o[sel], d[sel], counts, maps.opacity[sel])
     order_j, budgets_j = pipeline.block_sort(acfg, counts, opacity)
     return BlockLayout((o, d), np.asarray(order_j), np.asarray(budgets_j),
-                       pad, valid_idx)
+                       pad, valid_idx, edges=_layout_edges(maps, sel, pad))
 
 
 class BlockPool:
@@ -392,12 +418,17 @@ class BlockPool:
                                         (B, 1))] * n_pad)
             budgets = jnp.asarray([it[4] for it in batch] + [1] * n_pad,
                                   jnp.int32)
+            args = (o_b, d_b, budgets)
+            edges = [it[0].block_edges(it[1], it[7]) for it in batch]
+            if edges[0] is not None:
+                args += (jnp.stack(
+                    edges + [_pad_edges(B, edges[0].shape[1])] * n_pad),)
             # dispatch only — device arrays are fetched in collect(),
             # after the engine has overlapped Stage-A speculation
             march = march_for(group[0], group[1])
             if self.first_launch_t is None:
                 self.first_launch_t = time.perf_counter()
-            out = march(o_b, d_b, budgets)
+            out = march(*args)
         return batch, followers, n_pad, out, bid
 
     def collect(self, inflight):

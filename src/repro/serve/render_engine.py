@@ -57,6 +57,11 @@ class RenderServingEngine:
     def __init__(self, fields: Dict[str, FieldFns], acfg: ASDRConfig,
                  rcfg: RenderServeConfig = RenderServeConfig(),
                  scenecache: Optional[SceneBlockCache] = None):
+        if rcfg.reuse is not None and any(f.sampler is not None
+                                         for f in fields.values()):
+            raise ValueError("probe reuse warps ASDR's probe maps, and a field "
+                             "that brings its own sampler has none: serve it "
+                             "with RenderServeConfig(reuse=None)")
         self.fields = fields
         self.acfg = acfg
         self.rcfg = rcfg

@@ -19,9 +19,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import kernels
-from repro.configs import tensorf_vm192
+from repro.configs import nerfacto as nerfacto_config, tensorf_vm192
 from repro.configs.ingp_asdr import CONFIG
-from repro.core import hashgrid, model as model_lib, pipeline, scene, tensorf
+from repro.core import hashgrid, mlp as mlp_lib, model as model_lib, nerfacto
+from repro.core import pipeline, scene, tensorf
 from repro.kernels import fused_mlp as FM
 from repro.kernels import hash_encode as HE
 from repro.kernels import ops
@@ -164,6 +165,58 @@ def test_tensorf_march_block_compiles(chip, compiled):
     exe = _compile(march, _tensorf_serving_params(chip), _spec(chip, (B, 3)),
                    _spec(chip, (B, 3)), _spec(chip, (), jnp.int32))
     assert exe.memory_analysis().temp_size_in_bytes < HBM_BYTES // 8
+
+
+def _nerfacto_serving_params(sharding):
+    """The params nerfacto's field binds on one v5e: the three grids'
+    tables and their corner rows under one budget (main levels 0-9, both
+    proposal grids whole), the folded head, the proposal MLPs."""
+    m = nerfacto_config.CONFIG.model
+    grids = (m.grid,) + m.proposal_grids
+
+    def serving():
+        k = jax.random.split(jax.random.PRNGKey(0), 3)
+        tables = [hashgrid.init_hashgrid(kk, g) for kk, g in zip(k, grids)]
+        corners = hashgrid.field_corner_tables(tables, grids, HBM_BYTES)
+        mlps = mlp_lib.init_mlps(k[0], m.net)
+        return {"grid": tables[0], "corners": corners[0], "mlps": mlps,
+                "proposals": [{"grid": t, "corners": c,
+                               "mlp": [jnp.zeros((10, 16)), jnp.zeros((16, 1))]}
+                              for t, c in zip(tables[1:], corners[1:])]}
+    shapes = jax.eval_shape(serving)
+    assert [len(c) for c in [shapes["corners"]] + [p["corners"] for p in shapes["proposals"]]
+            ] == [10, 5, 5]
+    return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), shapes)
+
+
+def test_nerfacto_proposal_sampler_compiles(chip, compiled):
+    """Stage A of nerfacto for a 128x128 frame (256 + 96 proposal samples
+    a ray over 5-level grids, both resamplings) as one executable, in
+    blocks of 4,096 rays: its temporaries stay well under one chip."""
+    m, acfg = nerfacto_config.CONFIG.model, nerfacto_config.CONFIG.asdr
+    cam = scene.look_at_camera(128, 128, 0.6, 0.5)
+    fkey = (nerfacto._bound_field_fns, m)
+    exe = pipeline.proposal_edges.lower(
+        fkey, acfg.block_size, (128, 128, cam.focal), _nerfacto_serving_params(chip),
+        _spec(chip, (3, 3)), _spec(chip, (3,))).compile()
+    assert exe.memory_analysis().temp_size_in_bytes < HBM_BYTES // 8
+
+
+def test_nerfacto_interval_march_block_compiles(chip, compiled):
+    """One 4,096-ray block of nerfacto's march at the proposal sampler's
+    48 intervals, every sample's color on the fused-MLP kernels."""
+    m, acfg = nerfacto_config.CONFIG.model, nerfacto_config.CONFIG.asdr
+    B = acfg.block_size
+
+    def march(params, o, d, budget, edges):
+        return pipeline._march_block(nerfacto._bound_field_fns(params, m), acfg,
+                                     o, d, budget, edges=edges)
+
+    exe = _compile(march, _nerfacto_serving_params(chip), _spec(chip, (B, 3)),
+                   _spec(chip, (B, 3)), _spec(chip, (), jnp.int32),
+                   _spec(chip, (B, m.samples[-1] + 1)))
+    assert "tpu_custom_call" in exe.as_text()
+    assert exe.memory_analysis().temp_size_in_bytes < HBM_BYTES // 4
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError,
