@@ -12,10 +12,11 @@ scaled x1e4, as in ``chip_smoke.py``):
     against ``hashgrid.encode``: seven gather 8 table rows a level, two
     gather one corner row a point at the levels the device's memory
     holds (``hashgrid.corner_tables``, derived outside the timed call);
-  * the corner-row build the served field runs (``ops.corner_tables``),
-    then each level past ``res^3 <= T`` that it covers, one level at a
-    time: its 8-row gather (``encode_level``) against its corner row
-    (``encode_corner_level``), checked equal;
+  * the corner-row and x-neighbour-row build the served field runs
+    (``ops.corner_tables``), then each level past ``res^3 <= T`` that it
+    covers, one level at a time: its 8-row gather (``encode_level``)
+    against its corner row (``encode_corner_level``) or its 4 x-neighbour
+    rows (``encode_xrow_level``), checked equal;
   * the fused-MLP Pallas kernels against XLA's own matmuls (``core.mlp``);
   * the field's density (encode + MLP) in a jit that closes over the
     params, i.e. holds them as constants;
@@ -181,25 +182,32 @@ def encoders(g, T, F):
 
 
 def per_level(tables, g, pts):
-    """Build the served corner rows, then time each level past
-    ``res^3 <= T`` they cover by its 8 table rows and by its corner row."""
+    """Build the served corner and x-neighbour rows, then time each level
+    past ``res^3 <= T`` they cover by its 8 table rows and by its corner
+    row or its 4 x-neighbour rows."""
     memory = ops.device_memory_bytes(tables)
     levels = hashgrid.corner_levels(g, memory)
+    (xlevels,) = hashgrid.field_xrow_levels((g,), memory)
     print(f"corner levels {levels} of memory_bytes={memory}: "
-          f"{sum(hashgrid.corner_bytes(g, l) for l in levels)} B", flush=True)
-    (corners,) = timeit("corner_tables build", ops.corner_tables, (tables,), (g,),
-                        memory, n=3)
-    for l in levels:
+          f"{sum(hashgrid.corner_bytes(g, l) for l in levels)} B; x-neighbour "
+          f"levels {xlevels}: {sum(hashgrid.xrow_bytes(g, l) for l in xlevels)} B",
+          flush=True)
+    (corners,), (xrows,) = timeit("corner_tables build", ops.corner_tables,
+                                  (tables,), (g,), memory, n=3)
+    served = [(l, "corner row", hashgrid.encode_corner_level, c)
+              for l, c in zip(levels, corners)]
+    served += [(l, "4 x-neighbour rows", hashgrid.encode_xrow_level, x)
+               for l, x in zip(xlevels, xrows)]
+    for l, name, encode, arg in served:
         res = g.level_resolution(l)
         if res ** 3 <= g.table_size:
             continue
         rows = timeit(f"level {l} res={res} 8 rows", jax.jit(
             lambda p, t: hashgrid.encode_level(p, t, res, g.level_is_dense(l))),
             pts, tables[l])
-        row = timeit(f"level {l} res={res} corner row", jax.jit(
-            lambda p, c: hashgrid.encode_corner_level(p, c, res)),
-            pts, corners[l])
-        print(f"  max_abs={float(jnp.max(jnp.abs(rows - row))):.3e}", flush=True)
+        got = timeit(f"level {l} res={res} {name}", jax.jit(
+            lambda p, a: encode(p, a, res)), pts, arg)
+        print(f"  max_abs={float(jnp.max(jnp.abs(rows - got))):.3e}", flush=True)
 
 
 def main(argv=None) -> int:
@@ -207,7 +215,7 @@ def main(argv=None) -> int:
     ap.add_argument("--small", action="store_true",
                     help="SMOKE config, 48x48 frame (a rehearsal)")
     ap.add_argument("--levels", action="store_true",
-                    help="only the corner-row build and the per-level gathers")
+                    help="only the row build and the per-level gathers")
     args = ap.parse_args(argv)
     dev = jax.devices()[0]
     if not args.small and dev.platform != "tpu":

@@ -27,8 +27,26 @@ two proposal grids) shares one such budget (``field_corner_levels``):
 levels of all its grids in order of their rows' size.  The rows are
 built z-slab by z-slab from each slab's vertex features (one index a
 vertex), so a level's build holds a small fraction of its rows in
-temporaries.  Training keeps the stacked tables alone: it updates them
-every step and takes gradients into them.
+temporaries.
+
+The hashed levels corner rows do not reach are read from *x-neighbour
+rows* (``xrow_table``) where they fit beside them.  With ``PRIMES[0] ==
+1`` and ``T`` a power of two, the hash of a vertex's x-neighbour is an
+XOR: ``h(x+1, y, z) = h(x, y, z) ^ ((2^(t+1) - 1) & (T-1))``, ``t`` the
+number of trailing one bits of ``x``.  So row ``h`` holds ``T[h]``, then
+``T[h ^ (2^(t+1) - 1)]`` for ``t = 0 .. floor(log2(res))``, and one
+gathered row serves both corners on a voxel's x-edge: a point gathers 4
+rows a level there (one a (dy, dz)), not 8, through ``level_indices``
+itself, so collisions are kept and the encoding is the same to the bit.
+The rows stay indexed by the hash slot: ``T * (k+2) * F * 4`` bytes a
+level, 42-55 MB each for levels 10-15 at the paper's config (289 MB), far
+below their corner rows (4.28 GB at level 10).  After the corner levels
+are chosen, a field's next hashed levels take them in order of their
+size while both kinds of rows fit in the same quarter of the device's
+memory (``field_xrow_levels``); where the device reports no memory size
+none do.  On a 16 GB chip at the paper's config a point then gathers
+10 + 6 x 4 = 34 indices, not 58.  Training keeps the stacked tables
+alone: it updates them every step and takes gradients into them.
 
 All functions are pure; parameters are plain pytrees.
 """
@@ -272,20 +290,116 @@ def encode_corner_level(points: jnp.ndarray, corners: jnp.ndarray, res: int) -> 
     return _blend(feats, frac, points.dtype)
 
 
+def xrow_width(res: int) -> int:
+    """Entries of an x-neighbour row at resolution ``res``: the vertex's
+    own, then one for each trailing-ones count ``t = 0 .. k`` an ``x`` in
+    ``[0, res-1]`` can have, ``k = floor(log2(res))``."""
+    return res.bit_length() + 1
+
+
+def xrow_bytes(cfg: HashGridConfig, level: int) -> int:
+    """Bytes of one level's x-neighbour rows (T, xrow_width*F) in float32."""
+    return cfg.table_size * xrow_width(cfg.level_resolution(level)) * cfg.feature_dim * 4
+
+
+def field_xrow_levels(grids: Tuple[HashGridConfig, ...],
+                      memory_bytes: int | None = None) -> Tuple[Tuple[int, ...], ...]:
+    """The levels each of a field's grids serves from x-neighbour rows:
+    with ``memory_bytes``, the hashed levels right after its corner levels
+    (``field_corner_levels``), taken over all the grids in order of their
+    rows' size while corner rows and x-neighbour rows together fit in a
+    quarter of it.  Without, none.  A grid whose first level past its
+    corner levels is dense takes none: the levels a grid serves from rows
+    are always its first few, corner rows then x-neighbour rows."""
+    corners = field_corner_levels(grids, memory_bytes)
+    taken = [[] for _ in grids]
+    if memory_bytes is None:
+        return tuple(tuple(t) for t in taken)
+    used = sum(corner_bytes(g, l) for g, c in zip(grids, corners) for l in c)
+    for size, i, l in sorted((xrow_bytes(g, l), i, l)
+                             for i, (g, c) in enumerate(zip(grids, corners))
+                             if len(c) < g.n_levels and not g.level_is_dense(len(c))
+                             for l in range(len(c), g.n_levels)):
+        used += size
+        if used > memory_bytes // 4:
+            break
+        taken[i].append(l)
+    return tuple(tuple(t) for t in taken)
+
+
+def xrow_table(table: jnp.ndarray, res: int) -> jnp.ndarray:
+    """One hashed level's x-neighbour rows (T, xrow_width(res)*F) from its
+    table (T, F): row ``h`` holds ``T[h]``, then ``T[h ^ m_t]`` for
+    ``t = 0 .. k``, ``m_t = (2^(t+1) - 1) & (T-1)``.  One one-index
+    gather of T rows an entry: the TPU compiler takes ~1 s for these at
+    level 15, ~280 s for the same entries as block reversals, and one
+    gather of all the entries' indices holds ~70x the rows in
+    temporaries."""
+    T = table.shape[0]
+    h = jnp.arange(T, dtype=jnp.int32)
+    return jnp.concatenate([table] + [table[h ^ (((2 << t) - 1) & (T - 1))]
+                                      for t in range(xrow_width(res) - 1)], axis=1)
+
+
+def field_xrow_tables(tables, grids: Tuple[HashGridConfig, ...],
+                      memory_bytes: int | None = None):
+    """The x-neighbour rows of each of a field's grids (stacked tables
+    ``tables[i]`` of ``grids[i]``) at its ``field_xrow_levels``."""
+    return tuple(tuple(xrow_table(t[l], g.level_resolution(l)) for l in levels)
+                 for t, g, levels in zip(tables, grids,
+                                         field_xrow_levels(grids, memory_bytes)))
+
+
+def encode_xrow_level(points: jnp.ndarray, xrows: jnp.ndarray, res: int) -> jnp.ndarray:
+    """``encode_level`` of a hashed level from its x-neighbour rows
+    (``xrow_table``): one gathered row for each (dy, dz) edge, at the
+    hash of its x-lower vertex; the x-upper vertex is entry ``1 + t``,
+    ``t`` the trailing ones of the voxel's x.  The same features and the
+    same blend."""
+    N = points.shape[0]
+    W = xrow_width(res)
+    base, frac = _voxel(points, res)
+    x = base[:, 0].astype(jnp.uint32)
+    t = lax.population_count(x ^ (x + 1)).astype(jnp.int32) - 1
+    lo, hi = [], []
+    for dy, dz in ((0, 0), (0, 1), (1, 0), (1, 1)):
+        idx = level_indices(base + jnp.asarray([0, dy, dz], jnp.int32), res, False,
+                            xrows.shape[0])
+        row = xrows[idx].reshape(N, W, -1)
+        up = row[:, 1]
+        for j in range(1, W - 1):
+            up = jnp.where((t == j)[:, None], row[:, 1 + j], up)
+        lo.append(row[:, 0])
+        hi.append(up)
+    return _blend(jnp.stack(lo + hi, axis=1), frac, points.dtype)  # CORNERS order
+
+
+def rows_per_point(cfg: HashGridConfig, corners=(), xrows=()) -> int:
+    """Gather indices a point takes in ``encode`` with these rows: one a
+    corner-row level, 4 an x-neighbour-row level, 8 any other."""
+    n, m = len(corners), len(xrows)
+    return n + 4 * m + 8 * (cfg.n_levels - n - m)
+
+
 def encode(points: jnp.ndarray, tables: jnp.ndarray, cfg: HashGridConfig,
-           corners: Tuple[jnp.ndarray, ...] = ()) -> jnp.ndarray:
+           corners: Tuple[jnp.ndarray, ...] = (),
+           xrows: Tuple[jnp.ndarray, ...] = ()) -> jnp.ndarray:
     """Full multi-resolution encoding: (N, 3) -> (N, n_levels * feature_dim).
 
     With ``corners`` (``corner_tables``: rows of the first
     ``len(corners)`` levels) those levels gather one corner row a point
-    instead of 8 table rows; the result is the same.  Its ops carry the
-    ``hash_encode`` scope in the profiler's metadata."""
+    instead of 8 table rows; with ``xrows`` (``field_xrow_tables``: rows
+    of the next ``len(xrows)`` levels) those gather 4 x-neighbour rows.
+    The result is the same.  Its ops carry the ``hash_encode`` scope in
+    the profiler's metadata."""
     with jax.named_scope("hash_encode"):
         outs = []
         for l in range(cfg.n_levels):
             res = cfg.level_resolution(l)
             if l < len(corners):
                 outs.append(encode_corner_level(points, corners[l], res))
+            elif l < len(corners) + len(xrows):
+                outs.append(encode_xrow_level(points, xrows[l - len(corners)], res))
             else:
                 outs.append(encode_level(points, tables[l], res, cfg.level_is_dense(l)))
         return jnp.concatenate(outs, axis=-1)

@@ -51,11 +51,14 @@ class NGPConfig:
     def gather_stats(self, params) -> Dict[str, int]:
         """Hash-table rows gathered a point: one at each of the
         ``corner_levels`` levels whose corner rows ``params`` carries
-        (``params["corners"]``, the kernel-backed field), 8 at every other
-        level; the color side reads no table."""
-        n = len(params.get("corners", ()))
-        return {"encode_gather_rows_per_sample": n + 8 * (self.grid.n_levels - n),
-                "corner_levels": n, "color_gather_rows_per_sample": 0}
+        (``params["corners"]``, the kernel-backed field), 4 at each of the
+        ``xrow_levels`` levels whose x-neighbour rows it carries
+        (``params["xrows"]``), 8 at every other level; the color side
+        reads no table."""
+        c, x = params.get("corners", ()), params.get("xrows", ())
+        return {"encode_gather_rows_per_sample": hashgrid.rows_per_point(self.grid, c, x),
+                "corner_levels": len(c), "xrow_levels": len(x),
+                "color_gather_rows_per_sample": 0}
 
 
 def init_ngp(key: jax.Array, cfg: NGPConfig) -> Dict:

@@ -33,7 +33,8 @@ runs on ``ops.color_mlp`` at Instant-NGP's 31 inputs.  The field returns
 sigma per program unit (``sigma_w / WORLD_SCALE``), so the march's
 ``1 - exp(-sigma * dt)`` over program widths is nerfstudio's alpha.
 
-The three grids' corner rows share one budget (``ops.field_corner_tables``).
+The three grids' corner and x-neighbour rows share one budget
+(``ops.field_corner_tables``).
 """
 from __future__ import annotations
 
@@ -77,16 +78,17 @@ class NerfactoConfig:
 
     def gather_stats(self, params) -> Dict[str, int]:
         """Hash-table rows gathered: a marched point's (one a corner-row
-        level, 8 at every other level of the main grid), and a ray's over
-        both proposal fields."""
-        def rows(grid, corners):
-            return len(corners) + 8 * (grid.n_levels - len(corners))
-        n = len(params["corners"])
-        return {"encode_gather_rows_per_sample": rows(self.grid, params["corners"]),
-                "corner_levels": n, "color_gather_rows_per_sample": 0,
+        level, 4 an x-neighbour-row level, 8 at every other level of the
+        main grid), and a ray's over both proposal fields."""
+        return {"encode_gather_rows_per_sample": hashgrid.rows_per_point(
+                    self.grid, params["corners"], params["xrows"]),
+                "corner_levels": len(params["corners"]),
+                "xrow_levels": len(params["xrows"]),
+                "color_gather_rows_per_sample": 0,
                 "proposal_samples_per_ray": self.samples_per_ray,
                 "proposal_gather_rows_per_ray": sum(
-                    s * rows(g, p["corners"]) for s, g, p in
+                    s * hashgrid.rows_per_point(g, p["corners"], p["xrows"])
+                    for s, g, p in
                     zip(self.samples, self.proposal_grids, params["proposals"]))}
 
 
@@ -146,7 +148,7 @@ def pdf_edges(s, w, n: int):
 def _proposal_sigma(p, grid: hashgrid.HashGridConfig, x_w):
     """``HashMLPDensityField``'s density at world points (N, 3)."""
     pos, inside = grid_points(x_w)
-    enc = hashgrid.encode(pos, p["grid"], grid, p["corners"])
+    enc = hashgrid.encode(pos, p["grid"], grid, p["corners"], p["xrows"])
     h = jax.nn.relu(jnp.dot(enc, p["mlp"][0], precision=HIGHEST))
     h = jnp.dot(h, p["mlp"][1], precision=HIGHEST)[:, 0]
     return jnp.where(inside, jnp.exp(h), 0.0)
@@ -166,28 +168,30 @@ def fold_appearance(mlps: Dict, appearance, net: mlp.MLPConfig) -> Dict:
 def field_fns(params: Dict, cfg: NerfactoConfig) -> FieldFns:
     """The field over ``params`` (``{"grid", "mlps": {"density", "color"},
     "appearance" (images, 32), "proposals": [{"grid", "mlp"}, ...]}``).
-    The corner rows of all three grids are derived here under one budget
-    and carried, with the folded head, in the ``Bound`` params, so jitted
-    callers pass them as arguments."""
+    The corner and x-neighbour rows of all three grids are derived here
+    under one budget and carried, with the folded head, in the ``Bound``
+    params, so jitted callers pass them as arguments."""
     props = params["proposals"]
-    corners = ops.field_corner_tables(
+    corners, xrows = ops.field_corner_tables(
         (params["grid"],) + tuple(p["grid"] for p in props),
         (cfg.grid,) + cfg.proposal_grids)
-    bound = {"grid": params["grid"], "corners": corners[0],
+    bound = {"grid": params["grid"], "corners": corners[0], "xrows": xrows[0],
              "mlps": fold_appearance(params["mlps"], params["appearance"], cfg.net),
-             "proposals": [dict(p, corners=c) for p, c in zip(props, corners[1:])]}
+             "proposals": [dict(p, corners=c, xrows=x)
+                           for p, c, x in zip(props, corners[1:], xrows[1:])]}
     return _bound_field_fns(bound, cfg)
 
 
 def _bound_field_fns(params: Dict, cfg: NerfactoConfig) -> FieldFns:
-    """``field_fns`` over params that carry their corner rows and the
-    folded head."""
+    """``field_fns`` over params that carry their corner and x-neighbour
+    rows and the folded head."""
     center = jnp.asarray(CENTER, jnp.float32)
 
     def density(points):
         with jax.named_scope("nerfacto_density"):
             pos, inside = grid_points((points - center) / WORLD_SCALE)
-            enc = hashgrid.encode(pos, params["grid"], cfg.grid, params["corners"])
+            enc = hashgrid.encode(pos, params["grid"], cfg.grid, params["corners"],
+                                  params["xrows"])
             sigma, geo = ops.density_mlp(enc, params["mlps"], cfg.net)
             return jnp.where(inside, sigma / WORLD_SCALE, 0.0), geo
 

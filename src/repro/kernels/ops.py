@@ -245,16 +245,20 @@ def volume_render(sigmas, anchor_colors, deltas, group: int,
 # ------------------------------------------------------------------- FieldFns
 @partial(jax.jit, static_argnums=(1, 2))
 def corner_tables(tables, grids, memory_bytes):
-    """``hashgrid.field_corner_tables`` as one executable of its own name."""
-    return hashgrid.field_corner_tables(tables, grids, memory_bytes)
+    """``hashgrid.field_corner_tables`` and ``field_xrow_tables`` as one
+    executable of its own name."""
+    return (hashgrid.field_corner_tables(tables, grids, memory_bytes),
+            hashgrid.field_xrow_tables(tables, grids, memory_bytes))
 
 
 def field_corner_tables(tables, grids):
-    """The corner rows of a field's grids (``tables[i]`` the stacked
-    tables of ``grids[i]``) under one budget, a quarter of the memory of
-    the device holding ``tables[0]`` (``hashgrid.field_corner_levels``).
-    Waited for here, so a field is ready when built: the derivation never
-    runs on the device inside the caller's first march."""
+    """The corner rows and x-neighbour rows of a field's grids
+    (``tables[i]`` the stacked tables of ``grids[i]``) under one budget, a
+    quarter of the memory of the device holding ``tables[0]``
+    (``hashgrid.field_corner_levels``, ``field_xrow_levels``): a pair of
+    tuples, each with one entry a grid.  Waited for here, so a field is
+    ready when built: the derivation never runs on the device inside the
+    caller's first march."""
     return jax.block_until_ready(corner_tables(
         tuple(tables), tuple(grids), device_memory_bytes(tables[0])))
 
@@ -276,21 +280,25 @@ def field_fns(params: Dict, cfg) -> FieldFns:
     a quarter of the memory of the device holding ``params["grid"]``
     (levels 0-9, 2.6 GB, on a 16 GB chip at the paper's config), the
     budget of the field's one grid (a field of several grids shares one
-    budget over them: ``field_corner_tables``).  Its corner tables are
-    derived here from ``params["grid"]``, once per call, and carried in
-    the ``Bound`` params beside it, so jitted callers (``fields.unbind``)
-    pass them as arguments and ``rebind`` inside a trace only reads them.
+    budget over them: ``field_corner_tables``).  The hashed levels past
+    them gather 4 x-neighbour rows a point where those fit in the same
+    budget (levels 10-15, 289 MB, there).  Its corner and x-neighbour
+    tables are derived here from ``params["grid"]``, once per call, and
+    carried in the ``Bound`` params beside it, so jitted callers
+    (``fields.unbind``) pass them as arguments and ``rebind`` inside a
+    trace only reads them.
     """
-    (corners,) = field_corner_tables((params["grid"],), (cfg.grid,))
-    return _bound_field_fns(dict(params, corners=corners), cfg)
+    (corners,), (xrows,) = field_corner_tables((params["grid"],), (cfg.grid,))
+    return _bound_field_fns(dict(params, corners=corners, xrows=xrows), cfg)
 
 
 def _bound_field_fns(params: Dict, cfg) -> FieldFns:
-    """``field_fns`` over params that already carry their corner tables."""
+    """``field_fns`` over params that already carry their corner and
+    x-neighbour tables."""
 
     def density(points):
         enc = hashgrid.encode(points, params["grid"], cfg.grid,
-                              params["corners"])
+                              params["corners"], params["xrows"])
         sigma, geo = density_mlp(enc, params["mlps"], cfg.net)
         inside = jnp.all((points >= 0.0) & (points <= 1.0), axis=-1)
         return jnp.where(inside, sigma, 0.0), geo

@@ -180,3 +180,119 @@ def test_slab_built_corner_rows_equal_corner_table(res, dense, planes):
     np.testing.assert_array_equal(
         np.asarray(build(table, res, dense, planes)),
         np.asarray(hg.corner_table(table, res, dense)))
+
+
+def _xrow_case(case):
+    """(grid, points) for the x-neighbour encode's cases; every level of
+    each grid is hashed."""
+    pts = jax.random.uniform(jax.random.PRNGKey(5), (513, 3))
+    if case == "hashed_collisions":
+        # res 16, 25, 40, 63 at T = 2^12: (res+1)^3 > T, so slots collide
+        return hg.HashGridConfig(n_levels=4, log2_table_size=12,
+                                 max_resolution=63), pts
+    if case == "every_trailing_ones_count":
+        # res 32 and 64 = 2^k: x = res - 1 has k trailing ones, and every
+        # x in [0, res - 1] is a point's voxel
+        xs = (jnp.arange(64) + 0.5) / 64
+        grid = jnp.stack([xs, jnp.roll(xs, 7), jnp.roll(xs, 29)], -1)
+        return hg.HashGridConfig(n_levels=2, log2_table_size=12,
+                                 base_resolution=32, max_resolution=64), \
+            jnp.concatenate([grid, pts])
+    if case == "masks_past_the_table":
+        # T = 2^6 below 2^(k+1) = 2^7 at res 64: the neighbour's XOR mask
+        # wraps to T - 1
+        return hg.HashGridConfig(n_levels=1, log2_table_size=6,
+                                 base_resolution=64, max_resolution=64), pts
+    # every corner, edge midpoint and face centre of the unit cube
+    g = jnp.asarray([0.0, 0.5, 1.0])
+    cube = jnp.stack(jnp.meshgrid(g, g, g, indexing="ij"), -1).reshape(-1, 3)
+    return hg.HashGridConfig(n_levels=4, log2_table_size=12,
+                             max_resolution=63), jnp.concatenate([cube, pts])
+
+
+@pytest.mark.parametrize("case", ["hashed_collisions", "every_trailing_ones_count",
+                                  "masks_past_the_table", "unit_cube_points"])
+def test_xrow_encode_is_bit_identical(case):
+    """A hashed level read from x-neighbour rows (4 gathered rows a point,
+    the x-upper corner picked by the trailing ones of x) encodes to the
+    bit what its 8 table rows do, collisions included; so does ``encode``
+    with every level from them."""
+    cfg, pts = _xrow_case(case)
+    tables = hg.init_hashgrid(jax.random.PRNGKey(6), cfg)
+    xrows = []
+    for l in range(cfg.n_levels):
+        res = cfg.level_resolution(l)
+        assert not cfg.level_is_dense(l)
+        xrows.append(hg.xrow_table(tables[l], res))
+        assert xrows[-1].shape == (cfg.table_size, (res.bit_length() + 1) * cfg.feature_dim)
+        np.testing.assert_array_equal(
+            np.asarray(hg.encode_xrow_level(pts, xrows[-1], res)),
+            np.asarray(hg.encode_level(pts, tables[l], res, False)))
+    if case == "hashed_collisions":
+        res = cfg.level_resolution(0)
+        verts = jnp.stack(jnp.meshgrid(*[jnp.arange(res + 1)] * 3,
+                                       indexing="ij"), -1).reshape(-1, 3)
+        rows = hg.level_indices(verts, res, False, cfg.table_size)
+        assert len(np.unique(np.asarray(rows))) < (res + 1) ** 3
+    if case == "every_trailing_ones_count":
+        for res in cfg.level_resolutions():
+            x = np.asarray(hg._voxel(pts, res)[0][:, 0])
+            ones = {bin(v ^ (v + 1)).count("1") - 1 for v in x.tolist()}
+            assert ones == set(range(int(np.log2(res)) + 1))
+    np.testing.assert_array_equal(
+        np.asarray(hg.encode(pts, tables, cfg, xrows=tuple(xrows))),
+        np.asarray(hg.encode(pts, tables, cfg)))
+
+
+@pytest.mark.parametrize("res,log2_t", [(16, 12), (63, 12), (64, 6), (2048, 19)])
+def test_xrow_table_equals_its_definition(res, log2_t):
+    """Row h of the x-neighbour rows holds T[h], then T[h ^ m_t] for
+    t = 0 .. floor(log2(res)), m_t = (2^(t+1) - 1) & (T - 1): to the bit."""
+    T = 1 << log2_t
+    table = jax.random.normal(jax.random.PRNGKey(7), (T, 2))
+    got = np.asarray(jax.jit(hg.xrow_table, static_argnums=1)(table, res))
+    k = int(np.floor(np.log2(res)))
+    assert got.shape == (T, 2 * (k + 2))
+    h = np.arange(T)
+    want = [np.asarray(table)] + [np.asarray(table)[h ^ (((2 << t) - 1) & (T - 1))]
+                                  for t in range(k + 1)]
+    np.testing.assert_array_equal(got, np.concatenate(want, axis=1))
+
+
+@pytest.mark.parametrize("case", ["ingp_16gib", "nerfacto_v5e", "no_memory", "zero_memory"])
+def test_xrow_levels_fill_the_quarter_past_the_corner_rows(case):
+    """After the corner levels, a field's next hashed levels take
+    x-neighbour rows while both kinds fit a quarter of the device: on a
+    16 GiB chip the paper's grid serves 0-9 from corner rows and 10-15
+    from x-neighbour rows (289 MB), 34 indices a point; nerfacto's three
+    grids on a v5e's bytes_limit leave at least main levels 10-13; with
+    no memory size, or none, no level takes them."""
+    from repro.configs.ingp_asdr import CONFIG
+    from repro.configs import nerfacto as nerfacto_config
+    g = CONFIG.model.grid
+    if case == "nerfacto_v5e":
+        m = nerfacto_config.CONFIG.model
+        grids, memory = (m.grid,) + m.proposal_grids, 16909336064
+    else:
+        grids = (g,)
+        memory = {"ingp_16gib": 16 << 30, "no_memory": None, "zero_memory": 0}[case]
+    corners = hg.field_corner_levels(grids, memory)
+    xrows = hg.field_xrow_levels(grids, memory)
+    used = sum(hg.corner_bytes(gg, l) for gg, c in zip(grids, corners) for l in c) + sum(
+        hg.xrow_bytes(gg, l) for gg, x in zip(grids, xrows) for l in x)
+    if case == "ingp_16gib":
+        assert corners == (tuple(range(10)),) and xrows == (tuple(range(10, 16)),)
+        assert sum(hg.xrow_bytes(g, l) for l in xrows[0]) == 289_406_976
+        assert used <= memory // 4
+        assert hg.rows_per_point(g, corners[0], xrows[0]) == 10 + 6 * 4 == 34
+    elif case == "nerfacto_v5e":
+        assert corners == (tuple(range(10)), tuple(range(5)), tuple(range(5)))
+        assert xrows[0][:4] == (10, 11, 12, 13) and xrows[1:] == ((), ())
+        assert used <= memory // 4
+        nxt = len(corners[0]) + len(xrows[0])
+        if nxt < g.n_levels:
+            assert used + hg.xrow_bytes(g, nxt) > memory // 4
+    else:
+        assert xrows == ((),)
+        assert hg.rows_per_point(g, corners[0], xrows[0]) == len(corners[0]) + 8 * (
+            16 - len(corners[0]))

@@ -1,8 +1,8 @@
 """nerfacto (core/nerfacto.py) at SMOKE size on seeded random weights:
 its contraction, sampler, field and frames against the plain reference
 (bench/reference/nerfacto.py), the interval march against a one-pass
-render at the same intervals, the per-field corner-row budget, and a
-tiny benchmark cell judged correct by the harness."""
+render at the same intervals, the per-field corner-row and x-neighbour
+row budget, and a tiny benchmark cell judged correct by the harness."""
 import json
 import shutil
 import sys
@@ -163,6 +163,27 @@ def test_field_matches_reference(field, params):
     assert np.asarray(want_rgb).std() > 0.02
 
 
+def test_field_from_xrow_levels_equals_field_without(field, params, monkeypatch):
+    """On a device whose memory leaves main levels 4-7 to x-neighbour
+    rows (4 gathered rows a point there, not 8), the field's density and
+    the proposal sampler's edges are the same to the bit, and
+    gather_stats counts the rows."""
+    monkeypatch.setattr(ops, "device_memory_bytes", lambda x: 240 * 10 ** 6)
+    xfield = nerfacto.field_fns(params, SMOKE.model)
+    bound = xfield.bound.params
+    assert (len(bound["corners"]), len(bound["xrows"])) == (4, 4)
+    st = fields.gather_stats(xfield)
+    assert (st["xrow_levels"], st["encode_gather_rows_per_sample"]) == (4, 4 + 4 * 4)
+    assert fields.gather_stats(field)["xrow_levels"] == 0
+    x_w = jax.random.normal(jax.random.PRNGKey(12), (1024, 3)) * 2.0
+    pts = x_w * nerfacto.WORLD_SCALE + jnp.asarray(nerfacto.CENTER)
+    for got, want in zip(xfield.density(pts), field.density(pts)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    o, d = _rays(128)
+    np.testing.assert_array_equal(np.asarray(xfield.sampler.edges(o, d)),
+                                  np.asarray(field.sampler.edges(o, d)))
+
+
 def test_interval_march_matches_one_pass_render(field):
     """The pooled march at the sampler's intervals (chunks of 8 over 12
     samples, the last one cut short) equals ``pipeline.render_at`` at the
@@ -263,9 +284,11 @@ def test_corner_budget_is_shared_over_the_field_grids(monkeypatch):
     fns = nerfacto.field_fns(p, SMOKE.model)
     (tables, g, memory), = seen
     assert g == (SMOKE.model.grid,) + SMOKE.model.proposal_grids and memory == 240 * 10 ** 6
-    got = [len(c) for c in [fns.bound.params["corners"]]
-           + [q["corners"] for q in fns.bound.params["proposals"]]]
-    assert tuple(got) == tuple(map(len, hashgrid.field_corner_levels(g, memory)))
+    for key, levels in (("corners", hashgrid.field_corner_levels(g, memory)),
+                        ("xrows", hashgrid.field_xrow_levels(g, memory))):
+        got = [len(c) for c in [fns.bound.params[key]]
+               + [q[key] for q in fns.bound.params["proposals"]]]
+        assert tuple(got) == tuple(map(len, levels))
 
 
 def test_proposal_executable_takes_tables_as_arguments(field):

@@ -338,6 +338,7 @@ def test_kernel_field_carries_corner_tables(smoke_field, monkeypatch):
     assert len(corners) == len(hashgrid.corner_levels(cfg.grid)) == 2
     for got, ref in zip(corners, want):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    assert fns.bound.params["xrows"] == ()      # no memory size, no x-rows
 
     calls = []
     real = ops.corner_tables
@@ -374,8 +375,10 @@ def test_engine_frame_over_corner_rows_matches_model_field(smoke_field):
     np.testing.assert_allclose(out["kernel"][0], out["model"][0],
                                rtol=1e-4, atol=1e-5)
     assert out["kernel"][1]["corner_levels"] == 2
+    assert out["kernel"][1]["xrow_levels"] == 0
     assert out["kernel"][1]["encode_gather_rows_per_sample"] == 2 + 8 * 6
     assert out["model"][1]["corner_levels"] == 0
+    assert out["model"][1]["xrow_levels"] == 0
     assert out["model"][1]["encode_gather_rows_per_sample"] == 8 * 8
 
 
@@ -383,7 +386,8 @@ def test_field_on_a_device_with_memory_serves_levels_past_the_table(
         smoke_field, monkeypatch):
     """Where the device reports its memory, ``ops.field_fns`` serves more
     levels from corner rows than ``res^3 <= T`` allows: SMOKE's levels
-    2-3 (res 35 and 52, 2^14 rows) among them.  A frame over it matches
+    2-3 (res 35 and 52, 2^14 rows) among them; the hashed levels past
+    them (4-7) from x-neighbour rows.  A frame over it matches
     ``model.field_fns``, engine_stats() reports the levels, and neither a
     jitted rebind nor the pooled march builds the tables again."""
     import jax
@@ -393,7 +397,8 @@ def test_field_on_a_device_with_memory_serves_levels_past_the_table(
     from repro.serve import pool
     cfg, params = smoke_field
     g = cfg.grid
-    # a quarter of it holds levels 0-3's rows (12.8 MB), not level 4's
+    # a quarter of it holds levels 0-3's corner rows (12.8 MB), not level
+    # 4's, and beside them levels 4-7's x-neighbour rows (4.6 MB)
     monkeypatch.setattr(ops, "device_memory_bytes", lambda x: 80 * 10 ** 6)
     fns = ops.field_fns(params, cfg)
     corners = fns.bound.params["corners"]
@@ -403,6 +408,11 @@ def test_field_on_a_device_with_memory_serves_levels_past_the_table(
         np.testing.assert_array_equal(np.asarray(c), np.asarray(
             hashgrid.corner_table(params["grid"][l], g.level_resolution(l),
                                   g.level_is_dense(l))))
+    xrows = fns.bound.params["xrows"]
+    assert len(xrows) == 4
+    for l, x in enumerate(xrows, start=4):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(
+            hashgrid.xrow_table(params["grid"][l], g.level_resolution(l))))
 
     cam = scene.look_at_camera(16, 16, theta=0.7, phi=0.5)
     out = {}
@@ -416,7 +426,8 @@ def test_field_on_a_device_with_memory_serves_levels_past_the_table(
     np.testing.assert_allclose(out["kernel"][0], out["model"][0],
                                rtol=1e-4, atol=1e-5)
     assert out["kernel"][1]["corner_levels"] == 4
-    assert out["kernel"][1]["encode_gather_rows_per_sample"] == 4 + 8 * 4
+    assert out["kernel"][1]["xrow_levels"] == 4
+    assert out["kernel"][1]["encode_gather_rows_per_sample"] == 4 + 4 * 4
 
     calls = []
     real = ops.corner_tables

@@ -28,6 +28,7 @@ from repro.kernels import hash_encode as HE
 from repro.kernels import ops
 
 HBM_BYTES = 16 * 10 ** 9                  # one v5e chip
+BYTES_LIMIT = 16909336064                 # its bytes_limit, as JAX reports it
 ROWS = CONFIG.asdr.block_size * CONFIG.asdr.chunk   # samples per march step
 
 
@@ -66,14 +67,15 @@ def _params(sharding):
 
 def _serving_params(sharding):
     """The params the serving field binds on one v5e: the stacked tables,
-    the MLPs and the ten corner tables (levels 0-9) ``ops.field_fns``
-    derives from the tables for a chip of ``HBM_BYTES``."""
+    the MLPs, the ten corner tables (levels 0-9) and the six x-neighbour
+    tables (levels 10-15) ``ops.field_fns`` derives from the tables for a
+    chip of ``HBM_BYTES``."""
     params = _params(sharding)
-    corners = jax.eval_shape(lambda t: hashgrid.corner_tables(
-        t, CONFIG.model.grid, HBM_BYTES), params["grid"])
-    assert len(corners) == 10
-    return dict(params, corners=jax.tree.map(
-        lambda s: _spec(sharding, s.shape, s.dtype), corners))
+    (corners,), (xrows,) = jax.eval_shape(lambda t: ops.corner_tables(
+        (t,), (CONFIG.model.grid,), HBM_BYTES), params["grid"])
+    assert (len(corners), len(xrows)) == (10, 6)
+    return dict(params, **jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype),
+                                       {"corners": corners, "xrows": xrows}))
 
 
 def _compile(fn, *args):
@@ -96,7 +98,8 @@ def test_fused_mlp_kernels_compile_at_paper_widths(chip, compiled, kernel):
 
 def test_reference_march_block_compiles(chip, compiled):
     """One 4096-ray block of the engine's march: XLA-gather encode (corner
-    rows at levels 0-9) feeding the compiled fused-MLP kernels."""
+    rows at levels 0-9, x-neighbour rows at 10-15) feeding the compiled
+    fused-MLP kernels."""
     cfg, acfg = CONFIG.model, CONFIG.asdr
     B = acfg.block_size
 
@@ -139,6 +142,19 @@ def test_level9_corner_rows_build_in_slabs(chip, compiled):
     assert mem.temp_size_in_bytes < mem.output_size_in_bytes // 4, mem
 
 
+def test_level15_xrow_rows_build_in_one_index_gathers(chip, compiled):
+    """Level 15's x-neighbour rows (2^19 slots, 13 entries of 2 floats,
+    54.5 MB) build from one one-index gather an entry: the compile takes
+    seconds, and its temporaries stay under the rows it writes."""
+    g = CONFIG.model.grid
+    res = g.level_resolution(15)
+    exe = _compile(partial(hashgrid.xrow_table, res=res),
+                   _spec(chip, (g.table_size, g.feature_dim)))
+    mem = exe.memory_analysis()
+    assert mem.output_size_in_bytes >= hashgrid.xrow_bytes(g, 15) == 54_525_952
+    assert mem.temp_size_in_bytes < mem.output_size_in_bytes, mem
+
+
 def _tensorf_serving_params(sharding):
     """The params the serving field binds: the packed tables
     ``tensorf.field_fns`` derives, the basis and the MLP."""
@@ -169,23 +185,24 @@ def test_tensorf_march_block_compiles(chip, compiled):
 
 def _nerfacto_serving_params(sharding):
     """The params nerfacto's field binds on one v5e: the three grids'
-    tables and their corner rows under one budget (main levels 0-9, both
-    proposal grids whole), the folded head, the proposal MLPs."""
+    tables, their corner rows and x-neighbour rows under one budget of
+    the chip's ``bytes_limit`` (main levels 0-9 and 10-13, both proposal
+    grids whole), the folded head, the proposal MLPs."""
     m = nerfacto_config.CONFIG.model
     grids = (m.grid,) + m.proposal_grids
 
     def serving():
         k = jax.random.split(jax.random.PRNGKey(0), 3)
         tables = [hashgrid.init_hashgrid(kk, g) for kk, g in zip(k, grids)]
-        corners = hashgrid.field_corner_tables(tables, grids, HBM_BYTES)
+        corners, xrows = ops.corner_tables(tuple(tables), grids, BYTES_LIMIT)
         mlps = mlp_lib.init_mlps(k[0], m.net)
-        return {"grid": tables[0], "corners": corners[0], "mlps": mlps,
-                "proposals": [{"grid": t, "corners": c,
+        return {"grid": tables[0], "corners": corners[0], "xrows": xrows[0], "mlps": mlps,
+                "proposals": [{"grid": t, "corners": c, "xrows": x,
                                "mlp": [jnp.zeros((10, 16)), jnp.zeros((16, 1))]}
-                              for t, c in zip(tables[1:], corners[1:])]}
+                              for t, c, x in zip(tables[1:], corners[1:], xrows[1:])]}
     shapes = jax.eval_shape(serving)
-    assert [len(c) for c in [shapes["corners"]] + [p["corners"] for p in shapes["proposals"]]
-            ] == [10, 5, 5]
+    assert [(len(q["corners"]), len(q["xrows"])) for q in [shapes] + shapes["proposals"]
+            ] == [(10, 4), (5, 0), (5, 0)]
     return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), shapes)
 
 
